@@ -8,6 +8,7 @@ distributed proxy with the same apply/read surface.
 """
 from __future__ import annotations
 
+import logging
 import time
 import uuid as uuid_mod
 
@@ -21,7 +22,10 @@ from ..storage.rows import RowData, row_to_dict, rows_from_batch
 from ..types import parse_type
 from ..types.marshal import ListType, MapType, SetType
 from ..utils import timeutil
+from ..utils.logonce import warn_once
 from . import ast
+
+_log = logging.getLogger(__name__)
 
 
 class InvalidRequest(ValueError):
@@ -2021,8 +2025,11 @@ class Executor:
             try:
                 cnt, vmin, vmax, sm, _info = \
                     cfs.scan_filtered_aggregate(pred, now=now)
-            except Exception:
+            except Exception as e:
                 _M.incr("scan.fallback")
+                warn_once(_log, "scan.agg_pushdown.fallback",
+                          "aggregate scan pushdown refused, the Python "
+                          "path answers: %r", e)
                 return None   # fold refused: the Python path answers
             _M.incr("scan.pushdown")
             _M.incr("scan.agg_pushdown")
@@ -2047,8 +2054,11 @@ class Executor:
             return ("agg", ResultSet(names, [tuple(out)]))
         try:
             batches, _info = cfs.scan_filtered(pred, now=now)
-        except Exception:
+        except Exception as e:
             _M.incr("scan.fallback")
+            warn_once(_log, "scan.pushdown.fallback",
+                      "scan pushdown refused, the Python path "
+                      "answers: %r", e)
             return None   # kernel/key surprise: results still correct
         _M.incr("scan.pushdown")
         return ("batches", batches)

@@ -73,23 +73,31 @@ def match_scan_np(src: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def _policy_scan(src, n):
     """Traced body of the policy scan; one shifted-equality pass +
-    reversed cummin per candidate distance (the python loop unrolls
-    over the static distance table)."""
+    reversed cummin per candidate distance. The distances run as ONE
+    lax.scan body with a dynamic shift, not 73 unrolled copies: the
+    unrolled program took the TPU compiler 104 s and 443 MB of code
+    for one segment (CHANGES.md PR 21), the loop compiles in seconds.
+    Ascending order keeps ties on the smallest distance."""
     idx = jnp.arange(n, dtype=jnp.int32)
-    best_len = jnp.zeros((n,), dtype=jnp.int32)
-    best_d = jnp.zeros((n,), dtype=jnp.int32)
-    for d in DISTANCES:
-        if d >= n:
-            break
-        e = jnp.zeros((n,), dtype=jnp.bool_).at[d:].set(
-            src[d:] == src[:-d])
+    zero = jnp.zeros((n,), dtype=jnp.int32)
+    dists = [d for d in DISTANCES if d < n]
+    if not dists:
+        return zero, zero
+
+    def step(best, d):
+        best_len, best_d = best
+        # roll: prev[i] = src[i - d] wherever i >= d
+        e = (idx >= d) & (src == jnp.roll(src, d))
         nxt = jnp.where(e, jnp.int32(n), idx)
         nxt = jax.lax.cummin(nxt, axis=0, reverse=True)
         run = nxt - idx
         upd = run > best_len
-        best_len = jnp.where(upd, run, best_len)
-        best_d = jnp.where(upd, jnp.int32(d), best_d)
-    return best_len, best_d
+        return (jnp.where(upd, run, best_len),
+                jnp.where(upd, d, best_d)), None
+
+    best, _ = jax.lax.scan(step, (zero, zero),
+                           jnp.asarray(dists, dtype=jnp.int32))
+    return best
 
 
 @jax.jit
@@ -106,8 +114,11 @@ def segment_scan_kernel(meta_u8, lanes_u32):
     (planes, meta_best_len, meta_best_d, lane_best_len, lane_best_d,
     order_ok)."""
     n, k = lanes_u32.shape
+    # (n, k, 4) bytes -> plane (lane, byte) major, cell minor. One
+    # transpose; merging the 4-wide minor dimension first (reshape to
+    # (n, 4k), then .T) is what the TPU compiler is slow to compile
     planes = jax.lax.bitcast_convert_type(lanes_u32, jnp.uint8)
-    planes = planes.reshape(n, 4 * k).T.reshape(-1)
+    planes = jnp.transpose(planes, (1, 2, 0)).reshape(-1)
     a = lanes_u32[:-1]
     b = lanes_u32[1:]
     neq = a != b

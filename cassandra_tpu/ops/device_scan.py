@@ -37,11 +37,15 @@ carry an exact 64-bit accumulator) — still zero rows materialized.
 """
 from __future__ import annotations
 
+import logging
 import struct
 
 import numpy as np
 
 from ..schema import ColumnKind, TableMetadata
+from ..utils.logonce import warn_once
+
+_log = logging.getLogger(__name__)
 
 _BIAS = 1 << 63
 _U64_MAX = (1 << 64) - 1
@@ -449,8 +453,10 @@ def segment_mask(keys: np.ndarray, pred: CompiledPredicate,
     if use_device:
         try:
             return nan_fix(mask_device(keys, pred), keys, pred), True
-        except Exception:
-            pass
+        except Exception as e:
+            warn_once(_log, "scan.mask.fallback",
+                      "device scan mask kernel failed, host numpy "
+                      "reference takes the segment: %r", e)
     return nan_fix(mask_host(keys, pred), keys, pred), False
 
 
@@ -516,7 +522,10 @@ def fold_batch(batch, pred: CompiledPredicate, use_device: bool
             kmin = (int(mnh) << 32) | int(mnl)
             kmax = (int(mxh) << 32) | int(mxl)
             on_device = True
-        except Exception:
+        except Exception as e:
+            warn_once(_log, "scan.fold.fallback",
+                      "device scan fold kernel failed, host numpy "
+                      "reference folds the batch: %r", e)
             on_device = False
     if not on_device:
         mask = nan_fix(mask_host(keys, pred), keys, pred)

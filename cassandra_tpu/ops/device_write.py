@@ -35,14 +35,19 @@ same fused program, so the decision costs three tiny transfers.
 """
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
                                  FLAG_RANGE_BOUND, CellBatch)
+from ..utils.logonce import warn_once
 from . import device_compress
 from . import merge as dmerge
+
+_log = logging.getLogger(__name__)
 
 _U32 = jnp.uint32
 _BIAS_H = 0x80000000  # high u32 word of the 2^63 timestamp bias
@@ -139,14 +144,29 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
     borrow = (ts_l < prev_l).astype(jnp.uint32)
     d_h = ts_h - prev_h - borrow
 
-    def u32_bytes(a):
-        return jax.lax.bitcast_convert_type(a, jnp.uint8).reshape(-1)
+    def le_bytes(words, per_cell):
+        """Little-endian bytes of `per_cell` u32 word planes per cell,
+        interleaved cell by cell. Written as a gather over a byte iota
+        — byte j is a shift of word j>>2 — because the direct form
+        (bitcast to (n, 4) u8, then flatten) merges a 4-wide minor
+        dimension, which the TPU compiler unrolls: 18 s of compile per
+        section at 65,536 cells against 1 s for this (CHANGES.md
+        PR 21). Same bytes."""
+        j = jnp.arange(4 * per_cell * n, dtype=jnp.int32)
+        w = j >> 2
+        cell = w // per_cell
+        word = words[0][cell]
+        for k in range(1, per_cell):
+            word = jnp.where(w % per_cell == k, words[k][cell], word)
+        shift = (j & 3).astype(jnp.uint32) << 3
+        return ((word >> shift) & jnp.uint32(0xFF)).astype(jnp.uint8)
 
-    # (n, 2) u32 little-endian pair -> the 8 LE bytes of each i64 delta
-    ts_b = jax.lax.bitcast_convert_type(
-        jnp.stack([d_l, d_h], axis=1), jnp.uint8).reshape(-1)
+    def u32_bytes(a):
+        return le_bytes(
+            [jax.lax.bitcast_convert_type(a, jnp.uint32)], 1)
+
     meta = jnp.concatenate([
-        ts_b, u32_bytes(ldt), u32_bytes(ttl), flags8,
+        le_bytes([d_l, d_h], 2), u32_bytes(ldt), u32_bytes(ttl), flags8,
         u32_bytes(fl), u32_bytes(vr)])
 
     # stats reductions (biased-pair lexicographic min/max for ts)
@@ -195,6 +215,17 @@ class ResidentHandle:
                  "gc_before", "now", "prof", "fallback")
 
 
+def _resident_fallback(n: int, why: str) -> None:
+    """A round that leaves the resident lane for the host
+    materialization: same bytes, but the device did not do the work —
+    counted, and its cause logged once."""
+    from ..service.metrics import GLOBAL as _METRICS
+    _METRICS.incr("compaction.device_resident_fallback")
+    warn_once(_log, f"resident.fallback.{why}",
+              "device-resident round (%d cells) materialized on the "
+              "host: %s", n, why)
+
+
 # test seam: {round_seq: seconds} delay applied at collect time BEFORE
 # the device result is consumed — reverses the completion order of
 # in-flight rounds (tests/test_device_resident.py); None in production.
@@ -221,6 +252,7 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
         h.mode, h.result = "done", cat
         return h
     if ((cat.flags & (FLAG_RANGE_BOUND | FLAG_COUNTER)) != 0).any():
+        _resident_fallback(h.n, "counters or range tombstone bounds")
         h.mode = "host"
         h.fallback = dmerge.submit_merge(batches, gc_before, now,
                                          purgeable_ts_fn, prof=prof)
@@ -228,6 +260,7 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     t0 = _time.perf_counter()
     built = build_resident_operands(cat, gc_before, now, purgeable_ts_fn)
     if built is None:   # >= 4 GiB frame: let the host path fail loudly
+        _resident_fallback(h.n, "frame exceeds the u32 offset lane")
         h.mode = "host"
         h.fallback = dmerge.submit_merge(batches, gc_before, now,
                                          purgeable_ts_fn, prof=prof)
@@ -283,6 +316,8 @@ def collect_merge_resident(h: ResidentHandle):
         # host's full-value tie-break, kept expired cells need the
         # tombstone conversion's payload rewrite — materialize on the
         # host exactly like ops/merge.py's v1/v2 collect
+        _resident_fallback(
+            h.n, "equal-(identity, ts) ties or kept expired-TTL cells")
         n = h.n
         perm = np.asarray(perm_d).astype(np.int64)[:n]
         keep, amb, expired, shadowed = dmerge.unpack_masks(
@@ -502,11 +537,14 @@ class DeviceWriteLane:
                              (np.asarray(lbl), np.asarray(lbd)))
                     _kprof.record_execute("write.compress",
                                           _time.perf_counter() - t_e)
-                except Exception:
+                except Exception as e:
                     # per-segment fallback: the host compress leg takes
                     # this one; output bytes identical either way
                     from ..service.metrics import GLOBAL as _METRICS
                     _METRICS.incr("compaction.device_compress_fallback")
+                    warn_once(_log, "write.compress.fallback",
+                              "device compress kernel failed, host "
+                              "compress leg takes the segment: %r", e)
                 else:
                     if not ok:
                         raise ValueError("appended cells out of order")

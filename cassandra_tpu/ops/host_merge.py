@@ -3,10 +3,10 @@ reconcile (ops/native/merge.cpp) for sorted CellBatch runs.
 
 This is the host-side counterpart of the TPU kernel (ops/merge.py) —
 the CompactionIterator formulation (db/compaction/CompactionIterator.java
-:90) in native code. The compaction task picks an engine per the measured
-environment: the TPU kernel when the device link sustains it, this engine
-when the link is latency/bandwidth-bound (e.g. a tunneled chip), numpy as
-the always-available executable spec.
+:90) in native code. The compaction task takes engine= per call: this
+engine by default when the library builds, the TPU kernel on request,
+numpy as the always-available executable spec (which of the first two
+is faster on an attached chip is not measured — ROADMAP A2).
 
 Falls back to the numpy merge when a batch is unsorted, contains counter
 cells (commutative-sum reconcile lives in numpy), or the native library

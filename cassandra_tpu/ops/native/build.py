@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import platform
 import subprocess
@@ -18,6 +19,7 @@ _SRCS = [os.path.join(_DIR, f) for f in ("codec.cpp", "merge.cpp")]
 _SO = os.path.join(_DIR, "libcodec.so")
 _STAMP = _SO + ".srchash"
 _lock = threading.Lock()
+_log = logging.getLogger(__name__)
 _lib = None
 _load_error = None  # negative cache: don't re-run g++ per call on failure
 
@@ -53,6 +55,16 @@ def _stale(h: str) -> bool:
         return f.read().strip() != h
 
 
+def rebuild() -> None:
+    """Compile the library from the sources now, whatever is cached:
+    chip_smoke.py starts from what git commits, not from a .so another
+    machine left in the checkout. Lands by atomic rename, so a process
+    that already mapped the old file keeps it. Raises CalledProcessError
+    with the compiler's output."""
+    with _lock:
+        _build(_src_hash())
+
+
 def load() -> ctypes.CDLL:
     global _lib, _load_error
     if _lib is not None:
@@ -69,6 +81,14 @@ def load() -> ctypes.CDLL:
             if _stale(h):
                 _build(h)
         except Exception as e:
+            # callers catch this and take their numpy legs (the native
+            # merge engine, segment packer, ragged gather): say so once,
+            # with the compiler's own words — the negative cache above
+            # means this line cannot repeat
+            detail = getattr(e, "stderr", None) or b""
+            _log.warning("native codec build failed, host paths fall "
+                         "back to numpy: %s %s", e,
+                         detail.decode("utf-8", "replace")[-2000:])
             _load_error = RuntimeError(f"native codec build failed: {e}")
             raise _load_error
         lib = ctypes.CDLL(_SO)

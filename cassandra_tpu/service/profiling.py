@@ -52,9 +52,14 @@ accelerator is shared by every in-process node anyway.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import OrderedDict
+
+from ..utils.logonce import warn_once
+
+_log = logging.getLogger(__name__)
 
 # patchable clock seam (the pipeline-ledger pattern): tests freeze it,
 # production leaves time.perf_counter
@@ -85,10 +90,7 @@ def _shape_of(x):
 def _has_tracer(x) -> bool:
     """True iff any leaf of the operand tree is a jax Tracer — i.e. the
     call is happening INSIDE an enclosing trace."""
-    try:
-        from jax.core import Tracer
-    except Exception:
-        return False
+    from jax.core import Tracer
 
     def walk(v):
         if isinstance(v, Tracer):
@@ -197,19 +199,20 @@ class DeviceProgramRegistry:
 
     def maybe_record_cost(self, kernel: str, fn, args=(),
                           kwargs=None) -> None:
-        """Best-effort XLA cost analysis for a program's most recently
-        compiled shape. jit caches the executable, so lower().compile()
-        right after a compiling dispatch is a cache hit, not a second
-        compile; backends without the analysis (or older jax APIs)
-        simply leave cost at None."""
+        """XLA cost analysis for a program's most recently compiled
+        shape. Right after a compiling dispatch, lower() is jit's cached
+        lowering and compile() finds the executable that ran in jax's
+        in-memory compilation cache — microseconds, not a second
+        compile (timed on the chip, CHANGES.md PR 21). A failure leaves
+        cost at None and says why, once per program."""
         try:
             lowered = fn.lower(*args, **(kwargs or {}))
             cost = lowered.compile().cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
             flops = float(cost.get("flops", 0.0))
             nbytes = float(cost.get("bytes accessed", 0.0))
-        except Exception:
+        except Exception as e:
+            warn_once(_log, f"profile.cost.{kernel}",
+                      "no XLA cost analysis for %s: %r", kernel, e)
             return
         with self._lock:
             self._kernel_locked(kernel)["cost"] = {

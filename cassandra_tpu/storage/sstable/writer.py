@@ -27,6 +27,7 @@ Write-leg staging (docs/compaction-executor.md):
 from __future__ import annotations
 
 import json
+import logging
 import mmap
 import os
 import queue
@@ -40,9 +41,11 @@ import numpy as np
 from ...ops.codec import CompressionParams, SegmentPacker, lanes_shuffle
 from ...schema import TableMetadata
 from ...utils import bloom, faultfs
+from ...utils.logonce import warn_once
 from ..cellbatch import CellBatch
 from .format import SEGMENT_CELLS, Component, Descriptor
 
+_log = logging.getLogger(__name__)
 
 # test seam: per-segment delay hook run by pool workers before packing
 # (tests/test_parallel_compress.py forces adversarial completion order
@@ -1191,11 +1194,14 @@ class SSTableWriter:
             if device_pack is not None:
                 try:
                     packed = device_pack(attempt, maxlen)
-                except Exception:
+                except Exception as e:
                     # per-segment fallback: the host leg compresses this
                     # one; output bytes identical (same policy encoder)
                     if self._metrics is not None:
                         self._metrics.incr("device_compress_fallback")
+                    warn_once(_log, "write.device_pack.fallback",
+                              "device-compressed segment pack failed, "
+                              "host compress leg takes it: %r", e)
                     packed = None
             if packed is not None and self._cpool is not None:
                 self._submit_packed(blocks, attempt, need, n,

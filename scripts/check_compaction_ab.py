@@ -321,6 +321,8 @@ def run_check(base_dir: str | None = None) -> list[str]:
 
 
 def main() -> int:
+    from cassandra_tpu.utils import compile_cache
+    compile_cache.configure()
     diverged = run_check()
     if diverged:
         print("parallel-compression A/B DIVERGED:", file=sys.stderr)

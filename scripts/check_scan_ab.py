@@ -156,6 +156,8 @@ def run_check(base_dir: str) -> list[str]:
 
 
 def main() -> int:
+    from cassandra_tpu.utils import compile_cache
+    compile_cache.configure()
     with tempfile.TemporaryDirectory(prefix="ctpu-scan-ab-") as d:
         diverged = run_check(d)
     for msg in diverged:

@@ -13,6 +13,10 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
+from cassandra_tpu.utils import compile_cache
+
+compile_cache.configure()
+
 from cassandra_tpu.ops import merge as dmerge
 from cassandra_tpu.storage import cellbatch as cb
 from cassandra_tpu.tools import bulk

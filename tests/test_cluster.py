@@ -28,6 +28,12 @@ def cluster(tmp_path):
 
 
 def test_write_one_node_read_another(cluster):
+    # the read on node 2 races the replication of a CL ONE write; wait on
+    # every replica's acknowledgement (ALL), with a timeout that survives
+    # six xdist workers on a loaded box — then the read MUST see it
+    for n in cluster.nodes:
+        n.proxy.timeout = 15.0
+    cluster.node(1).default_cl = ConsistencyLevel.ALL
     s1 = cluster.session(1)
     s1.keyspace = "ks"
     s1.execute("INSERT INTO kv (k, v) VALUES (1, 'hello')")

@@ -27,10 +27,6 @@ def build_workload(n_parts=40, n_cks=5, gens=3):
 
 
 def test_mesh_really_has_8_devices():
-    import os
-    if os.environ.get("CASSANDRA_TPU_TEST_BACKEND", "cpu") != "cpu":
-        import pytest
-        pytest.skip("suite running on real hardware backend")
     assert len(jax.devices()) >= 8, jax.devices()
     assert jax.default_backend() == "cpu"
 

@@ -157,11 +157,11 @@ def test_compressed_encrypted_rotation_replay(tmp_path):
     enc_mod.set_context(enc_mod.EncryptionContext(str(tmp_path / "keys")))
     try:
         d = str(tmp_path / "cl")
-        payload = b"secret--" * 32
+        payload = b"secret--" * 64            # compressible: sized like
         cl = CommitLog(d, sync_mode="batch", segment_size=2048,
                        compression="LZ4Compressor", encrypt=True)
-        n = 24
-        for i in range(n):
+        n = 120                               # the test above, so the
+        for i in range(n):                    # frames really rotate
             cl.add(_mut(i, payload))
         assert len(cl.segment_ids()) > 2
         cl.close()
