@@ -31,7 +31,7 @@ from ..ops import merge as dmerge
 from ..storage import cellbatch as cb
 from ..storage.lifecycle import LifecycleTransaction
 from ..storage.sstable import Descriptor, SSTableReader, SSTableWriter
-from ..utils import timeutil
+from ..utils import pipeline_ledger, timeutil
 
 _log = logging.getLogger(__name__)
 
@@ -70,15 +70,13 @@ class _Cursor:
     buffers between rounds via fill_to, never concurrently with the
     round's own cursor access.)"""
 
-    def __init__(self, reader: SSTableReader, prof: dict | None = None,
-                 led=None):
+    def __init__(self, reader: SSTableReader, prof: dict | None = None):
         self._it = reader.scanner()
         self.prof = prof
-        # pipeline ledger `compaction`/`decode` stage (led): every
-        # fetch bills the SAME dt to the profile and to the stage's
-        # busy seconds, so bench.py's reconcile proves them equal by
-        # construction
-        self.led = led
+        # pipeline ledger `compaction`/`decode` stage: every fetch is
+        # one `compaction.decode.fetch` span, which bills the SAME
+        # seconds to the profile and to the stage's busy seconds
+        self.led = pipeline_ledger.ledger("compaction").stage("decode")
         # which phase bucket _fetch bills: the decode-ahead thread bills
         # its overlapped fills to 'decode_ahead' so 'io_decode' keeps
         # meaning time the MERGE thread stalled waiting on decode
@@ -88,24 +86,18 @@ class _Cursor:
         self._fetch()
 
     def _fetch(self) -> bool:
-        t0 = time.perf_counter()
-        try:
-            self.bufs.append(next(self._it))
+        with self.led.busy("compaction.decode.fetch", prof=self.prof,
+                           key=self.prof_key) as sp:
+            try:
+                b = next(self._it)
+            except StopIteration:
+                self.exhausted = True
+                return False
+            self.bufs.append(b)
+            sp.cells = len(b)
+            sp.nbytes = b.payload.nbytes + b.lanes.nbytes
+            self.led.add_items(1, sp.nbytes)
             return True
-        except StopIteration:
-            self.exhausted = True
-            return False
-        finally:
-            dt = time.perf_counter() - t0
-            if self.prof is not None:
-                key = self.prof_key
-                self.prof[key] = self.prof.get(key, 0.0) + dt
-            if self.led is not None:
-                self.led.add_busy(dt)
-                if self.bufs and not self.exhausted:
-                    b = self.bufs[-1]
-                    self.led.add_items(
-                        1, b.payload.nbytes + b.lanes.nbytes)
 
     @property
     def has_data(self) -> bool:
@@ -770,6 +762,15 @@ class CompactionTask:
         CompactionTask.java:252-266)."""
         if self.drop_only and self._drop_safe():
             return self._execute_drop()
+        # the root span: every span of this task, on whichever thread,
+        # carries its id (docs/observability.md, span catalogue)
+        with pipeline_ledger.span(
+                "compaction.task", task=pipeline_ledger.new_task_id(),
+                cells=sum(r.n_cells for r in self.inputs),
+                nbytes=sum(r.data_size for r in self.inputs)) as root:
+            return self._execute(root.task)
+
+    def _execute(self, task_id: int) -> dict:
         cfs = self.cfs
         table = cfs.table
         t0 = time.time()
@@ -777,13 +778,14 @@ class CompactionTask:
         now = timeutil.now_seconds()
         controller = CompactionController(cfs, self.inputs)
         prof = self.profile
-        # pipeline `compaction` gains a `decode` stage: cursor fetches
-        # (inline AND decode-ahead) bill busy, the merge thread's
-        # prefetch waits bill stall, the prefetch thread's parked time
-        # bills idle, and queue_hwm records how many segments decode
-        # ran ahead of the merge (docs/observability.md)
-        from ..utils import pipeline_ledger
-        led_decode = pipeline_ledger.ledger("compaction").stage("decode")
+        # pipeline `compaction` (docs/observability.md): `decode` —
+        # cursor fetches (inline AND decode-ahead) bill busy, the merge
+        # thread's prefetch waits stall, the prefetch thread's parked
+        # time idle, queue_hwm = segments decoded ahead of the merge;
+        # `writeq` — the merge thread blocked on the full write queue
+        # bills stall, compact-w parked on the empty one idle
+        led = pipeline_ledger.ledger("compaction")
+        led_decode, led_wq = led.stage("decode"), led.stage("writeq")
         # None for the device engine: its rounds go through
         # submit/collect. The serial loop defers the output gather to
         # the writer thread (it drains the wq FIFO on one thread, so
@@ -835,6 +837,11 @@ class CompactionTask:
 
         progress = self.progress
 
+        def wq_put(item):
+            with led_wq.stall("compaction.writeq.put_wait", prof=prof,
+                              key="writeq_put_wait"):
+                wq.put(item)
+
         def flush_lane():
             lane = wstate["lane"]
             if lane is not None:
@@ -857,7 +864,9 @@ class CompactionTask:
             # state another thread is mutating.
             try:
                 while True:
-                    merged = wq.get()
+                    with led_wq.idle("compaction.writeq.get_wait",
+                                     prof=prof, key="writeq_get_wait"):
+                        merged = wq.get()
                     if merged is None:
                         # the sentinel is already consumed: a raise out
                         # of the lane flush must land in werr and
@@ -928,7 +937,7 @@ class CompactionTask:
             else:
                 merged = dmerge.collect_merge(pending.popleft())
             if len(merged):
-                wq.put(merged)
+                wq_put(merged)
 
         # throttle + progress work in on-disk byte terms: each round
         # consumed cells are mapped back to their share of the input
@@ -952,7 +961,8 @@ class CompactionTask:
 
         def prefetch_loop():
             while True:
-                with led_decode.idle():   # parked between prefetches
+                # parked between prefetches
+                with led_decode.idle("compaction.decode.park"):
                     per = pf_q.get()
                 if per is None:
                     return
@@ -973,6 +983,11 @@ class CompactionTask:
                         sum(len(c.bufs) for c in cursors))
                     pf_done.set()
 
+        def in_task(fn):
+            # the task's helper threads: their spans carry its id
+            with pipeline_ledger.task_scope(task_id):
+                fn()
+
         def stop_prefetch():
             if pf_thread is not None:
                 pf_q.put(None)
@@ -983,7 +998,8 @@ class CompactionTask:
             if progress is not None:
                 progress.set_phase("decode")
             wstate["writer"] = new_writer()
-            wthread = threading.Thread(target=write_loop, name="compact-w")
+            wthread = threading.Thread(
+                target=in_task, args=(write_loop,), name="compact-w")
             wthread.start()
             # mesh execution mode: shard the rewrite by token range and
             # fan decode+merge across the mesh lanes; the serial round
@@ -1004,8 +1020,7 @@ class CompactionTask:
                                   and self.device_resident
                                   and not mesh_done)
             cursors = [] if mesh_done \
-                else [_Cursor(r, prof, led=led_decode)
-                      for r in self.inputs]
+                else [_Cursor(r, prof) for r in self.inputs]
             # the decode-ahead thread starts (and stops, and restarts)
             # from the knob check at the top of each round — see below
             while True:
@@ -1026,10 +1041,9 @@ class CompactionTask:
                 # out any in-flight prefetch before touching them (the
                 # wait is the merge thread BLOCKED ON decode — the
                 # ledger bills it as a decode-stage stall)
-                t_pf = time.perf_counter()
-                pf_done.wait()
                 if pf_thread is not None:
-                    led_decode.add_stall(time.perf_counter() - t_pf)
+                    with led_decode.stall("compaction.decode.wait"):
+                        pf_done.wait()
                 if pf_err:
                     raise pf_err[0]
                 # hot-reloadable `compaction_decode_ahead`: re-resolved
@@ -1046,7 +1060,7 @@ class CompactionTask:
                     elif pf_thread is None and want_da:
                         pf_q = queue.Queue()
                         pf_thread = threading.Thread(
-                            target=prefetch_loop,
+                            target=in_task, args=(prefetch_loop,),
                             name="compact-prefetch", daemon=True)
                         pf_thread.start()
                 active = [c for c in cursors if c.has_data]
@@ -1058,18 +1072,20 @@ class CompactionTask:
                 # that key's partition; merge everything up to the
                 # partition end (full key width padded with 0xFF)
                 per_cursor = max(self.round_cells // len(active), 1)
-                for c in active:
-                    c.fill_to(per_cursor)
-                prefix16 = min(c.last_key() for c in active)[:16]
-                for c in cursors:
-                    c.extend_past_partition(prefix16)
-                K = self.inputs[0].K
-                boundary = prefix16 + b"\xff" * (4 * K - 16)
-                slices = []
-                for c in cursors:
-                    s = c.split_at(boundary)
-                    if s is not None and len(s):
-                        slices.append(s)
+                with pipeline_ledger.span("compaction.round.cut") as sp:
+                    for c in active:
+                        c.fill_to(per_cursor)
+                    prefix16 = min(c.last_key() for c in active)[:16]
+                    for c in cursors:
+                        c.extend_past_partition(prefix16)
+                    K = self.inputs[0].K
+                    boundary = prefix16 + b"\xff" * (4 * K - 16)
+                    slices = []
+                    for c in cursors:
+                        s = c.split_at(boundary)
+                        if s is not None and len(s):
+                            slices.append(s)
+                    sp.cells = sum(len(s) for s in slices)
                 if not slices:
                     continue
                 if pf_thread is not None and \
@@ -1104,59 +1120,62 @@ class CompactionTask:
                     merged = merge_fn(slices, gc_before=gc_before, now=now,
                                       purgeable_ts_fn=controller.purgeable_ts_fn)
                     if len(merged):
-                        wq.put(merged)
+                        wq_put(merged)
             stop_prefetch()
             pf_thread = None
             while pending:
                 collect_oldest()
-            wq.put(None)
-            wthread.join()
+            with led_wq.stall("compaction.writeq.drain", prof=prof,
+                              key="writeq_put_wait"):
+                wq.put(None)
+                wthread.join()
             if werr:
                 raise werr[0]
             cells_written = wstate["cells"]
             writer = wstate["writer"]
             if progress is not None:
                 progress.set_phase("seal")
-            tw = time.perf_counter()
-            writer.finish()
-            prof["seal"] = prof.get("seal", 0.0) + \
-                (time.perf_counter() - tw)
+            with led.stage("seal").busy("compaction.seal", prof=prof,
+                                        key="seal"):
+                writer.finish()
             if progress is not None:
                 # the final pool drain's tail (write_loop is joined,
                 # so "credited" is stable here)
                 progress.add_written(
                     writer.data_offset() - wstate["credited"])
-            new_readers.append(SSTableReader(writer.desc, table))
-            for r in self.inputs:
-                txn.track_obsolete(r.desc.generation)
-            # empty outputs (everything purged) die in the same txn
-            live_new = []
-            for r in new_readers:
-                if r.n_cells > 0:
-                    live_new.append(r)
-                else:
-                    r.close()
+            with pipeline_ledger.span("compaction.commit", prof=prof,
+                                      key="commit"):
+                new_readers.append(SSTableReader(writer.desc, table))
+                for r in self.inputs:
                     txn.track_obsolete(r.desc.generation)
-            # COMMIT first (a failure here must roll back cleanly while the
-            # tracker still serves the inputs), then swap the live view;
-            # input files may already be unlinked but their open fds keep
-            # serving in-flight reads. Inputs are RELEASED, not closed
-            # (reference SSTableReader ref-counting, utils/concurrent/Ref).
-            txn.commit()
-            cfs.tracker.replace(self.inputs, live_new)
-            if cfs.row_cache is not None:
-                # compaction-generation change: the read fast lane pins
-                # cached merges to the sstable set they were computed
-                # from (storage/row_cache.py invalidation contract)
-                cfs.row_cache.clear()
-            for r in self.inputs:
-                r.release()
-            if getattr(cfs, "index_build_fn", None) is not None:
-                # eager attached-index components for the outputs, so
-                # the first indexed query after compaction never pays
-                # the build storm (build_eager never raises)
-                for r in live_new:
-                    cfs.index_build_fn(r)
+                # empty outputs (everything purged) die in the same txn
+                live_new = []
+                for r in new_readers:
+                    if r.n_cells > 0:
+                        live_new.append(r)
+                    else:
+                        r.close()
+                        txn.track_obsolete(r.desc.generation)
+                # COMMIT first (a failure here must roll back cleanly while the
+                # tracker still serves the inputs), then swap the live view;
+                # input files may already be unlinked but their open fds keep
+                # serving in-flight reads. Inputs are RELEASED, not closed
+                # (reference SSTableReader ref-counting, utils/concurrent/Ref).
+                txn.commit()
+                cfs.tracker.replace(self.inputs, live_new)
+                if cfs.row_cache is not None:
+                    # compaction-generation change: the read fast lane pins
+                    # cached merges to the sstable set they were computed
+                    # from (storage/row_cache.py invalidation contract)
+                    cfs.row_cache.clear()
+                for r in self.inputs:
+                    r.release()
+                if getattr(cfs, "index_build_fn", None) is not None:
+                    # eager attached-index components for the outputs, so
+                    # the first indexed query after compaction never pays
+                    # the build storm (build_eager never raises)
+                    for r in live_new:
+                        cfs.index_build_fn(r)
         except BaseException as exc:
             pending.clear()
             stop_prefetch()

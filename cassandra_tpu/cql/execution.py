@@ -21,7 +21,7 @@ from ..storage.mutation import Mutation
 from ..storage.rows import RowData, row_to_dict, rows_from_batch
 from ..types import parse_type
 from ..types.marshal import ListType, MapType, SetType
-from ..utils import timeutil
+from ..utils import pipeline_ledger, timeutil
 from ..utils.logonce import warn_once
 from . import ast
 
@@ -2365,11 +2365,14 @@ class Executor:
         else:
             hits = idx.ann(q, k)
         rows = []
-        for pk, ck, score in hits:
-            batch = cfs.read_partition(pk)
-            for r in rows_from_batch(t, batch):
-                if r.ck_frame == ck and not r.is_static:
-                    rows.append(row_to_dict(t, r, with_meta=True))
+        # the hits' rows, read back one partition each (span catalogue:
+        # docs/observability.md)
+        with pipeline_ledger.span("cql.ann.rows", items=len(hits)):
+            for pk, ck, score in hits:
+                batch = cfs.read_partition(pk)
+                for r in rows_from_batch(t, batch):
+                    if r.ck_frame == ck and not r.is_static:
+                        rows.append(row_to_dict(t, r, with_meta=True))
         return self._project(t, s, rows)
 
     def _apply_ck_restrictions(self, t, rows, ck_rel):

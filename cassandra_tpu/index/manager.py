@@ -21,7 +21,13 @@ import threading
 import numpy as np
 
 from ..schema import TableMetadata
+from ..utils import pipeline_ledger
 from . import sstable_index as ssi
+
+# pipeline `index` (docs/observability.md, span catalogue): `ann` busy =
+# the host side of a vector query, stall = blocked on the device's answer
+_LED_ANN = pipeline_ledger.ledger("index").stage("ann")
+_LED_BUILD = pipeline_ledger.ledger("index").stage("component_build")
 
 
 class _AttachedIndex:
@@ -65,7 +71,9 @@ class _AttachedIndex:
                 # the writer tail. The counter pair proves it.
                 from ..service.metrics import GLOBAL as _M
                 _M.incr("index.lazy_builds")
-                self._build(reader)
+                with _LED_BUILD.busy("index.component_build",
+                                     cells=reader.n_cells):
+                    self._build(reader)
                 loaded = self._load(path)
             if loaded is None:   # disk refused twice: serve from memory
                 loaded = self._fresh(reader)
@@ -99,7 +107,9 @@ class _AttachedIndex:
             if loaded is None:
                 from ..service.metrics import GLOBAL as _M
                 _M.incr("index.builds")
-                self._build(reader)
+                with _LED_BUILD.busy("index.component_build",
+                                     cells=reader.n_cells):
+                    self._build(reader)
                 loaded = self._load(path)
                 built = True
             if loaded is None:
@@ -285,18 +295,29 @@ class VectorIndex(_AttachedIndex):
             similarity: str = "cosine") -> list:
         """Top-k (pk, ck, score). One matmul + top_k on the device — the
         MXU path (index/sai vector search role)."""
-        m, keys = self._gather()
+        with _LED_ANN.busy("index.ann.gather") as sp:
+            m, keys = self._gather()
+            sp.cells = len(m)
         if len(m) == 0:
             return []
         q = np.asarray(query, dtype=np.float32)
         if similarity == "cosine":
-            m = m / np.maximum(np.linalg.norm(m, axis=1, keepdims=True),
-                               1e-9)
-            q = q / max(float(np.linalg.norm(q)), 1e-9)
-        vals, idx = ann_program()(m, q, k=min(k, len(m)),
-                                  similarity=similarity)
+            with _LED_ANN.busy("index.ann.normalise", cells=len(m),
+                               nbytes=m.nbytes):
+                m = m / np.maximum(
+                    np.linalg.norm(m, axis=1, keepdims=True), 1e-9)
+                q = q / max(float(np.linalg.norm(q)), 1e-9)
+        # the call uploads the matrix and dispatches; the pull blocks on
+        # the device's answer (no upload span of its own: splitting it
+        # out would take a sync the program does not have)
+        with _LED_ANN.busy("index.ann.call", cells=len(m),
+                           nbytes=m.nbytes + q.nbytes):
+            vals, idx = ann_program()(m, q, k=min(k, len(m)),
+                                      similarity=similarity)
+        with _LED_ANN.stall("index.ann.pull"):
+            vals, idx = np.asarray(vals), np.asarray(idx)
         return [(keys[int(i)][0], keys[int(i)][1], float(v))
-                for v, i in zip(np.asarray(vals), np.asarray(idx))]
+                for v, i in zip(vals, idx)]
 
 
 _ANN_PROGRAM = None
@@ -326,13 +347,16 @@ def ann_program():
 
         @partial(jax.jit, static_argnames=("k", "similarity"))
         def program(m, q, k, similarity):
-            if similarity == "euclidean":
-                # -(|x - q|^2) so bigger is better
-                scores = -jnp.sum((m - q[None, :]) ** 2, axis=1)
-            else:   # cosine arrives normalized; dot as is
-                scores = jnp.matmul(m, q,
-                                    precision=jax.lax.Precision.HIGHEST)
-            return jax.lax.top_k(scores, k)
+            # named_scope: op names in a profiler trace, nothing else
+            with jax.named_scope("score"):
+                if similarity == "euclidean":
+                    # -(|x - q|^2) so bigger is better
+                    scores = -jnp.sum((m - q[None, :]) ** 2, axis=1)
+                else:   # cosine arrives normalized; dot as is
+                    scores = jnp.matmul(
+                        m, q, precision=jax.lax.Precision.HIGHEST)
+            with jax.named_scope("top_k"):
+                return jax.lax.top_k(scores, k)
 
         _ANN_PROGRAM = _kprof.wrap("index.ann", program)
     return _ANN_PROGRAM

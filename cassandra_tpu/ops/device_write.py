@@ -43,6 +43,8 @@ import numpy as np
 
 from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
                                  FLAG_RANGE_BOUND, CellBatch)
+from ..service.profiling import GLOBAL as _kprof
+from ..utils import pipeline_ledger
 from ..utils.logonce import warn_once
 from . import device_compress
 from . import merge as dmerge
@@ -51,6 +53,11 @@ _log = logging.getLogger(__name__)
 
 _U32 = jnp.uint32
 _BIAS_H = 0x80000000  # high u32 word of the 2^63 timestamp bias
+
+# pipeline `merge`/`resident`: the host side of a resident round — busy =
+# concat + operand pack + dispatch + payload gather, stall = blocked on
+# the device result (docs/observability.md, span catalogue)
+_LED_RESIDENT = pipeline_ledger.ledger("merge").stage("resident")
 
 
 # ------------------------------------------------------------- operands --
@@ -103,22 +110,28 @@ def _resident_program(operands):
     order, kept cells first. Returns (n_keep, n_amb, n_exp_kept,
     perm_out, cols, perm, packed); the last two feed the host fallback
     when the scalar counts demand it."""
-    perm = dmerge.device_sort_perm(operands)
-    packed = dmerge.reconcile_kernel(operands, perm)
-    keep = (packed & 1) != 0
-    amb = (packed & 2) != 0
-    expired = (packed & 4) != 0
-    n_keep = jnp.sum(keep).astype(jnp.int32)
-    n_amb = jnp.sum(amb).astype(jnp.int32)
-    n_exp_kept = jnp.sum(expired & keep).astype(jnp.int32)
-    N = keep.shape[0]
-    # stable partition: kept cells to the front, SORTED ORDER preserved
-    # (stability) — the device-side analog of np.flatnonzero(keep)
-    _, ord_ = jax.lax.sort(
-        (jnp.where(keep, jnp.uint32(0), jnp.uint32(1)),
-         jnp.arange(N, dtype=jnp.int32)), num_keys=1, is_stable=True)
-    perm_out = perm[ord_]
-    cols = {k: operands[k][perm_out] for k in RESIDENT_COLS}
+    # named_scope: metadata only (op names in a profiler trace), the
+    # program and its bytes are unchanged
+    with jax.named_scope("sort"):
+        perm = dmerge.device_sort_perm(operands)
+    with jax.named_scope("reconcile"):   # its purge stage names itself
+        packed = dmerge.reconcile_kernel(operands, perm)
+    with jax.named_scope("compact"):
+        keep = (packed & 1) != 0
+        amb = (packed & 2) != 0
+        expired = (packed & 4) != 0
+        n_keep = jnp.sum(keep).astype(jnp.int32)
+        n_amb = jnp.sum(amb).astype(jnp.int32)
+        n_exp_kept = jnp.sum(expired & keep).astype(jnp.int32)
+        N = keep.shape[0]
+        # stable partition: kept cells to the front, SORTED ORDER
+        # preserved (stability) — the device-side analog of
+        # np.flatnonzero(keep)
+        _, ord_ = jax.lax.sort(
+            (jnp.where(keep, jnp.uint32(0), jnp.uint32(1)),
+             jnp.arange(N, dtype=jnp.int32)), num_keys=1, is_stable=True)
+        perm_out = perm[ord_]
+        cols = {k: operands[k][perm_out] for k in RESIDENT_COLS}
     return n_keep, n_amb, n_exp_kept, perm_out, cols, perm, packed
 
 
@@ -137,12 +150,14 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
     pairs ARE the i64 deltas, and cell 0's absolute stamp is its uts
     minus the bias — one XOR on the high word."""
     n = ts_h.shape[0]
-    prev_h = jnp.concatenate(
-        [jnp.full((1,), _BIAS_H, dtype=jnp.uint32), ts_h[:-1]])
-    prev_l = jnp.concatenate([jnp.zeros(1, dtype=jnp.uint32), ts_l[:-1]])
-    d_l = ts_l - prev_l
-    borrow = (ts_l < prev_l).astype(jnp.uint32)
-    d_h = ts_h - prev_h - borrow
+    with jax.named_scope("timestamps"):
+        prev_h = jnp.concatenate(
+            [jnp.full((1,), _BIAS_H, dtype=jnp.uint32), ts_h[:-1]])
+        prev_l = jnp.concatenate(
+            [jnp.zeros(1, dtype=jnp.uint32), ts_l[:-1]])
+        d_l = ts_l - prev_l
+        borrow = (ts_l < prev_l).astype(jnp.uint32)
+        d_h = ts_h - prev_h - borrow
 
     def le_bytes(words, per_cell):
         """Little-endian bytes of `per_cell` u32 word planes per cell,
@@ -165,18 +180,24 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
         return le_bytes(
             [jax.lax.bitcast_convert_type(a, jnp.uint32)], 1)
 
-    meta = jnp.concatenate([
-        le_bytes([d_l, d_h], 2), u32_bytes(ldt), u32_bytes(ttl), flags8,
-        u32_bytes(fl), u32_bytes(vr)])
+    with jax.named_scope("timestamps"):
+        ts_bytes = le_bytes([d_l, d_h], 2)
+    with jax.named_scope("lengths"):
+        meta = jnp.concatenate([
+            ts_bytes, u32_bytes(ldt), u32_bytes(ttl), flags8,
+            u32_bytes(fl), u32_bytes(vr)])
 
     # stats reductions (biased-pair lexicographic min/max for ts)
-    max_h = jnp.max(ts_h)
-    max_l = jnp.max(jnp.where(ts_h == max_h, ts_l, jnp.uint32(0)))
-    min_h = jnp.min(ts_h)
-    min_l = jnp.min(jnp.where(ts_h == min_h, ts_l, _U32(0xFFFFFFFF)))
-    tombs = jnp.sum((flags8 & jnp.uint8(DEATH_FLAGS)) != 0)
-    return meta, (min_h, min_l, max_h, max_l,
-                  jnp.min(ldt), jnp.max(ldt), tombs)
+    with jax.named_scope("stats"):
+        max_h = jnp.max(ts_h)
+        max_l = jnp.max(jnp.where(ts_h == max_h, ts_l, jnp.uint32(0)))
+        min_h = jnp.min(ts_h)
+        min_l = jnp.min(jnp.where(ts_h == min_h, ts_l,
+                                  _U32(0xFFFFFFFF)))
+        tombs = jnp.sum((flags8 & jnp.uint8(DEATH_FLAGS)) != 0)
+        stats = (min_h, min_l, max_h, max_l,
+                 jnp.min(ldt), jnp.max(ldt), tombs)
+    return meta, stats
 
 
 def _uts_pair_to_i64(h: int, l: int) -> int:
@@ -241,12 +262,12 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     formulation cannot encode (counters, range bounds, oversized
     frames) dispatch through the regular submit_merge path instead —
     collect_merge_resident returns a host CellBatch for those."""
-    import time as _time
-
     h = ResidentHandle()
     h.gc_before, h.now, h.prof = gc_before, now, prof
     h.fallback = None
-    cat = CellBatch.concat(batches)
+    with _LED_RESIDENT.busy("merge.resident.concat") as sp:
+        cat = CellBatch.concat(batches)
+        sp.cells = len(cat)
     h.cat, h.n = cat, len(cat)
     if h.n == 0:
         h.mode, h.result = "done", cat
@@ -257,31 +278,34 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
         h.fallback = dmerge.submit_merge(batches, gc_before, now,
                                          purgeable_ts_fn, prof=prof)
         return h
-    t0 = _time.perf_counter()
-    built = build_resident_operands(cat, gc_before, now, purgeable_ts_fn)
+    with _LED_RESIDENT.busy("merge.resident.pack", prof=prof, key="pack",
+                            cells=h.n) as sp:
+        built = build_resident_operands(cat, gc_before, now,
+                                        purgeable_ts_fn)
+        if built is not None:
+            operands, h.pts = built
+            if device is not None:
+                operands = {k: jax.device_put(v, device)
+                            for k, v in operands.items()}
+            # items = the padded cell count the program runs at
+            # (_bucket), nbytes = what the round pushes to the device
+            sp.items = int(operands["lanes"].shape[0])
+            sp.nbytes = sum(int(v.nbytes) for v in operands.values())
     if built is None:   # >= 4 GiB frame: let the host path fail loudly
         _resident_fallback(h.n, "frame exceeds the u32 offset lane")
         h.mode = "host"
         h.fallback = dmerge.submit_merge(batches, gc_before, now,
                                          purgeable_ts_fn, prof=prof)
         return h
-    operands, h.pts = built
-    if device is not None:
-        operands = {k: jax.device_put(v, device)
-                    for k, v in operands.items()}
-    t1 = _time.perf_counter()
-    h.out = _resident_program(operands)
-    from ..service.profiling import GLOBAL as _kprof
+    with _LED_RESIDENT.busy("merge.resident.dispatch") as sp:
+        h.out = _resident_program(operands)
     if _kprof.record_dispatch(
             "merge.resident",
             (int(operands["lanes"].shape[0]),
-             int(operands["lanes"].shape[1])),
-            _time.perf_counter() - t1):
+             int(operands["lanes"].shape[1])), sp.seconds):
         _kprof.maybe_record_cost("merge.resident", _resident_program,
                                  (operands,))
     h.mode = "resident"
-    if prof is not None:
-        prof["pack"] = prof.get("pack", 0.0) + (t1 - t0)
     return h
 
 
@@ -301,46 +325,40 @@ def collect_merge_resident(h: ResidentHandle):
         return promote_round(dmerge.collect_merge(h.fallback))
     cat, prof = h.cat, h.prof
     n_keep_d, n_amb_d, n_exp_d, perm_out_d, cols, perm_d, packed_d = h.out
-    t0 = _time.perf_counter()
-    n_keep = int(n_keep_d)          # blocks until the program finishes
-    n_amb = int(n_amb_d)
-    n_exp_kept = int(n_exp_d)
-    t1 = _time.perf_counter()
-    from ..service.profiling import GLOBAL as _kprof
-    _kprof.record_execute("merge.resident", t1 - t0)
-    if prof is not None:
-        prof["device"] = prof.get("device", 0.0) + (t1 - t0)
+    with _LED_RESIDENT.stall("merge.resident.wait", prof=prof,
+                             key="device") as sp:
+        n_keep = int(n_keep_d)      # blocks until the program finishes
+        n_amb = int(n_amb_d)
+        n_exp_kept = int(n_exp_d)
+    _kprof.record_execute("merge.resident", sp.seconds)
 
-    if n_amb or n_exp_kept:
-        # exact-resolution round: equal-(identity, ts) runs need the
-        # host's full-value tie-break, kept expired cells need the
-        # tombstone conversion's payload rewrite — materialize on the
-        # host exactly like ops/merge.py's v1/v2 collect
-        _resident_fallback(
-            h.n, "equal-(identity, ts) ties or kept expired-TTL cells")
-        n = h.n
-        perm = np.asarray(perm_d).astype(np.int64)[:n]
-        keep, amb, expired, shadowed = dmerge.unpack_masks(
-            np.asarray(packed_d)[:n])
-        pts_sorted = h.pts[perm] if h.pts is not None else None
-        if amb.any():
-            dmerge.host_tiebreak(cat, perm, keep, amb, shadowed,
-                                 expired, h.gc_before, pts_sorted)
-        out = dmerge.finalize_merged(cat, perm, keep, expired, shadowed)
-        if prof is not None:
-            prof["gather"] = prof.get("gather", 0.0) \
-                + (_time.perf_counter() - t1)
-        return promote_round(out)
+    with _LED_RESIDENT.busy("merge.resident.gather", prof=prof,
+                            key="gather", cells=n_keep):
+        if n_amb or n_exp_kept:
+            # exact-resolution round: equal-(identity, ts) runs need the
+            # host's full-value tie-break, kept expired cells need the
+            # tombstone conversion's payload rewrite — materialize on
+            # the host exactly like ops/merge.py's v1/v2 collect
+            _resident_fallback(
+                h.n, "equal-(identity, ts) ties or kept expired-TTL cells")
+            n = h.n
+            perm = np.asarray(perm_d).astype(np.int64)[:n]
+            keep, amb, expired, shadowed = dmerge.unpack_masks(
+                np.asarray(packed_d)[:n])
+            pts_sorted = h.pts[perm] if h.pts is not None else None
+            if amb.any():
+                dmerge.host_tiebreak(cat, perm, keep, amb, shadowed,
+                                     expired, h.gc_before, pts_sorted)
+            out = dmerge.finalize_merged(cat, perm, keep, expired,
+                                         shadowed)
+            return promote_round(out)
 
-    # resident round: pull ONLY the kept permutation (the payload
-    # gather's index vector) — the columns stay on the device
-    perm_kept = np.asarray(perm_out_d).astype(np.int64)[:n_keep]
-    payload, off, val_start = _gather_payload(cat, perm_kept)
-    if prof is not None:
-        prof["gather"] = prof.get("gather", 0.0) \
-            + (_time.perf_counter() - t1)
-    return DeviceRound(n_keep, cols, payload, off, val_start,
-                       dict(cat.pk_map), cat.ck_fits_prefix)
+        # resident round: pull ONLY the kept permutation (the payload
+        # gather's index vector) — the columns stay on the device
+        perm_kept = np.asarray(perm_out_d).astype(np.int64)[:n_keep]
+        payload, off, val_start = _gather_payload(cat, perm_kept)
+        return DeviceRound(n_keep, cols, payload, off, val_start,
+                           dict(cat.pk_map), cat.ck_fits_prefix)
 
 
 def promote_round(batch: CellBatch) -> DeviceRound:
@@ -424,25 +442,26 @@ class DeviceWriteLane:
         self.pk_map: dict = {}
 
     def append(self, r: DeviceRound) -> None:
-        import time as _time
-        t0 = _time.perf_counter()
         w = self.writer
-        if w.K is None:
-            w.K = int(r.cols["lanes"].shape[1])
-        w._ck_fits = w._ck_fits and r.ck_fits_prefix
-        take = {k: v[:r.n] for k, v in r.cols.items()}
-        if self.cols is None or self.pending == 0:
-            self.cols = take
-        else:
-            self.cols = {k: jnp.concatenate([self.cols[k][:self.pending],
-                                             take[k]])
-                         for k in RESIDENT_COLS}
-        self.pending += r.n
-        self.payloads.append((r.payload, r.off, r.val_start))
-        self.payload_cells += r.n
-        for k, v in r.pk_map.items():
-            self.pk_map[k] = v
-        w._acct("serialize", _time.perf_counter() - t0)
+        with w._span("serialize", "busy", "write.lane.append",
+                     key="serialize", cells=r.n) as sp:
+            if w.K is None:
+                w.K = int(r.cols["lanes"].shape[1])
+            w._ck_fits = w._ck_fits and r.ck_fits_prefix
+            take = {k: v[:r.n] for k, v in r.cols.items()}
+            sp.items = len(take)       # eager programs dispatched
+            if self.cols is None or self.pending == 0:
+                self.cols = take
+            else:
+                self.cols = {k: jnp.concatenate(
+                    [self.cols[k][:self.pending], take[k]])
+                    for k in RESIDENT_COLS}
+                sp.items += 2 * len(RESIDENT_COLS)
+            self.pending += r.n
+            self.payloads.append((r.payload, r.off, r.val_start))
+            self.payload_cells += r.n
+            for k, v in r.pk_map.items():
+                self.pk_map[k] = v
         while self.pending >= self.seg_cells:
             self._cut(self.seg_cells)
 
@@ -484,93 +503,71 @@ class DeviceWriteLane:
                                for payload, off, _vs in outs])
 
     def _cut(self, n: int) -> None:
-        import time as _time
         w = self.writer
-        t0 = _time.perf_counter()
-        seg = {k: self.cols[k][:n] for k in RESIDENT_COLS}
-        self.cols = {k: self.cols[k][n:] for k in RESIDENT_COLS}
-        self.pending -= n
-        lanes_np = np.ascontiguousarray(np.asarray(seg["lanes"]))
+        span = pipeline_ledger.span
+        full = n == self.seg_cells
+        # one `serialize` span per segment; its parts are children that
+        # bill nothing themselves (ring + trace only)
+        with w._span("serialize", "busy", "write.lane.cut",
+                     key="serialize", cells=n):
+            with span("write.lane.cut.slice",
+                      items=2 * len(RESIDENT_COLS)):
+                seg = {k: self.cols[k][:n] for k in RESIDENT_COLS}
+                self.cols = {k: self.cols[k][n:] for k in RESIDENT_COLS}
+                self.pending -= n
+            with span("write.lane.cut.pull_lanes") as sp:
+                lanes_np = np.ascontiguousarray(np.asarray(seg["lanes"]))
+                sp.nbytes = lanes_np.nbytes
+            if full:
+                # full segment: the fused kernel serializes + reduces
+                # stats in one device program; the host sees finished
+                # bytes
+                kargs = (seg["ts_h"], seg["ts_l"], seg["ldt"], seg["ttl"],
+                         seg["flags8"], seg["fl"], seg["vr"])
+                with span("write.lane.cut.kernel_dispatch") as sp:
+                    meta_d, st = _meta_block_kernel(*kargs)
+                if _kprof.record_dispatch("write.serialize", (n,),
+                                          sp.seconds):
+                    _kprof.maybe_record_cost(
+                        "write.serialize", _meta_block_kernel, kargs)
+                with span("write.lane.cut.kernel_pull") as sp:
+                    meta = np.asarray(meta_d)   # blocks on the kernel
+                    stats = (_uts_pair_to_i64(st[0], st[1]),
+                             _uts_pair_to_i64(st[2], st[3]),
+                             int(st[4]), int(st[5]), int(st[6]))
+                    sp.nbytes = meta.nbytes + 4 * len(st)
+                _kprof.record_execute("write.serialize", sp.seconds)
+            else:
+                # final partial segment: host assembly through the one
+                # shared META builder (byte-identical layout by
+                # definition)
+                from ..storage.sstable.writer import build_meta_block
+                with span("write.lane.cut.host_meta"):
+                    h = np.asarray(seg["ts_h"]).astype(np.uint64)
+                    l = np.asarray(seg["ts_l"]).astype(np.uint64)
+                    ts = ((h << np.uint64(32)) | l) ^ np.uint64(1 << 63)
+                    ts = ts.astype(np.int64)
+                    ldt = np.asarray(seg["ldt"])
+                    ttl = np.asarray(seg["ttl"])
+                    flags = np.asarray(seg["flags8"])
+                    meta = build_meta_block(
+                        ts, ldt, ttl, flags,
+                        np.asarray(seg["fl"]).astype("<u4"),
+                        np.asarray(seg["vr"]).astype("<u4"))
+                    stats = (int(ts.min()), int(ts.max()),
+                             int(ldt.min()), int(ldt.max()),
+                             int(((flags & DEATH_FLAGS) != 0).sum()))
+            with span("write.lane.cut.payload") as sp:
+                payload_np = self._take_payload(n)
+                sp.nbytes = payload_np.nbytes
         dc_state = None
-        dc_compress_s = 0.0
-        if n == self.seg_cells:
-            # full segment: the fused kernel serializes + reduces stats
-            # in one device program; the host sees finished bytes
-            t_k = _time.perf_counter()
-            meta_d, st = _meta_block_kernel(
-                seg["ts_h"], seg["ts_l"], seg["ldt"], seg["ttl"],
-                seg["flags8"], seg["fl"], seg["vr"])
-            from ..service.profiling import GLOBAL as _kprof
-            if _kprof.record_dispatch("write.serialize", (n,),
-                                      _time.perf_counter() - t_k):
-                _kprof.maybe_record_cost(
-                    "write.serialize", _meta_block_kernel,
-                    (seg["ts_h"], seg["ts_l"], seg["ldt"], seg["ttl"],
-                     seg["flags8"], seg["fl"], seg["vr"]))
-            t_k = _time.perf_counter()
-            meta = np.asarray(meta_d)
-            _kprof.record_execute("write.serialize",
-                                  _time.perf_counter() - t_k)
-            stats = (_uts_pair_to_i64(st[0], st[1]),
-                     _uts_pair_to_i64(st[2], st[3]),
-                     int(st[4]), int(st[5]), int(st[6]))
-            if w._device_compress_now():
-                # second fused program: lane shuffle + order check +
-                # the policy match scans; the host keeps only the LZ4
-                # wire emission (O(sequences)) and the pwrite pump
-                t_c = _time.perf_counter()
-                try:
-                    planes_d, mbl, mbd, lbl, lbd, order_ok = \
-                        device_compress.segment_scan_kernel(
-                            meta_d, seg["lanes"])
-                    if _kprof.record_dispatch(
-                            "write.compress", (n,),
-                            _time.perf_counter() - t_c):
-                        _kprof.maybe_record_cost(
-                            "write.compress",
-                            device_compress.segment_scan_kernel,
-                            (meta_d, seg["lanes"]))
-                    t_e = _time.perf_counter()
-                    ok = bool(order_ok)
-                    planes_np = np.asarray(planes_d)
-                    scans = ((np.asarray(mbl), np.asarray(mbd)),
-                             (np.asarray(lbl), np.asarray(lbd)))
-                    _kprof.record_execute("write.compress",
-                                          _time.perf_counter() - t_e)
-                except Exception as e:
-                    # per-segment fallback: the host compress leg takes
-                    # this one; output bytes identical either way
-                    from ..service.metrics import GLOBAL as _METRICS
-                    _METRICS.incr("compaction.device_compress_fallback")
-                    warn_once(_log, "write.compress.fallback",
-                              "device compress kernel failed, host "
-                              "compress leg takes the segment: %r", e)
-                else:
-                    if not ok:
-                        raise ValueError("appended cells out of order")
-                    dc_state = (planes_np, scans)
-                dc_compress_s = _time.perf_counter() - t_c
-        else:
-            # final partial segment: host assembly through the one
-            # shared META builder (byte-identical layout by definition)
-            from ..storage.sstable.writer import build_meta_block
-            h = np.asarray(seg["ts_h"]).astype(np.uint64)
-            l = np.asarray(seg["ts_l"]).astype(np.uint64)
-            ts = ((h << np.uint64(32)) | l) ^ np.uint64(1 << 63)
-            ts = ts.astype(np.int64)
-            ldt = np.asarray(seg["ldt"])
-            ttl = np.asarray(seg["ttl"])
-            flags = np.asarray(seg["flags8"])
-            meta = build_meta_block(ts, ldt, ttl, flags,
-                                    np.asarray(seg["fl"]).astype("<u4"),
-                                    np.asarray(seg["vr"]).astype("<u4"))
-            stats = (int(ts.min()), int(ts.max()),
-                     int(ldt.min()), int(ldt.max()),
-                     int(((flags & DEATH_FLAGS) != 0).sum()))
-        payload_np = self._take_payload(n)
-        w._acct("serialize", _time.perf_counter() - t0 - dc_compress_s)
-        if dc_compress_s:
-            w._acct("compress", dc_compress_s)
+        if full and w._device_compress_now():
+            # second fused program: lane shuffle + order check + the
+            # policy match scans; the host keeps only the LZ4 wire
+            # emission (O(sequences)) and the pwrite pump
+            with w._span("compress", "busy", "write.lane.device_compress",
+                         key="compress", cells=n):
+                dc_state = self._device_compress(n, meta_d, seg["lanes"])
         device_pack = None
         if dc_state is not None:
             planes_np, scans = dc_state
@@ -581,3 +578,35 @@ class DeviceWriteLane:
                     _m, _p, _s, _pl, attempt, maxlen)
         w._emit_segment(n, meta, lanes_np, payload_np, self.pk_map,
                         stats, device_pack=device_pack)
+
+    @staticmethod
+    def _device_compress(n: int, meta_d, lanes_d):
+        """(planes, scans) of one full segment compressed on the device,
+        or None when the kernel failed and the host compress leg takes
+        the segment (counted; output bytes identical either way)."""
+        span = pipeline_ledger.span
+        try:
+            with span("write.lane.device_compress.dispatch") as sp:
+                planes_d, mbl, mbd, lbl, lbd, order_ok = \
+                    device_compress.segment_scan_kernel(meta_d, lanes_d)
+            if _kprof.record_dispatch("write.compress", (n,), sp.seconds):
+                _kprof.maybe_record_cost(
+                    "write.compress",
+                    device_compress.segment_scan_kernel,
+                    (meta_d, lanes_d))
+            with span("write.lane.device_compress.pull") as sp:
+                ok = bool(order_ok)
+                planes_np = np.asarray(planes_d)
+                scans = ((np.asarray(mbl), np.asarray(mbd)),
+                         (np.asarray(lbl), np.asarray(lbd)))
+            _kprof.record_execute("write.compress", sp.seconds)
+        except Exception as e:
+            from ..service.metrics import GLOBAL as _METRICS
+            _METRICS.incr("compaction.device_compress_fallback")
+            warn_once(_log, "write.compress.fallback",
+                      "device compress kernel failed, host "
+                      "compress leg takes the segment: %r", e)
+            return None
+        if not ok:
+            raise ValueError("appended cells out of order")
+        return planes_np, scans

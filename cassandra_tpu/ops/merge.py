@@ -187,11 +187,13 @@ def _reconcile_core(lanes, ts_h, ts_l, valid, ldt, expiring, is_cd,
                   jnp.where(is_cd, _le_pair(ts_h, ts_l, del_h, del_l),
                             False)))
 
-    # ---- TTL expiry + purge
-    expired = expiring & (ldt <= now)
-    death_eff = death | expired
-    purgeable = _lt_pair(ts_h, ts_l, purge_h, purge_l)
-    purged = death_eff & (ldt < gc_before) & purgeable
+    # ---- TTL expiry + purge (named_scope: op names in a profiler
+    # trace, metadata only)
+    with jax.named_scope("purge"):
+        expired = expiring & (ldt <= now)
+        death_eff = death | expired
+        purgeable = _lt_pair(ts_h, ts_l, purge_h, purge_l)
+        purged = death_eff & (ldt < gc_before) & purgeable
 
     keep = winner & ~shadowed & ~purged
 

@@ -45,6 +45,8 @@ from collections import deque
 # ring capacity per event type: enough context to reconstruct the
 # run-up to an incident without holding the process's history hostage
 RING_PER_TYPE = 128
+# records of the span ring's tail a flight bundle carries
+BUNDLE_SPAN_TAIL = 2048
 
 
 class DiagnosticEvent:
@@ -332,6 +334,10 @@ class FlightRecorder:
             # where-did-the-wall-go surface
             from ..utils import pipeline_ledger
             bundle["pipeline_ledger"] = pipeline_ledger.snapshot_all()
+            # the span ring's tail beside it: what each named thread was
+            # doing, span by span, in the seconds before the dump
+            bundle["pipeline_spans"] = pipeline_ledger.ring_records(
+                tail=BUNDLE_SPAN_TAIL)
         except Exception:
             pass
         try:

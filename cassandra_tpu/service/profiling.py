@@ -32,7 +32,10 @@ is the accounting layer:
       cost, so the wrapper passes straight through.
   add_phases({phase: seconds})
       folds a CompactionTask.profile (io_decode / merge / pack / device /
-      gather / compress / io_write / seal) into the process aggregate.
+      gather / compress / io_write / seal, and the waits and finer
+      phases the span primitive added) into the process aggregate. The
+      seconds are the spans' own (utils/pipeline_ledger.py): nothing
+      here times a phase.
 
 Per-program shape keys are tracked in a bounded LRU (SHAPE_CAP): under
 shape-bucket churn the set no longer grows without bound; an evicted
@@ -227,18 +230,16 @@ class DeviceProgramRegistry:
                     + float(seconds)
 
     def snapshot(self) -> dict:
-        """{"kernels": {name: {calls, compiles, shapes, shape_count,
+        """{"kernels": {name: {calls, compiles, shape_count,
         shape_evictions, retraces, compile_s, dispatch_s, execute_s,
-        cost_flops, cost_bytes}}, "phases": {name: seconds}}. `shapes`
-        (== shape_count, the LIVE tracked-shape count) is kept for the
-        pre-registry consumers."""
+        cost_flops, cost_bytes}}, "phases": {name: seconds}}.
+        `shape_count` is the LIVE tracked-shape count."""
         with self._lock:
             kernels = {}
             for name, k in self._kernels.items():
                 cost = k["cost"] or {}
                 kernels[name] = {
                     "calls": k["calls"], "compiles": k["compiles"],
-                    "shapes": len(k["shapes"]),
                     "shape_count": len(k["shapes"]),
                     "shape_evictions": k["shape_evictions"],
                     "retraces": k["retraces"],
@@ -256,9 +257,5 @@ class DeviceProgramRegistry:
             self._kernels.clear()
             self._phases.clear()
 
-
-# pre-registry name: the original compile/dispatch/execute accountant,
-# kept so existing imports and tests keep meaning the same object
-KernelProfiler = DeviceProgramRegistry
 
 GLOBAL = DeviceProgramRegistry()

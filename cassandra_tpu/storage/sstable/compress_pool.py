@@ -30,7 +30,6 @@ import os
 import queue
 import threading
 from ...utils import lockwitness
-import time
 
 
 def auto_workers() -> int:
@@ -65,6 +64,7 @@ class CompressorPool:
         self._target = max(int(workers), 1)
         self._shutdown = False
         self._jobs = 0
+        self._spawned = 0   # workers ever started: the index in a name
         # unified pipeline ledger stage: worker-side busy seconds +
         # jobs, inbound-queue high-water at submit. Every pool
         # (shared or pinned) accumulates into the one process stage —
@@ -94,21 +94,27 @@ class CompressorPool:
 
     def _spawn_locked(self) -> None:
         while len(self._workers) < self._target:
+            # an index per worker: the span ring and trace tools key
+            # on thread names
             w = threading.Thread(target=self._work_loop,
-                                 name=f"{self.name}-w", daemon=True)
+                                 name=f"{self.name}-w{self._spawned}",
+                                 daemon=True)
+            self._spawned += 1
             self._workers.append(w)
             w.start()
 
     # ---------------------------------------------------------- submit --
 
-    def submit(self, fn) -> None:
+    def submit(self, fn, task: int = 0) -> None:
         """Queue fn() for a worker. fn must trap its own exceptions
         into its result slot (SSTableWriter._PackJob.error) — the pool
-        only guarantees fn runs exactly once."""
+        only guarantees fn runs exactly once. task: the id of the
+        compaction task the job belongs to; it travels with the job to
+        the worker's span."""
         with self._lock:
             if self._shutdown:
                 raise RuntimeError("compressor pool is shut down")
-            self._q.put(fn)
+            self._q.put((fn, task))
             self._stage.note_queue(self._q.qsize())
             self._spawn_locked()
 
@@ -127,20 +133,26 @@ class CompressorPool:
         writer's ordered completion queue re-sequences them regardless
         of who ran them."""
         try:
-            fn = self._q.get_nowait()
+            job = self._q.get_nowait()
         except queue.Empty:
             return False
-        t0 = time.perf_counter()
+        self._run(*job)
+        return True
+
+    def _run(self, fn, task: int) -> None:
+        """One job on the calling thread, billed to the pack stage as
+        one `compress_pool.pack` span. Jobs own their error channel: a
+        raise here is a job bug, and one bad job must not retire a
+        shared worker."""
         try:
-            fn()
+            with self._stage.busy(task=task):
+                fn()
         except BaseException:
-            pass   # jobs own their error channel (see _work_loop)
+            pass
         finally:
-            self._stage.add_busy(time.perf_counter() - t0)
             self._stage.add_items(1)
             with self._lock:
                 self._jobs += 1
-        return True
 
     @property
     def jobs_completed(self) -> int:
@@ -155,21 +167,10 @@ class CompressorPool:
                         self._workers.remove(me)
                     return
             try:
-                fn = self._q.get(timeout=self.POLL_SECONDS)
+                job = self._q.get(timeout=self.POLL_SECONDS)
             except queue.Empty:
                 continue
-            t0 = time.perf_counter()
-            try:
-                fn()
-            except BaseException:
-                # jobs own their error channel; a raise here is a job
-                # bug, and one bad job must not retire a shared worker
-                pass
-            finally:
-                self._stage.add_busy(time.perf_counter() - t0)
-                self._stage.add_items(1)
-                with self._lock:
-                    self._jobs += 1
+            self._run(*job)
 
     def shutdown(self, timeout: float = 10.0) -> None:
         with self._lock:
@@ -182,7 +183,7 @@ class CompressorPool:
         # them here always unblocks a mid-flight writer
         while True:
             try:
-                fn = self._q.get_nowait()
+                fn, _task = self._q.get_nowait()
             except queue.Empty:
                 break
             try:

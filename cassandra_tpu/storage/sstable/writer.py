@@ -33,14 +33,13 @@ import os
 import queue
 import struct
 import threading
-import time
 import zlib
 
 import numpy as np
 
 from ...ops.codec import CompressionParams, SegmentPacker, lanes_shuffle
 from ...schema import TableMetadata
-from ...utils import bloom, faultfs
+from ...utils import bloom, faultfs, pipeline_ledger
 from ...utils.logonce import warn_once
 from ..cellbatch import CellBatch
 from .format import SEGMENT_CELLS, Component, Descriptor
@@ -229,18 +228,21 @@ class SSTableWriter:
         self._wq = None
         self._metrics = None
         self._ledger = None
+        # the task (compaction) this writer's spans belong to: the one
+        # whose thread constructs it; 0 for a flush or a bulk load
+        self._task = pipeline_ledger.current_task()
         if metrics_group:
             from ...service.metrics import GLOBAL as _METRICS
             self._metrics = _METRICS.group(metrics_group)
             # unified pipeline ledger (utils/pipeline_ledger.py): the
             # write leg's stages accumulate process-wide under the
             # pipeline named after the metrics group — serialize /
-            # compress / io_write busy seconds, producer stalls and the
-            # staging-queue high-water all land there
-            from ...utils import pipeline_ledger
+            # directory / compress / io_write busy seconds, producer
+            # stalls and the staging-queue high-water all land there
             led = pipeline_ledger.ledger(metrics_group)
             self._ledger = {
                 "serialize": led.stage("serialize"),
+                "directory": led.stage("directory"),
                 "compress": led.stage("compress"),
                 "io_write": led.stage("io_write"),
             }
@@ -480,13 +482,15 @@ class SSTableWriter:
             # fs without fallocate support: fall back to plain extend
             self._allocated = 1 << 62
 
-    def _acct(self, key: str, dt: float) -> None:
-        if self.prof is not None:
-            self.prof[key] = self.prof.get(key, 0.0) + dt
-        if self._ledger is not None:
-            st = self._ledger.get(key)
-            if st is not None:
-                st.add_busy(dt)
+    def _span(self, stage: str | None, kind: str, name: str,
+              key: str | None = None, **attrs):
+        """One span of this writer's write leg (pipeline_ledger.Span):
+        bills the ledger stage `stage` of the writer's pipeline, where
+        it reports to one, and the bound profile under `key`; carries
+        the writer's task id to whichever thread runs it."""
+        st = self._ledger.get(stage) if self._ledger is not None else None
+        return pipeline_ledger.Span(name, kind, st, prof=self.prof,
+                                    key=key, task=self._task, **attrs)
 
     def _write_all(self, mv: memoryview, reclaim=None) -> None:
         """Hand a compressed run of bytes to the data file. In threaded
@@ -506,11 +510,14 @@ class SSTableWriter:
             if self._ledger is not None:
                 self._ledger["io_write"].note_queue(self._wq.qsize())
             return
-        t0 = time.perf_counter()
-        self._write_sync(mv)
-        self._acct("io_write", time.perf_counter() - t0)
+        self._write_timed(mv)
 
-    def _steal_wait(self, take_nowait, take_blocking):
+    def _write_timed(self, mv: memoryview) -> None:
+        with self._span("io_write", "busy", "write.io", key="io_write",
+                        nbytes=mv.nbytes):
+            self._write_sync(mv)
+
+    def _steal_wait(self, name: str, take_nowait, take_blocking):
         """Producer-side wait with caller work-stealing: while the
         wanted resource is unavailable, run queued pack jobs inline
         (CompressorPool.try_run_one) instead of sleeping — the blocked
@@ -518,7 +525,9 @@ class SSTableWriter:
         unblocks it. Returns (value, genuine_stall_seconds): time spent
         stealing is compress BUSY work (billed by the pool's pack
         stage), not backpressure, so only the blocking remainder counts
-        as stall."""
+        as stall — each blocking take is one stall span `name`, billed
+        to the compress stage being waited ON and to the profile's
+        `write_stall` (bench.py's write_phase attribution reads it)."""
         stall = 0.0
         while True:
             try:
@@ -529,12 +538,13 @@ class SSTableWriter:
                 raise self._io_error[0]
             if self._cpool is not None and self._cpool.try_run_one():
                 continue
-            t0 = time.perf_counter()
+            sp = self._span("compress", "stall", name, key="write_stall")
             try:
-                return take_blocking(), \
-                    stall + time.perf_counter() - t0
+                with sp:
+                    value = take_blocking()
+                return value, stall + sp.seconds
             except queue.Empty:
-                stall += time.perf_counter() - t0
+                stall += sp.seconds
 
     def _take_pack_buf(self, need: int) -> "np.ndarray":
         """Borrow a pack buffer from the free pool (blocks when all are
@@ -548,20 +558,11 @@ class SSTableWriter:
             if self._metrics is not None:
                 self._metrics.incr("compress_stalls")
             buf, dt = self._steal_wait(
+                "write.emit.buffer_wait",
                 self._pack_free.get_nowait,
                 lambda: self._pack_free.get(timeout=0.05))
-            if dt > 0 and self.prof is not None:
-                # producer wall genuinely blocked on the write leg —
-                # bench.py's write_phase attribution reads this
-                self.prof["write_stall"] = \
-                    self.prof.get("write_stall", 0.0) + dt
             if self._metrics is not None and dt > 0:
                 self._metrics.hist("compress_stall").update_us(dt * 1e6)
-                if self._ledger is not None:
-                    # producer blocked on the compress+io stages: the
-                    # backpressure seconds the ledger attributes to the
-                    # stage being waited ON
-                    self._ledger["compress"].add_stall(dt)
         if buf.nbytes < need:
             buf = np.empty(need, dtype=np.uint8)
         return buf
@@ -578,7 +579,6 @@ class SSTableWriter:
         outcome_{k-LAG}) sequence, the decisions — and therefore the
         stored bytes — are identical for any pool size."""
         k = self._seq_submitted
-        stall_s = 0.0
         stalled = False
         while self._seq_applied <= k - self.SKIP_DECISION_LAG:
             if self._io_error:
@@ -593,21 +593,15 @@ class SSTableWriter:
                     stalled = True
                     if self._metrics is not None:
                         self._metrics.incr("compress_stalls")
-                out, dt = self._steal_wait(
+                out, _dt = self._steal_wait(
+                    "write.emit.attempt_wait",
                     self._acct_outcomes.get_nowait,
                     lambda: self._acct_outcomes.get(timeout=0.05))
-                stall_s += dt
             if out is _ACCT_FAILED:
                 raise self._io_error[0] if self._io_error else \
                     RuntimeError("compress pipeline failed")
             self._apply_outcome(out)
             self._seq_applied += 1
-        if stall_s > 0:
-            if self.prof is not None:
-                self.prof["write_stall"] = \
-                    self.prof.get("write_stall", 0.0) + stall_s
-            if self._ledger is not None:
-                self._ledger["compress"].add_stall(stall_s)
         attempt = []
         for i in range(3):
             if self._skip_left[i] > 0:
@@ -691,14 +685,15 @@ class SSTableWriter:
         if job.trace is not None:
             job.trace.add(f"Compress pool: segment {job.seq} submitted "
                           f"({job.n} cells)")
-        self._cpool.submit(lambda: self._run_pack_job(job))
+        self._cpool.submit(lambda: self._run_pack_job(job),
+                           task=self._task)
         self._wq.put(job)   # single producer: queue order == seq order
         if self._ledger is not None:
             self._ledger["compress"].note_queue(self._wq.qsize())
 
     def _submit_packed(self, blocks: list, attempt: list[bool],
                        need: int, n: int, lane_head: bytes,
-                       lane_tail: bytes, packed, t0: float) -> None:
+                       lane_tail: bytes, packed) -> None:
         """Enqueue a segment the device already compressed: the job
         enters the SAME ordered completion queue as pool jobs, born
         finished (ready pre-set, stored bytes staged in a pack buffer),
@@ -738,7 +733,6 @@ class SSTableWriter:
         job.total = int(total)
         job.sizes = sizes
         job.crcs = crcs
-        job.compress_s = time.perf_counter() - t0
         job.blocks = None
         job.ready.set()
         self._wq.put(job)   # single producer: queue order == seq order
@@ -757,14 +751,20 @@ class SSTableWriter:
                 # sstable.compress checkpoint: an injected EIO here must
                 # fail the writer like a real compressor/allocator fault
                 faultfs.GLOBAL.check("sstable.compress", self._data_path)
-            t0 = time.perf_counter()
-            total, sizes, _raws, crcs = self._packer.pack(
-                job.blocks, job.attempt, self.params.max_compressed_length,
-                shuffle_block=1, lane_width=self.K, out=job.buf)
+            # one pack job = one span on the pool worker (or on the
+            # thread that stole the job): cells = bytes in, nbytes out
+            with self._span("compress", "busy", "write.compress",
+                            key="compress", cells=sum(job.raw_lens),
+                            items=job.seq) as sp:
+                total, sizes, _raws, crcs = self._packer.pack(
+                    job.blocks, job.attempt,
+                    self.params.max_compressed_length,
+                    shuffle_block=1, lane_width=self.K, out=job.buf)
+                sp.nbytes = int(total)
             job.total = total
             job.sizes = sizes
             job.crcs = crcs
-            job.compress_s = time.perf_counter() - t0
+            job.compress_s = sp.seconds
         except BaseException as e:
             job.error = e
         finally:
@@ -806,15 +806,12 @@ class SSTableWriter:
                 entry += job.lane_head + job.lane_tail
                 self._index_entries.append(entry)
                 self._acct_outcomes.put(tuple(outcome))
-                self._acct("compress", job.compress_s)
                 if job.trace is not None:
                     job.trace.add(
                         f"Compress pool: segment {job.seq} packed "
                         f"({job.total} bytes, "
                         f"{job.compress_s * 1e3:.1f} ms)")
-                t0 = time.perf_counter()
-                self._write_sync(memoryview(job.buf)[:job.total])
-                self._acct("io_write", time.perf_counter() - t0)
+                self._write_timed(memoryview(job.buf)[:job.total])
                 self._data_off += job.total
                 self._published_off = self._data_off
                 self._pack_free.put(job.buf)
@@ -847,10 +844,8 @@ class SSTableWriter:
                 if item is None:
                     return
                 buf, reclaim = item
-                t0 = time.perf_counter()
-                self._write_sync(memoryview(buf) if not
-                                 isinstance(buf, memoryview) else buf)
-                self._acct("io_write", time.perf_counter() - t0)
+                self._write_timed(memoryview(buf) if not
+                                  isinstance(buf, memoryview) else buf)
                 if reclaim is not None:
                     self._pack_free.put(reclaim)
         except BaseException as e:
@@ -1040,27 +1035,27 @@ class SSTableWriter:
         # they replace, and far more compressible (small near-constant
         # integers); the ts lane is delta'd per segment for the same
         # reason (format.py "ce")
-        t_ser = time.perf_counter()
-        deltas = seg.off[1:] - seg.off[:-1]
-        vrel64 = seg.val_start - seg.off[:-1]
-        if len(deltas) and (int(deltas.max()) >= 1 << 32
-                            or int(vrel64.max()) >= 1 << 32):
-            # u32 lanes cannot hold a >=4GiB frame — fail loudly
-            # instead of wrapping into silent corruption
-            raise ValueError(
-                f"cell frame exceeds the u32 offset lane "
-                f"(max frame {int(deltas.max())} bytes)")
-        meta = build_meta_block(seg.ts.astype(np.int64, copy=False),
-                                seg.ldt, seg.ttl, seg.flags,
-                                deltas.astype("<u4"),
-                                vrel64.astype("<u4"))
-        payload_b = np.ascontiguousarray(seg.payload)
-        lanes_c = np.ascontiguousarray(seg.lanes)
-        from ..cellbatch import DEATH_FLAGS
-        seg_stats = (int(seg.ts.min()), int(seg.ts.max()),
-                     int(seg.ldt.min()), int(seg.ldt.max()),
-                     int(((seg.flags & DEATH_FLAGS) != 0).sum()))
-        self._acct("serialize", time.perf_counter() - t_ser)
+        with self._span("serialize", "busy", "write.serialize",
+                        key="serialize", cells=n):
+            deltas = seg.off[1:] - seg.off[:-1]
+            vrel64 = seg.val_start - seg.off[:-1]
+            if len(deltas) and (int(deltas.max()) >= 1 << 32
+                                or int(vrel64.max()) >= 1 << 32):
+                # u32 lanes cannot hold a >=4GiB frame — fail loudly
+                # instead of wrapping into silent corruption
+                raise ValueError(
+                    f"cell frame exceeds the u32 offset lane "
+                    f"(max frame {int(deltas.max())} bytes)")
+            meta = build_meta_block(seg.ts.astype(np.int64, copy=False),
+                                    seg.ldt, seg.ttl, seg.flags,
+                                    deltas.astype("<u4"),
+                                    vrel64.astype("<u4"))
+            payload_b = np.ascontiguousarray(seg.payload)
+            lanes_c = np.ascontiguousarray(seg.lanes)
+            from ..cellbatch import DEATH_FLAGS
+            seg_stats = (int(seg.ts.min()), int(seg.ts.max()),
+                         int(seg.ldt.min()), int(seg.ldt.max()),
+                         int(((seg.flags & DEATH_FLAGS) != 0).sum()))
         self._emit_segment(n, meta, lanes_c, payload_b, seg.pk_map,
                            seg_stats)
 
@@ -1121,6 +1116,27 @@ class SSTableWriter:
         identical attempt vectors; any failure falls back to the host
         compress leg for THIS segment (counted, never fatal, bytes
         identical)."""
+        with pipeline_ledger.span("write.emit", task=self._task, cells=n):
+            # everything up to the attempt decision was on no phase's
+            # books before the span primitive timed it
+            with self._span("directory", "busy", "write.emit.directory",
+                            key="directory", cells=n) as sp:
+                sp.items = self._index_segment(
+                    n, meta, lanes_c, payload_b, pk_map, seg_stats)
+            attempt = self._decide_attempt()
+            with pipeline_ledger.span("write.emit.submit"):
+                self._pack_segment(n, meta, lanes_c, payload_b, attempt,
+                                   device_pack)
+            self._total_cells += n
+            self._last_lane_end = lanes_c[-1].astype(">u4").tobytes()
+
+    def _index_segment(self, n: int, meta: "np.ndarray",
+                       lanes_c: "np.ndarray", payload_b: "np.ndarray",
+                       pk_map: dict, seg_stats: tuple) -> int:
+        """The sequential bookkeeping of one segment, in append order on
+        the appending thread: ordering guard, zone map, partition
+        directory + bloom (one Python iteration per partition START in
+        the segment), stats fold. Returns the partitions it opened."""
         # cross-segment ordering guard; the intra-segment check runs
         # inside segment_pack's delta loop (fast path) or the numpy
         # comparison below (fallback path)
@@ -1178,12 +1194,24 @@ class SSTableWriter:
         _lo("min_ldt", mn_ldt)
         _hi("max_ldt", mx_ldt)
         self._stats["tombstones"] += tombs
+        return len(new_keys)
 
-        attempt = self._decide_attempt()
+    def _pack_segment(self, n: int, meta: "np.ndarray",
+                      lanes_c: "np.ndarray", payload_b: "np.ndarray",
+                      attempt: list[bool], device_pack) -> None:
+        """Compress-and-write of one segment: handed to the pool
+        (parallel mode), or compressed here and written or staged
+        (serial / threaded_io), or the per-block fallback."""
         maxlen = self.params.max_compressed_length
         lane_head = lanes_c[0].astype("<u4").tobytes()
         lane_tail = lanes_c[-1].astype("<u4").tobytes()
-        t_pack = time.perf_counter()
+
+        def compress_span():
+            # serial legs compress on this thread: one `write.compress`
+            # span (bytes in, bytes out) up to the outcome's publication
+            return self._span("compress", "busy", "write.compress",
+                              key="compress", cells=meta.nbytes
+                              + lanes_c.nbytes + payload_b.nbytes)
 
         if self._packer is not None:
             # fused native path: delta + order check + compress-or-raw +
@@ -1193,7 +1221,11 @@ class SSTableWriter:
             packed = None
             if device_pack is not None:
                 try:
-                    packed = device_pack(attempt, maxlen)
+                    # the device lane's host half (LZ4 wire emission)
+                    with self._span("compress", "busy",
+                                    "write.compress.device_pack",
+                                    key="compress"):
+                        packed = device_pack(attempt, maxlen)
                 except Exception as e:
                     # per-segment fallback: the host leg compresses this
                     # one; output bytes identical (same policy encoder)
@@ -1205,9 +1237,7 @@ class SSTableWriter:
                     packed = None
             if packed is not None and self._cpool is not None:
                 self._submit_packed(blocks, attempt, need, n,
-                                    lane_head, lane_tail, packed, t_pack)
-                self._total_cells += n
-                self._last_lane_end = lanes_c[-1].astype(">u4").tobytes()
+                                    lane_head, lane_tail, packed)
                 return
             if self._cpool is not None:
                 # parallel leg: the pool compresses this segment while
@@ -1217,8 +1247,6 @@ class SSTableWriter:
                 # entries append in seq order over there, cells here)
                 self._submit_pack(blocks, attempt, need, n,
                                   lane_head, lane_tail)
-                self._total_cells += n
-                self._last_lane_end = lanes_c[-1].astype(">u4").tobytes()
                 return
             entry = struct.pack("<QI", self._data_off, n)
             if packed is not None:
@@ -1233,7 +1261,6 @@ class SSTableWriter:
                                               int(crcs[i]))
                     outcome.append((stored, blocks[i].nbytes, attempt[i]))
                 self._acct_outcomes.put(tuple(outcome))
-                self._acct("compress", time.perf_counter() - t_pack)
                 if self._ledger is not None:
                     self._ledger["compress"].add_items(1, need)
                 if self._metrics is not None:
@@ -1242,23 +1269,26 @@ class SSTableWriter:
                 self._data_off += int(total)
                 self._published_off = self._data_off
             else:
-                if self._threaded_io:
-                    out = self._take_pack_buf(need)
-                else:
-                    if self._pack_out is None or self._pack_out.nbytes < need:
-                        self._pack_out = np.empty(need, dtype=np.uint8)
-                    out = self._pack_out
-                total, sizes, raws, crcs = self._packer.pack(
-                    blocks, attempt, maxlen, shuffle_block=1,
-                    lane_width=lanes_c.shape[1], out=out)
-                outcome = []
-                for i in range(3):
-                    stored = int(sizes[i])
-                    entry += self._fold_block(stored, blocks[i].nbytes,
-                                              int(crcs[i]))
-                    outcome.append((stored, blocks[i].nbytes, attempt[i]))
-                self._acct_outcomes.put(tuple(outcome))
-                self._acct("compress", time.perf_counter() - t_pack)
+                with compress_span() as compress:
+                    if self._threaded_io:
+                        out = self._take_pack_buf(need)
+                    else:
+                        if self._pack_out is None \
+                                or self._pack_out.nbytes < need:
+                            self._pack_out = np.empty(need, dtype=np.uint8)
+                        out = self._pack_out
+                    total, sizes, raws, crcs = self._packer.pack(
+                        blocks, attempt, maxlen, shuffle_block=1,
+                        lane_width=lanes_c.shape[1], out=out)
+                    compress.nbytes = int(total)
+                    outcome = []
+                    for i in range(3):
+                        stored = int(sizes[i])
+                        entry += self._fold_block(
+                            stored, blocks[i].nbytes, int(crcs[i]))
+                        outcome.append(
+                            (stored, blocks[i].nbytes, attempt[i]))
+                    self._acct_outcomes.put(tuple(outcome))
                 if self._ledger is not None:
                     self._ledger["compress"].add_items(1, need)
                 self._write_all(memoryview(out)[:total],
@@ -1274,8 +1304,8 @@ class SSTableWriter:
                 lanes_c.astype(np.uint32, copy=False))
             blocks = [meta, lanes_b, payload_b]
             tried = [b for b, a in zip(blocks, attempt) if a]
-            dst, dst_offs, sizes = self.compressor.compress_iov(tried)
-            self._acct("compress", time.perf_counter() - t_pack)
+            with compress_span():
+                dst, dst_offs, sizes = self.compressor.compress_iov(tried)
             # min_compress_ratio fallback: store uncompressed when too
             # poor (CompressedSequentialWriter.java:160-175 semantics)
             ti = 0
@@ -1304,8 +1334,6 @@ class SSTableWriter:
         entry += lane_head
         entry += lane_tail
         self._index_entries.append(entry)
-        self._total_cells += n
-        self._last_lane_end = lanes_c[-1].astype(">u4").tobytes()
 
     _last_lane_end: bytes | None = None
 

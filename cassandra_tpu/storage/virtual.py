@@ -379,7 +379,8 @@ def build_engine_virtuals(engine) -> VirtualSchema:
         snap = kprof.snapshot()
         for name, k in sorted(snap["kernels"].items()):
             yield {"name": name, "kind": "kernel", "calls": k["calls"],
-                   "compiles": k["compiles"], "shapes": k["shapes"],
+                   "compiles": k["compiles"],
+                   "shapes": k["shape_count"],
                    "compile_seconds": k["compile_s"],
                    "dispatch_seconds": k["dispatch_s"],
                    "execute_seconds": k["execute_s"]}

@@ -43,12 +43,11 @@ import socket
 import ssl
 import struct
 import threading
-import time
 
 from ..cql.processor import QueryProcessor
 from ..service.metrics import GLOBAL as METRICS
 from ..utils.ratelimit import RateLimiter
-from ..utils import lockwitness
+from ..utils import lockwitness, pipeline_ledger
 from .admission import OverloadSignals, PermitGate
 from .frame import (CONSISTENCY_NAMES, ERR_BAD_CREDENTIALS, ERR_INVALID,
                     ERR_OVERLOADED, ERR_PROTOCOL, ERR_SERVER, EVENT_TYPES,
@@ -521,13 +520,15 @@ class _Dispatcher:
     def __init__(self, server: "CQLServer", n_threads: int):
         self.server = server
         self.queue: queue_mod.Queue = queue_mod.Queue()
-        # unified pipeline ledger stage (utils/pipeline_ledger.py):
-        # busy = request execution, idle = workers parked on an empty
-        # queue, queue_hwm = dispatch backlog high-water — the
-        # front-door leg of the where-did-the-wall-go table
-        from ..utils import pipeline_ledger
-        self._stage = pipeline_ledger.ledger("transport") \
-            .stage("dispatch")
+        # unified pipeline ledger stages (utils/pipeline_ledger.py):
+        # `dispatch` busy = request execution (one `transport.request`
+        # span each), idle = workers parked on an empty queue,
+        # queue_hwm = dispatch backlog high-water; `queue` stall = the
+        # seconds requests waited between submit and a worker taking
+        # them — the front-door leg of the where-did-the-wall-go table
+        led = pipeline_ledger.ledger("transport")
+        self._stage = led.stage("dispatch")
+        self._queue_stage = led.stage("queue")
         self.threads = [
             threading.Thread(target=self._work, daemon=True,
                              name=f"cql-exec-{server.port}-{i}")
@@ -537,7 +538,10 @@ class _Dispatcher:
 
     def submit(self, conn: Connection, stream: int, opcode: int,
                body: bytes) -> None:
-        self.queue.put((conn, stream, opcode, body))
+        # stamped at submit: `transport.queue_wait` runs from here to
+        # the worker that takes the item
+        self.queue.put((conn, stream, opcode, body,
+                        pipeline_ledger.CLOCK()))
         self._stage.note_queue(self.queue.qsize())
 
     def shutdown(self) -> None:
@@ -545,32 +549,39 @@ class _Dispatcher:
             self.queue.put(None)
 
     def _work(self) -> None:
-        srv = self.server
+        srv, stage = self.server, self._stage
         while True:
-            t_idle = time.monotonic()
-            item = self.queue.get()
-            t0 = time.monotonic()
-            self._stage.add_idle(t0 - t_idle)
+            with stage.idle("transport.dispatch.idle"):
+                item = self.queue.get()
             if item is None:
                 return
-            conn, stream, opcode, body = item
-            billed = False
+            conn, stream, opcode, body, t_submit = item
+            # the request's id in the span ring: connection + stream
+            rid = (conn.cid << 16) | (stream & 0xFFFF)
             try:
                 try:
-                    op, rsp = srv._dispatch(srv.processor, conn,
-                                            srv._need_auth, srv._auth,
-                                            opcode, body)
-                except Exception as e:
-                    op, rsp = _error_response(e)
-                # bill the ledger BEFORE the response leaves: a client
-                # that has already READ its response must be able to
-                # observe this request's dispatch busy/items — billing
-                # after send_envelope raced exactly that observation
-                # (the send only enqueues to the out buffer anyway;
-                # the socket write is the loop thread's work)
-                self._stage.add_busy(time.monotonic() - t0)
-                self._stage.add_items(1, len(body))
-                billed = True
+                    with stage.busy("transport.request", task=rid,
+                                    nbytes=len(body)):
+                        # back-dated to the submit stamp: a child of
+                        # the request by parentage, not by thread time
+                        with self._queue_stage.stall(
+                                "transport.queue_wait", since=t_submit):
+                            pass
+                        try:
+                            op, rsp = srv._dispatch(
+                                srv.processor, conn, srv._need_auth,
+                                srv._auth, opcode, body)
+                        except Exception as e:
+                            op, rsp = _error_response(e)
+                finally:
+                    # the ledger is billed BEFORE the response leaves:
+                    # a client that has already READ its response must
+                    # be able to observe this request's dispatch
+                    # busy/items — billing after send_envelope raced
+                    # exactly that observation (the send only enqueues
+                    # to the out buffer anyway; the socket write is the
+                    # loop thread's work)
+                    stage.add_items(1, len(body))
                 try:
                     conn.send_envelope(0x80 | (conn.version or 0x04),
                                        stream, op, rsp)
@@ -583,9 +594,6 @@ class _Dispatcher:
                     conn.loop.call(
                         lambda c=conn: c.loop.close_conn(c))
             finally:
-                if not billed:   # _error_response itself raised
-                    self._stage.add_busy(time.monotonic() - t0)
-                    self._stage.add_items(1, len(body))
                 with conn.wlock:
                     conn.in_flight -= 1
                 srv.permits.release()
@@ -1107,7 +1115,6 @@ class CQLServer:
 
     def _run(self, processor, conn: Connection, query, body: bytes,
              pos: int, prep=None):
-        import time as time_mod
         consistency, = struct.unpack_from(">H", body, pos)
         pos += 2
         if conn.version >= 0x05:          # v5 widened flags to [int]
@@ -1138,17 +1145,17 @@ class CQLServer:
             is_read = type(prep.statement).__name__ == "SelectStatement"
         else:
             is_read = query.lstrip()[:6].upper() == "SELECT"
-        t0 = time_mod.perf_counter()
-        if prep is not None:   # EXECUTE: resolved statement, no re-parse
-            rs = processor.execute_statement(
-                prep, params, conn.keyspace, user=conn.user,
-                page_size=page_size, paging_state=paging_state)
-        else:
-            rs = processor.process(query, params, conn.keyspace,
-                                   user=conn.user,
-                                   page_size=page_size,
-                                   paging_state=paging_state)
-        us = (time_mod.perf_counter() - t0) * 1e6
+        with pipeline_ledger.span("cql.execute", nbytes=len(body)) as sp:
+            if prep is not None:   # EXECUTE: resolved, no re-parse
+                rs = processor.execute_statement(
+                    prep, params, conn.keyspace, user=conn.user,
+                    page_size=page_size, paging_state=paging_state)
+            else:
+                rs = processor.process(query, params, conn.keyspace,
+                                       user=conn.user,
+                                       page_size=page_size,
+                                       paging_state=paging_state)
+        us = sp.seconds * 1e6
         verb = "read" if is_read else "write"
         # the per-CL tag uses the level the client DECLARED, so a
         # saturation-matrix breach attributes to ONE vs QUORUM instead

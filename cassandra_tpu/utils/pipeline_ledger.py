@@ -27,9 +27,22 @@ accumulate across tasks under stable `pipeline/stage` names, surfaced as
 Recording costs two float adds under a per-stage lock — cheap enough to
 stay armed always (the bench's paired A/B pins the data plane within
 noise of the un-instrumented path).
+
+ONE span primitive times everything (`Span`, opened by `Stage.busy()/
+stall()/idle()` or by `span()` for a span that bills no stage). While
+open it is a `jax.profiler.TraceAnnotation` named `ctpu.<name>`, so it
+lies in a profiler trace on the device's clock; on exit it writes the
+SAME seconds to its ledger stage, to the task's `profile` dict under its
+key, and appends one record to the process-global span ring (`RING`):
+the three clocks that used to time one phase are one write. Spans open
+per round, segment, pool job or request — never per cell, partition or
+block. docs/observability.md has the span-name catalogue.
 """
 from __future__ import annotations
 
+import collections
+import itertools
+import sys
 import threading
 from . import lockwitness
 import time
@@ -89,15 +102,20 @@ class Stage:
                 if depth > self.queue_hwm:
                     self.queue_hwm = depth
 
-    def busy(self) -> "_Timer":
-        """`with stage.busy(): ...` — timed busy work."""
-        return _Timer(self.add_busy)
+    def busy(self, name: str | None = None, **kw) -> "Span":
+        """`with stage.busy(): ...` — timed busy work. `name` is the
+        span's name in the ring and the trace (default
+        `<pipeline>.<stage>`); `**kw` as Span takes them."""
+        return Span(name or f"{self.pipeline}.{self.name}", "busy",
+                    self, **kw)
 
-    def stall(self) -> "_Timer":
-        return _Timer(self.add_stall)
+    def stall(self, name: str | None = None, **kw) -> "Span":
+        return Span(name or f"{self.pipeline}.{self.name}", "stall",
+                    self, **kw)
 
-    def idle(self) -> "_Timer":
-        return _Timer(self.add_idle)
+    def idle(self, name: str | None = None, **kw) -> "Span":
+        return Span(name or f"{self.pipeline}.{self.name}", "idle",
+                    self, **kw)
 
     # ------------------------------------------------------------- read --
 
@@ -116,18 +134,145 @@ class Stage:
             self.queue_hwm = 0
 
 
-class _Timer:
-    __slots__ = ("_sink", "_t0")
+# ------------------------------------------------------------------ spans
 
-    def __init__(self, sink):
-        self._sink = sink
+# the span ring: one bounded deque for the whole process (like the
+# ledger it survives engine close; deque.append is atomic). A record is
+# a tuple in RECORD_FIELDS order; `start`/`end` are CLOCK readings.
+RING_CAP = 32768
+RING: collections.deque = collections.deque(maxlen=RING_CAP)
+RECORD_FIELDS = ("name", "kind", "thread", "start", "end", "id", "parent",
+                 "task", "cells", "bytes", "items")
+TRACE_PREFIX = "ctpu."
+
+_TLS = threading.local()
+_IDS = itertools.count(1)          # span ids and task ids (next() is atomic)
+_PROF_LOCK = threading.Lock()      # pool workers share one profile key
+
+
+def new_task_id() -> int:
+    """An id for a root span (one compaction task, one request): its
+    children inherit it, and it travels to other threads with the work
+    item (`task_scope`, or `task=` on the span)."""
+    return next(_IDS)
+
+
+def current_task() -> int:
+    """The task/request id the calling thread works for right now."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1].task if stack else getattr(_TLS, "task", 0)
+
+
+class task_scope:
+    """`with task_scope(tid):` — spans this thread opens outside any
+    parent span belong to task `tid` (a worker thread serving one
+    task's queue: compact-w, the prefetch helper)."""
+
+    __slots__ = ("task", "_prev")
+
+    def __init__(self, task: int):
+        self.task = task
 
     def __enter__(self):
-        self._t0 = CLOCK()
+        self._prev = getattr(_TLS, "task", 0)
+        _TLS.task = self.task
         return self
 
     def __exit__(self, *exc):
-        self._sink(CLOCK() - self._t0)
+        _TLS.task = self._prev
+
+
+def _annotation(name: str, thread: str, task: int):
+    """A TraceAnnotation for the profiler's trace — a no-op object while
+    no profiler session runs. It carries the Python thread's name and
+    the task id as event stats: the profiler names a host line after
+    the process, not after a Python thread. jax is never imported from
+    here: a process that has not imported it (the wire client, the load
+    generator) has no profiler to annotate for."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation(TRACE_PREFIX + name,
+                                            thread=thread, task=task)
+    except AttributeError:     # jax mid-import on another thread
+        return None
+
+
+class Span:
+    """One timed stretch of one thread. On exit the same `seconds` go to
+    the ledger stage (by `kind`: busy | stall | idle), to `prof[key]`
+    and into the ring, with the parent (the span open on this thread
+    when this one opened), the task id and up to three integer
+    attributes, which may be set while the span is open
+    (`sp.nbytes = n`). `since` back-dates the start to a CLOCK reading
+    taken earlier (a queue wait stamped at submit): such a span is a
+    ring record and a ledger entry, not an annotation."""
+
+    __slots__ = ("name", "kind", "stage", "prof", "key", "task", "cells",
+                 "nbytes", "items", "seconds", "_t0", "_ann", "_id",
+                 "_parent", "_thread")
+
+    def __init__(self, name: str, kind: str = "busy", stage=None, *,
+                 prof: dict | None = None, key: str | None = None,
+                 task: int | None = None, since: float | None = None,
+                 cells: int = 0, nbytes: int = 0, items: int = 0):
+        self.name, self.kind, self.stage = name, kind, stage
+        self.prof, self.key, self.task = prof, key, task
+        self.cells, self.nbytes, self.items = cells, nbytes, items
+        self.seconds = 0.0
+        self._t0 = since
+
+    def __enter__(self):
+        stack = getattr(_TLS, "stack", None)
+        if stack is None:
+            stack = _TLS.stack = []
+        parent = stack[-1] if stack else None
+        self._parent = parent._id if parent is not None else 0
+        if self.task is None:
+            self.task = parent.task if parent is not None \
+                else getattr(_TLS, "task", 0)
+        self._id = next(_IDS)
+        self._ann = None
+        self._thread = threading.current_thread().name
+        stack.append(self)
+        if self._t0 is None:
+            self._ann = _annotation(self.name, self._thread, self.task)
+            if self._ann is not None:
+                self._ann.__enter__()
+            self._t0 = CLOCK()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = CLOCK()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _TLS.stack.pop()
+        dt = self.seconds = t1 - self._t0
+        if self.stage is not None:
+            getattr(self.stage, "add_" + self.kind)(dt)
+        if self.prof is not None and self.key is not None:
+            with _PROF_LOCK:
+                self.prof[self.key] = self.prof.get(self.key, 0.0) + dt
+        RING.append((self.name, self.kind, self._thread, self._t0, t1,
+                     self._id, self._parent, self.task, int(self.cells),
+                     int(self.nbytes), int(self.items)))
+
+
+def span(name: str, kind: str = "busy", **kw) -> Span:
+    """A span that bills no ledger stage: a finer child of one that
+    does, or a stretch no stage owns. Ring record, annotation and (with
+    `prof=`/`key=`) profile seconds like any other."""
+    return Span(name, kind, None, **kw)
+
+
+def ring_records(tail: int | None = None) -> list:
+    """The ring's records, oldest first, as dicts (the flight-recorder
+    bundle's `pipeline_spans` section; readers under benchmarks/)."""
+    recs = list(RING)
+    if tail is not None:
+        recs = recs[-tail:]
+    return [dict(zip(RECORD_FIELDS, r)) for r in recs]
 
 
 class PipelineLedger:
@@ -198,7 +343,8 @@ def snapshot_all() -> dict:
 
 def reset_all() -> None:
     """Zero every stage (bench legs / test isolation). Stages stay
-    registered — their metric gauges keep reporting, from zero."""
+    registered — their metric gauges keep reporting, from zero. The
+    span ring is left alone: it is history, not an accumulator."""
     with _LOCK:
         ledgers = list(_LEDGERS.values())
     for led in ledgers:

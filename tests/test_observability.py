@@ -327,7 +327,7 @@ def test_kernel_profiler_splits_compile_from_execute():
     assert name.startswith("merge.")
     assert k["calls"] == 2
     assert k["compiles"] == 1          # same shape: one compile only
-    assert k["shapes"] == 1
+    assert k["shape_count"] == 1
     assert k["compile_s"] > 0
     assert k["execute_s"] > 0
 

@@ -229,7 +229,7 @@ def test_registry_bounds_tracked_shapes_with_lru_eviction():
         assert reg.record_dispatch("k", ("s", i), 0.001)   # all compile
     snap = reg.snapshot()["kernels"]["k"]
     assert snap["compiles"] == n
-    assert snap["shapes"] == snap["shape_count"] == profiling.SHAPE_CAP
+    assert snap["shape_count"] == profiling.SHAPE_CAP
     assert snap["shape_evictions"] == 40
     # an EVICTED shape reappearing counts as a fresh compile (mirrors
     # a bounded compilation cache); a LIVE shape does not
@@ -280,12 +280,15 @@ def test_retrace_budget_zero_disables_sentinel():
         diagnostics.GLOBAL.clear()
 
 
-def test_kernel_profiler_alias_is_the_registry():
-    # pre-registry consumers (tests, bench, vtables) constructed
-    # KernelProfiler — the name must stay importable and be the same
-    # class, same process-global instance
-    assert profiling.KernelProfiler is profiling.DeviceProgramRegistry
+def test_the_registry_is_the_one_profiler():
+    # the pre-registry alias and the duplicate `shapes` snapshot key are
+    # gone (ROADMAP C7): one class, one process-global instance, one
+    # name per number
+    assert not hasattr(profiling, "KernelProfiler")
     assert isinstance(profiling.GLOBAL, profiling.DeviceProgramRegistry)
+    reg = profiling.DeviceProgramRegistry()
+    reg.record_dispatch("k", ("s", 0), 0.001)
+    assert "shapes" not in reg.snapshot()["kernels"]["k"]
 
 
 def test_sampler_global_engine_knob_wiring(tmp_path):
