@@ -8,7 +8,10 @@ the codec pass, which profiling showed dominate point-read latency.
 Entries key on (sstable path, generation, segment). Cached batches are
 treated as immutable by every consumer (merge paths concat/permute into
 fresh arrays before any mutation); `flags.setflags(write=False)` guards
-the contract in debug use.
+the contract in debug use. A cached segment's pk_map is either full (a
+scan decoded it) or empty (a point read did, which names its partition
+itself); the reader upgrades an empty one on a shallow copy it swaps in
+(SSTableReader._read_segment), never in place.
 
 Capacity is bytes-bounded with LRU eviction; a table-dropping truncate or
 compaction leaves stale entries that simply age out (keys are

@@ -10,6 +10,7 @@ scans: sequential segment decode yielding device-ready CellBatches.
 """
 from __future__ import annotations
 
+import copy
 import json
 import os
 import struct
@@ -19,7 +20,7 @@ import numpy as np
 
 from ...ops.codec import CompressionParams
 from ...utils import bloom as bloom_mod
-from ...utils import faultfs
+from ...utils import faultfs, pipeline_ledger
 from ..cellbatch import CellBatch
 from .format import Component, Descriptor
 
@@ -240,30 +241,44 @@ class SSTableReader:
 
     # ------------------------------------------------------------- decode
 
-    def _read_segment(self, i: int) -> CellBatch:
+    def _read_segment(self, i: int, keyed: bool = True) -> CellBatch:
+        """Decoded segment i through the chunk cache. A scan (`keyed`)
+        gets every partition key of the segment in its pk_map; a point
+        read names its one partition itself (_cell_range) and asks for
+        none, so a cached segment's map is either full or EMPTY."""
         from ..chunk_cache import GLOBAL as chunk_cache
         key = (self.desc.directory, self.desc.generation, i)
         cached = chunk_cache.get(key)
-        if cached is not None:
-            if cached.ck_comp is None and self._table is not None:
-                # a schema-less (offline-tool) reader may have warmed
-                # this entry; range-tombstone reconciliation needs the
-                # composite translator back. Fix up a SHALLOW COPY (the
-                # arrays stay shared — they are immutable by the cache
-                # contract): the cached object is read concurrently by
-                # other threads and an in-place attribute store here
-                # would race their merge passes
-                import copy
-                fixed = copy.copy(cached)
-                fixed.ck_comp = self._table.clustering_comp
-                # swap the repaired copy in (atomic reference replace)
-                # so later hits skip both the None-check and the copy
-                chunk_cache.put(key, fixed)
-                return fixed
+        if cached is None:
+            with pipeline_ledger.span(
+                    "sstable.read.segment",
+                    nbytes=int(self._blk[i, :, 1].sum())) as sp:
+                batch = self._decode_segment(i)
+                if keyed:
+                    batch.pk_map = self._segment_keys(i)
+                    sp.items = len(batch.pk_map)
+            chunk_cache.put(key, batch)
+            return batch
+        need_comp = cached.ck_comp is None and self._table is not None
+        need_keys = keyed and not cached.pk_map and len(cached) > 0
+        if not (need_comp or need_keys):
             return cached
-        batch = self._decode_segment(i)
-        chunk_cache.put(key, batch)
-        return batch
+        # a schema-less (offline-tool) reader may have warmed this entry
+        # without the composite translator that range-tombstone
+        # reconciliation needs, a point read without the keys a scan
+        # needs. Fix up a SHALLOW COPY (the arrays stay shared — they
+        # are immutable by the cache contract): the cached object is
+        # read concurrently by other threads and an in-place attribute
+        # store here would race their merge passes
+        fixed = copy.copy(cached)
+        if need_comp:
+            fixed.ck_comp = self._table.clustering_comp
+        if need_keys:
+            fixed.pk_map = self._segment_keys(i)
+        # swap the repaired copy in (atomic reference replace) so later
+        # hits skip both the checks and the copy
+        chunk_cache.put(key, fixed)
+        return fixed
 
     def _decode_segment(self, i: int) -> CellBatch:
         n = int(self._seg_n[i])
@@ -388,18 +403,24 @@ class SSTableReader:
         batch.ck_fits_prefix = bool(self.stats.get("ck_fits_prefix", False))
         if self._table is not None:
             batch.ck_comp = self._table.clustering_comp
-        self._fill_pk_map(batch, i)
         return batch
 
-    def _fill_pk_map(self, batch: CellBatch, seg_i: int) -> None:
-        """Attach pk bytes for every partition overlapping this segment."""
+    def _segment_keys(self, seg_i: int) -> dict[bytes, bytes]:
+        """pk bytes of every partition overlapping this segment, by its
+        16-byte lane key: one pass over the directory slice, no Python
+        statement per partition (a segment holds up to segment_cells of
+        them)."""
         lo_cell = int(self._seg_cell0[seg_i])
         hi_cell = int(self._seg_cell0[seg_i + 1])
         lo = int(np.searchsorted(self._part_cell0, lo_cell, side="right")) - 1
         hi = int(np.searchsorted(self._part_cell0, hi_cell, side="left"))
-        for p in range(max(lo, 0), hi):
-            key16 = self._part_lane4[p].astype(">u4").tobytes()
-            batch.pk_map[key16] = self.partition_key_at(p)
+        lo = max(lo, 0)
+        # the directory stores the lanes big-endian: a row's 16 bytes
+        # ARE its pk_map key
+        keys = self._part_lane4[lo:hi].view("V16").ravel().tolist()
+        offs = self._pk_off[lo:hi + 1].tolist()
+        return dict(zip(keys, map(self._pk_blob.__getitem__,
+                                  map(slice, offs[:-1], offs[1:]))))
 
     # ------------------------------------------------------------- reads --
 
@@ -446,25 +467,10 @@ class SSTableReader:
         hit = self._verified_key_cache_hit(key_cache, ck, pk)
         if hit is not None:
             return hit
-        from ..cellbatch import pk_lanes
-        target = pk_lanes(pk)
-        # binary search over big-endian-stored directory
-        view = self._part_lane4.astype(np.uint32)
-        lo, hi = 0, self.n_partitions
-        while lo < hi:
-            mid = (lo + hi) // 2
-            row = tuple(int(x) for x in view[mid])
-            if row < target:
-                lo = mid + 1
-            else:
-                hi = mid
-        if lo < self.n_partitions and tuple(int(x) for x in view[lo]) == target:
-            if self.partition_key_at(lo) != pk:
-                raise CorruptSSTableError("partition key hash collision",
-                                          descriptor=self.desc)
-            key_cache.put(ck, (lo,))
-            return lo
-        return None
+        p = self._partition_indexes_batch([pk])[0]
+        if p is not None:
+            key_cache.put(ck, (p,))
+        return p
 
     def warm_key(self, pk: bytes) -> bool:
         """Re-populate the key cache for pk through the normal lookup
@@ -487,7 +493,13 @@ class SSTableReader:
         if p is None:
             return None
         c0, c1 = self._partition_cell_range(p)
-        return self._cell_range(c0, c1)
+        return self._cell_range(c0, c1, self._own_key(p, pk))
+
+    def _own_key(self, p: int, pk: bytes) -> dict[bytes, bytes]:
+        """The pk_map of a point read of directory entry p: its one
+        partition, in a dict of its own (what a merge, the row cache or
+        a replica's response then carries)."""
+        return {self._part_lane4[p].tobytes(): pk}
 
     def _partition_indexes_batch(self, pks: list[bytes]) -> list[int | None]:
         """Vectorized directory lookup for many keys: all (token, pkh)
@@ -537,54 +549,60 @@ class SSTableReader:
         if not cands:
             return out, cands
         from ..key_cache import GLOBAL as key_cache
-        ranges: dict[bytes, tuple[int, int]] = {}
+        found: dict[bytes, int] = {}
         miss: list[bytes] = []
         for pk in cands:
             hit = self._verified_key_cache_hit(
                 key_cache, self._key_cache_key(pk), pk)
             if hit is not None:
-                ranges[pk] = self._partition_cell_range(hit)
+                found[pk] = hit
             else:
                 miss.append(pk)
         if miss:
             for pk, p in zip(miss, self._partition_indexes_batch(miss)):
                 if p is not None:
                     key_cache.put(self._key_cache_key(pk), (p,))
-                    ranges[pk] = self._partition_cell_range(p)
+                    found[pk] = p
         # gather: decode each needed segment once (ascending disk
         # order), slice every partition's cells out of the shared batch
         seg_memo: dict[int, CellBatch] = {}
-        for pk, (c0, c1) in sorted(ranges.items(), key=lambda kv: kv[1]):
-            s0 = int(np.searchsorted(self._seg_cell0, c0, side="right")) - 1
-            s1 = int(np.searchsorted(self._seg_cell0, c1, side="left"))
-            parts = []
-            for s in range(s0, max(s1, s0 + 1)):
-                seg = seg_memo.get(s)
-                if seg is None:
-                    seg = seg_memo[s] = self._read_segment(s)
-                lo = max(c0 - int(self._seg_cell0[s]), 0)
-                hi = min(c1 - int(self._seg_cell0[s]), len(seg))
-                if lo > 0 or hi < len(seg):
-                    parts.append(seg.slice_range(lo, hi))
-                else:
-                    parts.append(seg)
-            batch = CellBatch.concat(parts) if len(parts) > 1 else parts[0]
-            batch.sorted = True
-            out[pk] = batch
+        for pk, p in sorted(found.items(), key=lambda kv: kv[1]):
+            c0, c1 = self._partition_cell_range(p)
+            out[pk] = self._cell_range(c0, c1, self._own_key(p, pk),
+                                       seg_memo)
         return out, cands
 
-    def _cell_range(self, c0: int, c1: int) -> CellBatch:
+    def _cell_range(self, c0: int, c1: int,
+                    own_keys: dict[bytes, bytes] | None = None,
+                    seg_memo: dict[int, CellBatch] | None = None
+                    ) -> CellBatch:
+        """Cells [c0, c1) of the data file as one sorted batch. A scan
+        (no `own_keys`) carries the keys of every segment it touched; a
+        point read passes the keys of the partitions in the range and
+        the result carries exactly those, whatever the segments hold.
+        `seg_memo` keeps segments across the calls of one batched
+        read."""
         s0 = int(np.searchsorted(self._seg_cell0, c0, side="right")) - 1
         s1 = int(np.searchsorted(self._seg_cell0, c1, side="left"))
+        if seg_memo is None:
+            seg_memo = {}
         parts = []
         for s in range(s0, max(s1, s0 + 1)):
-            seg = self._read_segment(s)
+            seg = seg_memo.get(s)
+            if seg is None:
+                seg = seg_memo[s] = self._read_segment(
+                    s, keyed=own_keys is None)
             lo = max(c0 - int(self._seg_cell0[s]), 0)
             hi = min(c1 - int(self._seg_cell0[s]), len(seg))
             if lo > 0 or hi < len(seg):
-                parts.append(seg.slice_range(lo, hi))
-            else:
-                parts.append(seg)
+                seg = seg.slice_range(lo, hi)
+            elif own_keys is not None:
+                # the whole segment is the CACHED object: the point
+                # read's map goes on a shallow copy, never onto it
+                seg = copy.copy(seg)
+            if own_keys is not None:
+                seg.pk_map = own_keys
+            parts.append(seg)
         out = CellBatch.concat(parts) if len(parts) > 1 else parts[0]
         out.sorted = True
         return out
