@@ -31,9 +31,11 @@ class Node:
         self.endpoint = endpoint
         self.schema = schema
         self.ring = ring
+        # a node's commitlog syncs every write (batch) unless its
+        # config block names a mode (tools/noded.py _engine_opts)
         self.engine = StorageEngine(data_dir, schema,
-                                    commitlog_sync="batch",
-                                    **(engine_opts or {}))
+                                    **{"commitlog_sync": "batch",
+                                       **(engine_opts or {})})
         self.messaging = MessagingService(endpoint, transport)
         self.hints = HintsService(os.path.join(data_dir, "hints"))
         self.gossiper = Gossiper(self.messaging, seeds,

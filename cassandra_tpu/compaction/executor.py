@@ -54,6 +54,9 @@ class CompactionProgress:
         self.bytes_read = 0
         self.bytes_written = 0
         self.phase = "pending"
+        # the merge engine of the running task (device | native |
+        # numpy), set by the task; "" for operations that merge nothing
+        self.engine = ""
         self.started_at = time.time()
         self._t0 = time.monotonic()
         # `nodetool stop` lands HERE, per task (CompactionInfo.Holder
@@ -86,6 +89,7 @@ class CompactionProgress:
             "table": self.table,
             "kind": self.kind,
             "phase": self.phase,
+            "engine": self.engine,
             "total_bytes": total,
             "bytes_read": read,
             "bytes_written": self.bytes_written,

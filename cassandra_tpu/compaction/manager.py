@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import queue
 import threading
-from ..utils import lockwitness
+from ..utils import lockwitness, pipeline_ledger
 import time
 
 from ..utils.ratelimit import RateLimiter  # noqa: F401  (re-exported)
@@ -185,13 +185,19 @@ class CompactionManager:
             total_bytes=sum(r.data_size for r in task.inputs))
         task.limiter = self.limiter
         task.progress = info
+        # what the task merges with and whether it chose that itself,
+        # for the events below (the task stamps its progress handle)
+        engine = getattr(task, "engine", "")
+        chosen = bool(getattr(task, "engine_chosen", False))
         self.active.begin(info)
         from ..service import diagnostics
         diagnostics.publish("compaction.start",
                             keyspace=cfs.table.keyspace,
                             table=cfs.table.name, kind=kind,
                             inputs=len(task.inputs),
-                            bytes=info.total_bytes)
+                            bytes=info.total_bytes,
+                            engine=engine, engine_chosen=chosen,
+                            engine_why=getattr(task, "engine_why", ""))
         t0 = time.monotonic()
         stats = None
         try:
@@ -212,7 +218,8 @@ class CompactionManager:
                             table=cfs.table.name, kind=kind,
                             bytes_read=stats.get("bytes_read", 0),
                             bytes_written=stats.get("bytes_written", 0),
-                            seconds=round(stats.get("seconds", 0.0), 3))
+                            seconds=round(stats.get("seconds", 0.0), 3),
+                            engine=engine, engine_chosen=chosen)
         return stats
 
     def _maybe_compact(self, cfs, locked: bool = False) -> int:
@@ -224,7 +231,13 @@ class CompactionManager:
         try:
             strategy = get_strategy(cfs)
             while n < self.MAX_TASKS_PER_SUBMISSION:
-                task = strategy.next_background_task()
+                # the strategy's pick and, inside the task it builds,
+                # the engine choice (compaction/task.py choose_engine)
+                with pipeline_ledger.span("compaction.select") as sp:
+                    task = strategy.next_background_task()
+                    if task is not None:
+                        sp.items = len(task.inputs)
+                        sp.cells = sum(r.n_cells for r in task.inputs)
                 if task is None:
                     break
                 try:

@@ -196,6 +196,58 @@ class CompactionController:
         return per_part[part_id]
 
 
+def tpu_backend() -> bool:
+    """The backend probe of the engine choice: is jax's default backend
+    a TPU? Called only for a compaction that passed the size floor and
+    the metadata check, so a store that never compacts anything large
+    never initialises a jax backend for it. Tests replace it (the
+    `backend_probe=` argument, or this module attribute)."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+# cell kinds whose rounds the resident program hands to the host
+# (ops/device_write.py submit/collect_merge_resident; ROADMAP B3):
+# range tombstone bounds and counters by construction, TTLs because an
+# expired cell kept inside gc grace needs the host's payload rewrite
+_HOST_ROUND_FLAGS = cb.FLAG_RANGE_BOUND | cb.FLAG_COUNTER | cb.FLAG_EXPIRING
+
+
+def host_engine() -> str:
+    """What an unnamed engine has always resolved to: the C++ merge when
+    the library loads, else numpy."""
+    from ..ops import host_merge
+    return "native" if host_merge.available() else "numpy"
+
+
+def choose_engine(inputs, backend_probe=None) -> tuple[str, str]:
+    """(engine, why) for a compaction nobody named an engine for, from
+    what the code can observe — no setting (ROADMAP B1, C1):
+
+      size      fewer input cells than DEVICE_MIN_CELLS: the host engine.
+                A served node compacts its system keyspace and freshly
+                flushed slivers all day; each would pad to a program
+                shape nobody compiled (tens of seconds on a TPU) to
+                save milliseconds.
+      metadata  the inputs' Statistics (`cell_flags`, the OR of their
+                cells' flags) show range tombstone bounds, counters or
+                TTLs — or do not say: the resident program would send
+                those rounds to the host anyway.
+      backend   the probe last, and only for what passed the others:
+                `device` on a TPU, else the host engine."""
+    host = host_engine()
+    if sum(r.n_cells for r in inputs) < CompactionTask.DEVICE_MIN_CELLS:
+        return host, "below the device size floor"
+    flags = [getattr(r, "cell_flags", None) for r in inputs]
+    if any(f is None for f in flags):
+        return host, "an input's statistics do not record its cell kinds"
+    if any(f & _HOST_ROUND_FLAGS for f in flags):
+        return host, "inputs hold range tombstones, counters or TTLs"
+    if not (backend_probe or tpu_backend)():
+        return host, "no TPU backend"
+    return "device", "TPU backend, resident-encodable inputs"
+
+
 class CompactionTask:
     # cells merged per round. Device rounds target just under 2^19 cells:
     # big enough to amortise dispatch latency, small enough that rounds
@@ -210,6 +262,13 @@ class CompactionTask:
     # many rounds let the pipelined writer thread overlap compression +
     # file I/O with the next round's decode + merge.
     ROUND_CELLS_HOST = 1 << 17
+    # the engine choice's size floor (choose_engine): one full device
+    # round. Below it a compaction is a single partial round, padded to
+    # a power-of-two shape of its own (ops/merge._bucket) — a compile of
+    # tens of seconds on a TPU for work the native engine finishes in
+    # under a second; at or above it the rounds are the 2^19 / 2^20
+    # shapes every large compaction shares.
+    DEVICE_MIN_CELLS = ROUND_CELLS_DEVICE
 
     def __init__(self, cfs, inputs: list[SSTableReader],
                  max_output_bytes: int | None = None,
@@ -223,15 +282,24 @@ class CompactionTask:
                  mesh_devices: int | None = None,
                  device_resident: bool | None = None,
                  device_compress: bool | None = None,
-                 drop_only: bool = False):
+                 drop_only: bool = False,
+                 backend_probe=None):
         """engine: 'device' (TPU kernel), 'native' (C++ streaming merge),
         'numpy' (reference path). All three are tested bit-identical.
-        Default (engine=None, use_device unset): the native engine when
-        the library is available, else numpy (a failed g++ build is
-        logged by ops/native/build.py); pass engine='device' (or
-        use_device=True) to run the merge on the jax device. Which of
-        the two is faster on an attached chip is not measured yet
-        (ROADMAP A2).
+        An explicit engine= wins; else use_device=True means 'device'
+        and use_device=False 'numpy'. With neither given (every task a
+        strategy builds, so every served compaction) the task chooses
+        itself, choose_engine(): 'device' when jax's default backend is
+        a TPU, the inputs hold at least DEVICE_MIN_CELLS cells and their
+        statistics show nothing the resident program sends to the host
+        (range tombstones, counters, TTLs); otherwise 'native' when the
+        library is available, else 'numpy' (a failed g++ build is
+        logged by ops/native/build.py) — which is what None resolved to
+        before, and still does on any backend but a TPU.
+        `engine_chosen` says whether the task chose and `engine_why`
+        why; a chosen engine is counted at execute()
+        (compaction.engine_chosen.<engine>). backend_probe: the probe
+        in tpu_backend()'s place (tests).
 
         limiter: a utils.ratelimit.RateLimiter debited per round with the
         round's share of on-disk input bytes (compaction_throughput).
@@ -282,14 +350,17 @@ class CompactionTask:
         fall back per round to the pinned host materialization, so
         output bytes are identical to the serial host path always
         (scripts/check_compaction_ab.py device legs). None = on for
-        engine='device'; ignored for host engines and under the mesh
-        execution mode (mesh shards drain through the host writer).
+        engine='device', named or chosen; ignored for host engines and
+        under the mesh execution mode (mesh shards drain through the
+        host writer).
         device_compress: device-side block compression for the
         device-resident lane's full segments (ops/device_compress.py)
         — the fused policy-scan kernel compresses META + lanes on the
         device and the host io thread becomes a pwrite pump. None =
         inherit the engine's hot-reloadable `compaction_device_compress`
-        knob, re-read by the writer PER SEGMENT (a mid-compaction flip
+        knob (default OFF since PR 27: on a v5e the lane's host-side LZ4
+        emission took 234 s for a compaction the host pool does in
+        12 s), re-read by the writer PER SEGMENT (a mid-compaction flip
         moves the work at the next segment boundary); True/False pins
         it. Output bytes are identical for every choice — the native
         packer runs the same deterministic policy encoder.
@@ -302,14 +373,16 @@ class CompactionTask:
         self.limiter = limiter
         self.progress = progress
         self.pipelined_io = pipelined_io
+        self.engine_chosen = engine is None and use_device is None
+        self.engine_why = "named by the caller"
         if engine is None:
             if use_device:
                 engine = "device"
             elif use_device is False:
                 engine = "numpy"
             else:
-                from ..ops import host_merge
-                engine = "native" if host_merge.available() else "numpy"
+                engine, self.engine_why = choose_engine(inputs,
+                                                        backend_probe)
         self.engine = engine
         if compress_pool is None:
             from ..storage.sstable.compress_pool import get_pool
@@ -762,6 +835,11 @@ class CompactionTask:
         CompactionTask.java:252-266)."""
         if self.drop_only and self._drop_safe():
             return self._execute_drop()
+        if self.engine_chosen:
+            from ..service.metrics import GLOBAL as _metrics
+            _metrics.incr(f"compaction.engine_chosen.{self.engine}")
+        if self.progress is not None:
+            self.progress.engine = self.engine
         # the root span: every span of this task, on whichever thread,
         # carries its id (docs/observability.md, span catalogue)
         with pipeline_ledger.span(
@@ -1214,6 +1292,9 @@ class CompactionTask:
             "seconds": dt,
             "read_mib_s": bytes_read / dt / 2**20 if dt > 0 else 0,
             "write_mib_s": bytes_written / dt / 2**20 if dt > 0 else 0,
+            # what merged, and whether the task chose it itself
+            "engine": self.engine,
+            "engine_chosen": self.engine_chosen,
         }
         # history ring + amplification counters in one locked fold
         # (storage/table.py record_compaction: the append shares a

@@ -156,7 +156,12 @@ class Config:
     # Engine-scoped and hot-reloadable: the writer re-reads it per
     # segment, so a mid-compaction flip takes effect at the next
     # segment boundary. Only device-resident tasks consult it.
-    compaction_device_compress: bool = mut(True)
+    # Default OFF since PR 27: a served compaction on a TPU node now
+    # chooses the device engine itself, and on a v5e this lane's
+    # host-side LZ4 emission took 234 s for a compaction the host
+    # compress pool finishes in 12 s (PERF.md). The knob waits for
+    # ROADMAP C1 (measure, then choose in code or delete).
+    compaction_device_compress: bool = mut(False)
     # device predicate/aggregate kernels for analytical scans
     # (ops/device_scan.py): scan_filtered evaluates pushdown predicates
     # with the jitted key-compare kernels instead of the numpy host

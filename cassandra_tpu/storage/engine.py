@@ -10,7 +10,7 @@ import os
 import threading
 
 from ..schema import Schema, TableMetadata
-from ..utils import timeutil
+from ..utils import pipeline_ledger, timeutil
 from .commitlog import CommitLog
 from .mutation import Mutation
 from .table import ColumnFamilyStore
@@ -27,8 +27,10 @@ class StorageEngine:
                  commitlog_archive_dir: str | None = None,
                  encrypt_commitlog: bool = False,
                  commitlog_compression: str | None = None,
-                 settings=None):
-        """keystore_dir enables TDE: an EncryptionContext is installed
+                 settings=None,
+                 commitlog_sync_period_ms: int = 1000):
+        """commitlog_sync_period_ms: the periodic mode's fsync spacing.
+        keystore_dir enables TDE: an EncryptionContext is installed
         node-wide (tables opt in via WITH encryption = {'enabled': true};
         encrypt_commitlog covers the WAL). commitlog_archive_dir turns on
         the segment archiver for point-in-time restore. settings: a
@@ -77,6 +79,7 @@ class StorageEngine:
         self.commitlog = CommitLog(
             os.path.join(data_dir, "commitlog"),
             sync_mode=commitlog_sync,
+            sync_period_ms=int(commitlog_sync_period_ms),
             archive_dir=commitlog_archive_dir,
             encrypt=encrypt_commitlog,
             compression=commitlog_compression
@@ -538,8 +541,11 @@ class StorageEngine:
             # ordering); a full cdc_raw FAILS the write like the reference
             self.cdc.append(mutation)
         from ..service.metrics import Timer
-        with Timer(cfs.write_hist):
-            cfs.apply(mutation, self.commitlog, durable)
+        # one span per apply; its children are the store's
+        # commitlog.append, memtable.apply and commitlog.wait
+        with pipeline_ledger.span("engine.write", nbytes=mutation.size):
+            with Timer(cfs.write_hist):
+                cfs.apply(mutation, self.commitlog, durable)
         self._maybe_flush(cfs)
 
     def _maybe_flush(self, cfs) -> None:

@@ -185,6 +185,12 @@ class SSTableReader:
         return int(self.stats.get("tombstones", 0))
 
     @property
+    def cell_flags(self) -> int | None:
+        """OR of every cell's flags byte (cellbatch.FLAG_*); None for an
+        sstable written before the writer recorded it."""
+        return self.stats.get("cell_flags")
+
+    @property
     def repaired_at(self) -> int:
         """repairedAt millis; 0 = unrepaired (StatsMetadata.repairedAt)."""
         return int(self.stats.get("repaired_at", 0))

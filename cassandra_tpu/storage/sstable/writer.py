@@ -342,6 +342,12 @@ class SSTableWriter:
         self._stats = {
             "min_ts": None, "max_ts": None, "min_ldt": None, "max_ldt": None,
             "tombstones": 0,
+            # OR of every cell's flags byte: what KINDS of cell the
+            # sstable holds (range bounds, counters, TTLs). A compaction
+            # reads it off its inputs to tell whether the device's
+            # resident program can encode their rounds
+            # (compaction/task.py choose_engine)
+            "cell_flags": 0,
         }
         self.level = 0   # LCS level (recorded in Statistics.db)
         # repairedAt epoch millis; 0 = unrepaired (reference
@@ -1194,6 +1200,9 @@ class SSTableWriter:
         _lo("min_ldt", mn_ldt)
         _hi("max_ldt", mx_ldt)
         self._stats["tombstones"] += tombs
+        # the flags plane of the "ce" META block (build_meta_block):
+        # ts-delta 8 + ldt 4 + ttl 4 bytes per cell precede it
+        st["cell_flags"] |= int(np.bitwise_or.reduce(meta[16 * n:17 * n]))
         return len(new_keys)
 
     def _pack_segment(self, n: int, meta: "np.ndarray",

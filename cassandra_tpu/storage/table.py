@@ -15,7 +15,7 @@ import time
 import numpy as np
 
 from ..schema import TableMetadata
-from ..utils import timeutil
+from ..utils import pipeline_ledger, timeutil
 from .cellbatch import (FLAG_PARTITION_DEL, CellBatch, merge_sorted,
                         truncate_live_rows)
 from .commitlog import write_fastpath_enabled
@@ -496,10 +496,13 @@ class ColumnFamilyStore:
         parked writers must not block the writers coalescing behind
         them (that wait is the group-commit batch forming)."""
         wait_for = None
+        span = pipeline_ledger.span
         with self._barrier.shared():
             if commitlog is not None and durable:
-                _pos, wait_for = commitlog.append(mutation)
-            self.memtable.apply(mutation)
+                with span("commitlog.append", nbytes=mutation.size):
+                    _pos, wait_for = commitlog.append(mutation)
+            with span("memtable.apply", items=len(mutation.ops)):
+                self.memtable.apply(mutation)
             self.metrics["writes"] += 1
             self.metrics["bytes_ingested"] += mutation.size
         # invalidate BEFORE the durability wait: the memtable already
@@ -507,8 +510,12 @@ class ColumnFamilyStore:
         # entry would leave cache-hit and memtable reads divergent
         if self.row_cache is not None:
             self.row_cache.invalidate(mutation.pk)
-        if wait_for is not None:
-            commitlog.await_durable(wait_for)
+        if commitlog is not None and durable:
+            # the wait the sync mode imposes before the ack: parked on
+            # the group/batch barrier, nothing under periodic (the span
+            # is opened all the same, so a reader sees the zero)
+            with span("commitlog.wait", "stall"):
+                commitlog.await_durable(wait_for)
 
     def apply_batch(self, mutations: list[Mutation], commitlog=None,
                     durable: bool = True) -> None:
@@ -785,37 +792,44 @@ class ColumnFamilyStore:
         replica-side, the wire."""
         self.failures.check_can_read()
         self.metrics["reads"] += 1
-        _t0 = time.perf_counter()
-        from ..service.tracing import active, trace
-        now = now if now is not None else timeutil.now_seconds()
-        read_gen = None
-        if self.row_cache is not None:
-            cached = self.row_cache.get(pk)
-            if cached is not None:
-                if active() is not None:
-                    trace("Row cache hit")
-                if limits is not None:
-                    cached, _ = truncate_live_rows(cached, limits)
-                self.read_hist.update_us(
-                    (time.perf_counter() - _t0) * 1e6)
-                return cached
-            # captured BEFORE the source snapshot (see RowCache.put)
-            read_gen = self.row_cache.generation
-        sources, consulted = self._collate_sources(pk)
-        self.sstables_per_read.update_us(consulted)
-        if active() is not None:   # tracing off: zero-cost path
-            trace(f"Merging {len(sources)} source(s) for partition read")
-        if not sources:
-            from .cellbatch import lanes_for_table
-            merged = CellBatch.empty(lanes_for_table(self.table))
-        else:
-            merged = merge_sorted(sources, now=now)
-        if self.row_cache is not None:
-            self.row_cache.put(pk, merged, read_gen)
-        if limits is not None:
-            merged, _ = truncate_live_rows(merged, limits)
-        self.read_hist.update_us((time.perf_counter() - _t0) * 1e6)
-        return merged
+        # one span per point read: memtable probe + sstable walk +
+        # merge; `items` = sstables consulted (0 on a row-cache hit).
+        # Segment decodes it causes are its `sstable.read.segment`
+        # children
+        with pipeline_ledger.span("engine.read") as sp:
+            _t0 = time.perf_counter()
+            from ..service.tracing import active, trace
+            now = now if now is not None else timeutil.now_seconds()
+            read_gen = None
+            if self.row_cache is not None:
+                cached = self.row_cache.get(pk)
+                if cached is not None:
+                    if active() is not None:
+                        trace("Row cache hit")
+                    if limits is not None:
+                        cached, _ = truncate_live_rows(cached, limits)
+                    self.read_hist.update_us(
+                        (time.perf_counter() - _t0) * 1e6)
+                    return cached
+                # captured BEFORE the source snapshot (see RowCache.put)
+                read_gen = self.row_cache.generation
+            sources, consulted = self._collate_sources(pk)
+            sp.items = consulted
+            self.sstables_per_read.update_us(consulted)
+            if active() is not None:   # tracing off: zero-cost path
+                trace(f"Merging {len(sources)} source(s) for partition "
+                      "read")
+            if not sources:
+                from .cellbatch import lanes_for_table
+                merged = CellBatch.empty(lanes_for_table(self.table))
+            else:
+                merged = merge_sorted(sources, now=now)
+            if self.row_cache is not None:
+                self.row_cache.put(pk, merged, read_gen)
+            if limits is not None:
+                merged, _ = truncate_live_rows(merged, limits)
+            self.read_hist.update_us((time.perf_counter() - _t0) * 1e6)
+            return merged
 
     # batched reads at or above this many outstanding keys route
     # through the mesh fan-out when `compaction_mesh_devices` is on

@@ -100,11 +100,12 @@ def build_engine_virtuals(engine) -> VirtualSchema:
 
     # --- compactions_in_progress (db/virtual/SSTableTasksTable +
     # ActiveCompactions): live per-task progress while compactor slots
-    # run — phase, bytes read/written, % done, ETA
+    # run — phase, merge engine, bytes read/written, % done, ETA
     t_cip = make_table(
         "system_views", "compactions_in_progress", pk=["id"],
         cols={"id": "int", "keyspace_name": "text", "table_name": "text",
-              "kind": "text", "phase": "text", "bytes_total": "bigint",
+              "kind": "text", "phase": "text", "engine": "text",
+              "bytes_total": "bigint",
               "bytes_read": "bigint", "bytes_written": "bigint",
               "progress_pct": "double", "active_seconds": "double",
               "eta_seconds": "double"})
@@ -113,7 +114,8 @@ def build_engine_virtuals(engine) -> VirtualSchema:
         for s in engine.compactions.active.snapshot():
             yield {"id": s["id"], "keyspace_name": s["keyspace"],
                    "table_name": s["table"], "kind": s["kind"],
-                   "phase": s["phase"], "bytes_total": s["total_bytes"],
+                   "phase": s["phase"], "engine": s["engine"],
+                   "bytes_total": s["total_bytes"],
                    "bytes_read": s["bytes_read"],
                    "bytes_written": s["bytes_written"],
                    "progress_pct": s["progress_pct"],

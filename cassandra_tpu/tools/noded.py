@@ -126,6 +126,13 @@ def _engine_opts(cfg: dict) -> dict:
     startup). Runtime-mutable settings flow through engine.settings."""
     from ..config import Config, Settings
     out = {"settings": Settings(Config.load(cfg.get("config", {})))}
+    if "commitlog_sync" in cfg.get("config", {}):
+        # a mode the config block NAMES replaces the node's batch
+        # default (cassandra.yaml commitlog_sync / _period); unnamed,
+        # a node keeps syncing every write
+        out["commitlog_sync"] = out["settings"].get("commitlog_sync")
+        out["commitlog_sync_period_ms"] = int(
+            out["settings"].get("commitlog_sync_period") * 1000)
     if cfg.get("keystore_dir"):
         out["keystore_dir"] = cfg["keystore_dir"]
     if cfg.get("commitlog_archive_dir"):

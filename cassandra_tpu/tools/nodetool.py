@@ -117,7 +117,9 @@ def compact(engine, keyspace: str | None = None,
 def compactionstats(engine) -> dict:
     """nodetool compactionstats: pending count + per-task live progress
     (ActiveCompactions / CompactionManager.getMetrics in the reference;
-    history moved to `compactionhistory`)."""
+    history moved to `compactionhistory`). Each active task's row
+    carries its `engine` (device | native | numpy): a served task
+    chooses it itself (compaction/task.py choose_engine)."""
     cm = engine.compactions
     ex = cm.executor.stats()
     return {
