@@ -177,23 +177,32 @@ class CompactionController:
         mems = [m for m in mems if not m.is_empty]
         if not overlapping and not mems:
             return out
-        lane4 = batch.lanes[:, :4]
-        part_new = np.ones(n, dtype=bool)
-        part_new[1:] = (lane4[1:] != lane4[:-1]).any(axis=1)
-        part_id = np.cumsum(part_new) - 1
-        starts = np.flatnonzero(part_new)
-        per_part = np.full(len(starts), np.iinfo(np.int64).max,
-                           dtype=np.int64)
-        for j, s in enumerate(starts):
-            pk = batch.partition_key(int(s))
-            lo = np.iinfo(np.int64).max
-            for src in overlapping:
-                if src.might_contain(pk) and src.min_ts is not None:
-                    lo = min(lo, src.min_ts)
-            if any(m.contains(pk) for m in mems):
-                lo = min(lo, 0)  # memtable data is never purged against
-            per_part[j] = lo
-        return per_part[part_id]
+        with pipeline_ledger.span("compaction.purge.probe") as sp:
+            # the answer is read only under a death flag or an expired
+            # TTL (reconcile's `purged`, in every engine): only the
+            # partition runs that hold such a cell are probed
+            mask = (batch.flags & (cb.DEATH_FLAGS | cb.FLAG_EXPIRING)) != 0
+            if not mask.any():
+                return out
+            lane4 = batch.lanes[:, :4]
+            part_new = np.ones(n, dtype=bool)
+            part_new[1:] = (lane4[1:] != lane4[:-1]).any(axis=1)
+            part_id = np.cumsum(part_new) - 1
+            starts = np.flatnonzero(part_new)
+            probed = np.unique(part_id[mask])
+            sp.cells, sp.items = len(starts), len(probed)
+            per_part = np.full(len(starts), np.iinfo(np.int64).max,
+                               dtype=np.int64)
+            for j in probed:
+                pk = batch.partition_key(int(starts[j]))
+                lo = np.iinfo(np.int64).max
+                for src in overlapping:
+                    if src.might_contain(pk) and src.min_ts is not None:
+                        lo = min(lo, src.min_ts)
+                if any(m.contains(pk) for m in mems):
+                    lo = min(lo, 0)  # memtable data is never purged against
+                per_part[j] = lo
+            return per_part[part_id]
 
 
 def tpu_backend() -> bool:

@@ -861,12 +861,8 @@ def collect_merge(h: DeviceMergeHandle) -> CellBatch:
         return h.result
     cat, n, prof = h.cat, h.n, h.prof
     t0 = _time.perf_counter()
-    # nothing can expire or be purged when no cell carries a death or
-    # expiring flag (the fast path already guarantees no death flags) —
-    # skip the overlap query and the whole expiry/purge post-pass
-    inert = not ((cat.flags & (DEATH_FLAGS | FLAG_EXPIRING)) != 0).any()
     pts = h.purgeable_ts_fn(cat).astype(np.int64) \
-        if h.purgeable_ts_fn is not None and not inert else None
+        if h.purgeable_ts_fn is not None else None
     t1 = _time.perf_counter()
     combined = np.asarray(h.fut)
     t2 = _time.perf_counter()
@@ -898,20 +894,16 @@ def collect_merge(h: DeviceMergeHandle) -> CellBatch:
 
     # host post-pass: TTL expiry, purge and tie-breaks don't affect sort
     # order or shadow carries, so they never went to the device
-    if inert:
-        expired = np.zeros(n, dtype=bool)
-        pts_sorted = None
-    else:
-        flags_s = cat.flags[perm]
-        ldt_s = cat.ldt[perm]
-        ts_s = cat.ts[perm]
-        expired = ((flags_s & FLAG_EXPIRING) != 0) & (ldt_s <= h.now)
-        death_eff = ((flags_s & DEATH_FLAGS) != 0) | expired
-        pts_sorted = pts[perm] if pts is not None else None
-        purgeable = np.ones(n, dtype=bool) if pts_sorted is None \
-            else ts_s < pts_sorted
-        purged = death_eff & (ldt_s < h.gc_before) & purgeable
-        keep &= ~purged
+    flags_s = cat.flags[perm]
+    ldt_s = cat.ldt[perm]
+    ts_s = cat.ts[perm]
+    expired = ((flags_s & FLAG_EXPIRING) != 0) & (ldt_s <= h.now)
+    death_eff = ((flags_s & DEATH_FLAGS) != 0) | expired
+    pts_sorted = pts[perm] if pts is not None else None
+    purgeable = np.ones(n, dtype=bool) if pts_sorted is None \
+        else ts_s < pts_sorted
+    purged = death_eff & (ldt_s < h.gc_before) & purgeable
+    keep &= ~purged
     if ambiguous.any():
         host_tiebreak(cat, perm, keep, ambiguous, shadowed,
                       expired, h.gc_before, pts_sorted,

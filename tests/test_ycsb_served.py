@@ -19,6 +19,7 @@ import ycsb_model as ycsb
 from cassandra_tpu.compaction import task as task_mod
 from cassandra_tpu.compaction.task import CompactionTask
 from cassandra_tpu.service.metrics import GLOBAL as METRICS
+from cassandra_tpu.utils import pipeline_ledger
 
 RECORDS, SSTABLES, FIELDS, LENGTH = 2000, 4, 10, 100
 THREADS, MIN_OPS, MAX_OPS = 4, 150, 1500
@@ -28,6 +29,7 @@ FALLBACKS = ("compaction.device_compress_fallback",
              "compaction.device_host_rounds",
              "compaction.device_resident_fallback")
 HOST = task_mod.host_engine()
+PROBE_SPAN = "compaction.purge.probe"
 
 
 class Served:
@@ -156,13 +158,28 @@ def run(request, tmp_path_factory):
         for t in threads:
             t.start()
         start.set()
+        end = time.monotonic() + 240
+        while time.monotonic() < end and cfs.memtable.is_empty:
+            time.sleep(0.002)               # the purge guard's switch
+        first_span = pipeline_ledger.new_task_id()
+        probes = {}
+
+        def drain_probes():                 # before the ring can wrap
+            for r in list(pipeline_ledger.RING):
+                if r[0] == PROBE_SPAN and r[5] > first_span:
+                    probes[r[5]] = dict(zip(pipeline_ledger.RECORD_FIELDS,
+                                            r))
         cm.paused = False                   # enableautocompaction
         cm.submit_background(cfs)
-        end = time.monotonic() + 240
+        polls = 0
         while time.monotonic() < end and not (
                 len(cfs.live_sstables()) == 1 and len(cm.active) == 0
                 and cm.pending_tasks() == 0):
             time.sleep(0.02)
+            polls += 1
+            if polls % 10 == 0:
+                drain_probes()
+        drain_probes()
         stop.set()
         for t in threads:
             t.join()
@@ -184,6 +201,7 @@ def run(request, tmp_path_factory):
             "chosen": {e: METRICS.counter(f"compaction.engine_chosen.{e}")
                        - v for e, v in chosen0.items()},
             "served_hashes": _hashes(cfs.directory),
+            "probes": list(probes.values()),
             "copies": copies, "table": cfs.table}
     finally:
         served.close()
@@ -216,6 +234,14 @@ def test_the_task_chose_its_engine_and_nothing_fell_back(run):
     assert comp["engine"] == want and comp["engine_chosen"] is True
     assert run["chosen"][want] >= 1
     assert all(v == 0 for v in run["fallbacks"].values()), run["fallbacks"]
+
+
+def test_the_purge_guard_probed_no_partition(run):
+    """usertable holds no tombstone and no TTL: with the memtable
+    non-empty from before the compaction's first round, every call of
+    the guard opened its span and probed nothing."""
+    assert run["probes"]
+    assert all(p["items"] == 0 for p in run["probes"]), run["probes"]
 
 
 def test_one_sstable_with_the_numpy_engines_bytes(run, tmp_path):
