@@ -1650,7 +1650,7 @@ def _kernel_probe(table):
     compilation, the second is warm, so the kernel_profile section
     always reports a compile-vs-execute split. CPU numbers — they say
     nothing about a chip. A failure here fails the bench."""
-    from cassandra_tpu.ops import merge as dmerge
+    from cassandra_tpu.ops.device_write import merge_sorted_device
     from cassandra_tpu.storage import cellbatch as cb
     from cassandra_tpu.tools import bulk
     rng = np.random.default_rng(3)
@@ -1664,7 +1664,7 @@ def _kernel_probe(table):
         batches.append(cb.merge_sorted(
             [bulk.build_int_batch(table, pk, ck, vals, ts)]))
     for _ in range(2):
-        dmerge.merge_sorted_device(batches)
+        merge_sorted_device(batches)
 
 
 def main():

@@ -12,8 +12,9 @@ hands the result to a pipelined writer thread (compression + file I/O
 overlap the next round's decode + merge). Three interchangeable,
 bit-identical merge engines:
 
-  device  ops/merge.py — the TPU kernel (LSD radix sort + segmented-scan
-          reconcile); big rounds amortise link latency.
+  device  ops/device_write.py — the TPU program (ops/merge.py's LSD radix
+          sort + segmented-scan reconcile, then kept-cell compaction);
+          big rounds amortise link latency.
   native  ops/native/merge.cpp — C++ k-way streaming merge with inline
           reconcile (the CompactionIterator formulation in native code);
           wins when the accelerator link is bandwidth-bound.
@@ -27,7 +28,8 @@ import time
 
 import numpy as np
 
-from ..ops import merge as dmerge
+from ..ops.device_write import (DeviceWriteLane, collect_merge_resident,
+                                materialize_round, submit_merge_resident)
 from ..storage import cellbatch as cb
 from ..storage.lifecycle import LifecycleTransaction
 from ..storage.sstable import Descriptor, SSTableReader, SSTableWriter
@@ -289,7 +291,6 @@ class CompactionTask:
                  compress_pool=None,
                  decode_ahead: bool | None = None,
                  mesh_devices: int | None = None,
-                 device_resident: bool | None = None,
                  device_compress: bool | None = None,
                  drop_only: bool = False,
                  backend_probe=None):
@@ -348,20 +349,6 @@ class CompactionTask:
         order IS identity-lane order — no reshuffle). None = inherit
         the `compaction_mesh_devices` knob (parallel/fanout.py);
         0 = force serial.
-        device_resident: device-engine rounds stay END-TO-END on the
-        jax device (ops/device_write.py): one fused program runs sort +
-        reconcile + purge + kept-cell compaction, the columns stay in a
-        device pending buffer across rounds, segments cut on-device and
-        a second fused kernel serializes each META block — the host
-        receives only finished blocks (plus the ragged payload, which
-        never leaves it). Rounds the device cannot reproduce exactly
-        (equal-ts ties, kept expired cells, counters, range bounds)
-        fall back per round to the pinned host materialization, so
-        output bytes are identical to the serial host path always
-        (scripts/check_compaction_ab.py device legs). None = on for
-        engine='device', named or chosen; ignored for host engines and
-        under the mesh execution mode (mesh shards drain through the
-        host writer).
         device_compress: device-side block compression for the
         device-resident lane's full segments (ops/device_compress.py)
         — the fused policy-scan kernel compresses META + lanes on the
@@ -412,9 +399,6 @@ class CompactionTask:
         # _decode_ahead_enabled), True/False = pinned for this task
         self.decode_ahead = decode_ahead
         self.mesh_devices = mesh_devices
-        if device_resident is None:
-            device_resident = self.engine == "device"
-        self.device_resident = device_resident
         # tri-state like decode_ahead: None = inherit the owning
         # engine's hot-reloadable `compaction_device_compress` knob
         # (re-read PER SEGMENT by the writer), True/False = pinned for
@@ -475,7 +459,7 @@ class CompactionTask:
         standalone stores. The writer re-reads a callable gate per
         segment, so mid-compaction knob flips land on segment
         boundaries."""
-        if not self.device_resident:
+        if self.engine != "device":
             return False
         if self.device_compress is not None:
             return bool(self.device_compress)
@@ -657,11 +641,12 @@ class CompactionTask:
                 merged = None
                 if slices and not stop.is_set():
                     if devices is not None:
-                        h = dmerge.submit_merge(
+                        # the serial loop's program, one device per
+                        # lane; shards drain as host CellBatches
+                        merged = materialize_round(submit_merge_resident(
                             slices, gc_before=gc_before, now=now,
                             purgeable_ts_fn=controller.purgeable_ts_fn,
-                            device=devices[s % n_devices])
-                        merged = dmerge.collect_merge(h)
+                            device=devices[s % n_devices]))
                     else:
                         merged = merge_shard(slices, shard_prof)
                 walls[s] = time.perf_counter() - t1
@@ -974,7 +959,6 @@ class CompactionTask:
                     if wstate["resident"]:
                         lane = wstate["lane"]
                         if lane is None:
-                            from ..ops.device_write import DeviceWriteLane
                             lane = wstate["lane"] = DeviceWriteLane(w)
                         lane.append(merged)
                     else:
@@ -1018,11 +1002,7 @@ class CompactionTask:
         pending: deque = deque()
 
         def collect_oldest():
-            if wstate["resident"]:
-                from ..ops.device_write import collect_merge_resident
-                merged = collect_merge_resident(pending.popleft())
-            else:
-                merged = dmerge.collect_merge(pending.popleft())
+            merged = collect_merge_resident(pending.popleft())
             if len(merged):
                 wq_put(merged)
 
@@ -1103,9 +1083,7 @@ class CompactionTask:
             # device-resident rounds only make sense for the serial
             # device round loop: mesh shards drain host CellBatches
             # through the unchanged writer (token-order contract)
-            wstate["resident"] = (self.engine == "device"
-                                  and self.device_resident
-                                  and not mesh_done)
+            wstate["resident"] = self.engine == "device" and not mesh_done
             cursors = [] if mesh_done \
                 else [_Cursor(r, prof) for r in self.inputs]
             # the decode-ahead thread starts (and stops, and restarts)
@@ -1189,18 +1167,10 @@ class CompactionTask:
                 if self.limiter is not None:
                     self.limiter.acquire(round_bytes)
                 if self.engine == "device":
-                    if wstate["resident"]:
-                        from ..ops.device_write import \
-                            submit_merge_resident
-                        pending.append(submit_merge_resident(
-                            slices, gc_before=gc_before, now=now,
-                            purgeable_ts_fn=controller.purgeable_ts_fn,
-                            prof=prof))
-                    else:
-                        pending.append(dmerge.submit_merge(
-                            slices, gc_before=gc_before, now=now,
-                            purgeable_ts_fn=controller.purgeable_ts_fn,
-                            prof=prof))
+                    pending.append(submit_merge_resident(
+                        slices, gc_before=gc_before, now=now,
+                        purgeable_ts_fn=controller.purgeable_ts_fn,
+                        prof=prof))
                     while len(pending) >= self.PIPELINE_DEPTH:
                         collect_oldest()
                 else:

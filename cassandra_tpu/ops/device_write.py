@@ -18,20 +18,31 @@ META bytes and the row-major LANES matrix `segment_pack` wants — plus
 the variable-length payload, which never went to the device (ragged
 bytes gather through the native C++ path, storage/cellbatch.py).
 
+This is the ONE jitted merge program compaction dispatches
+(`_resident_program`, kernel name `merge.resident`), and this module owns
+the submit/collect pair around it: `submit_merge_resident` dispatches a
+round, `collect_merge_resident` hands the write lane a DeviceRound, and
+`materialize_round` hands back a host CellBatch from the same program's
+permutation and masks — what the mesh lanes (compaction/task.py
+`_mesh_produce`, one device per lane) and `merge_sorted_device` take.
+
 Byte identity with the serial host path is absolute, not statistical:
-rounds the device cannot reproduce exactly fall back to the host
-materialization path per ROUND —
+rounds the device cannot reproduce exactly leave the resident lane per
+ROUND (each counted, `compaction.device_resident_fallback`) —
 
   * equal-(identity, ts) duplicate runs (the device sort does not order
     the Cells.resolveRegular tie-break lanes; the host resolves them
-    with full values),
-  * kept expired-TTL cells (tombstone conversion rewrites flags AND
-    drops the value bytes — a payload rewrite),
-  * counter cells / range-tombstone bounds (host-only reconcile),
+    with full values) and kept expired-TTL cells (tombstone conversion
+    rewrites flags AND drops the value bytes — a payload rewrite):
+    `materialize_round` on the program's own outputs,
+  * counter cells / range-tombstone bounds (host-only reconcile) and
+    frames past the u32 offset lanes, which the program cannot encode:
+    the numpy spec, `cellbatch.merge_sorted`, merges the round
+    (`_host_round`; also counted `compaction.device_host_rounds`),
 
 and `scripts/check_compaction_ab.py`'s device legs pin the whole-file
-sha256 equality. Scalar counts of those conditions are computed in the
-same fused program, so the decision costs three tiny transfers.
+sha256 equality. Scalar counts of the first two conditions are computed
+in the same fused program, so the decision costs three tiny transfers.
 """
 from __future__ import annotations
 
@@ -42,7 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
-                                 FLAG_RANGE_BOUND, CellBatch)
+                                 FLAG_RANGE_BOUND, CellBatch, merge_sorted)
+from ..service.metrics import GLOBAL as _METRICS
 from ..service.profiling import GLOBAL as _kprof
 from ..utils import pipeline_ledger
 from ..utils.logonce import warn_once
@@ -64,7 +76,7 @@ _LED_RESIDENT = pipeline_ledger.ledger("merge").stage("resident")
 
 def build_resident_operands(cat: CellBatch, gc_before: int, now: int,
                             purgeable_ts_fn):
-    """The v1 packed operands (merge.build_operands) extended with the
+    """The kernel operands (merge.build_operands) extended with the
     serialize-side columns: full flags byte, ttl, u32 frame lengths and
     value offsets. Returns (operands, pts_host) or None when a frame
     exceeds the u32 lanes (the host path raises its loud error
@@ -232,19 +244,38 @@ class DeviceRound:
 
 
 class ResidentHandle:
+    """An in-flight round: mode "resident" (the program was dispatched,
+    `out` holds its outputs) or "done" (`result` already is the merged
+    host CellBatch: an empty round, or one the numpy spec merged)."""
+
     __slots__ = ("mode", "result", "cat", "n", "out", "pts",
-                 "gc_before", "now", "prof", "fallback")
+                 "gc_before", "now", "prof")
 
 
 def _resident_fallback(n: int, why: str) -> None:
     """A round that leaves the resident lane for the host
     materialization: same bytes, but the device did not do the work —
     counted, and its cause logged once."""
-    from ..service.metrics import GLOBAL as _METRICS
     _METRICS.incr("compaction.device_resident_fallback")
     warn_once(_log, f"resident.fallback.{why}",
               "device-resident round (%d cells) materialized on the "
               "host: %s", n, why)
+
+
+def _host_round(h: ResidentHandle, batches: list[CellBatch],
+                purgeable_ts_fn, why: str) -> ResidentHandle:
+    """A round the program cannot encode: merged synchronously by the
+    numpy spec, and COUNTED twice over — it left the resident lane, and
+    the device did none of its merge. A device-engine compaction that
+    quietly ran on the host is a misread benchmark."""
+    _resident_fallback(h.n, why)
+    _METRICS.incr("compaction.device_host_rounds")
+    warn_once(_log, f"merge.host_round.{why}",
+              "device merge round (%d cells) ran on the host: %s",
+              h.n, why)
+    h.mode = "done"
+    h.result = merge_sorted(batches, h.gc_before, h.now, purgeable_ts_fn)
+    return h
 
 
 # test seam: {round_seq: seconds} delay applied at collect time BEFORE
@@ -258,13 +289,15 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
                           now: int = 0, purgeable_ts_fn=None,
                           prof: dict | None = None,
                           device=None) -> ResidentHandle:
-    """Dispatch one device-resident round (async). Rounds the resident
-    formulation cannot encode (counters, range bounds, oversized
-    frames) dispatch through the regular submit_merge path instead —
-    collect_merge_resident returns a host CellBatch for those."""
+    """Dispatch one device-resident round (async). Rounds the program
+    cannot encode (counters, range bounds, oversized frames) are merged
+    here by the numpy spec instead (_host_round — counted).
+
+    device: an explicit jax.Device to commit the operands to (the mesh
+    compaction path places shard s's round on mesh device s); None =
+    the default device."""
     h = ResidentHandle()
     h.gc_before, h.now, h.prof = gc_before, now, prof
-    h.fallback = None
     with _LED_RESIDENT.busy("merge.resident.concat") as sp:
         cat = CellBatch.concat(batches)
         sp.cells = len(cat)
@@ -273,11 +306,10 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
         h.mode, h.result = "done", cat
         return h
     if ((cat.flags & (FLAG_RANGE_BOUND | FLAG_COUNTER)) != 0).any():
-        _resident_fallback(h.n, "counters or range tombstone bounds")
-        h.mode = "host"
-        h.fallback = dmerge.submit_merge(batches, gc_before, now,
-                                         purgeable_ts_fn, prof=prof)
-        return h
+        # range tombstone coverage is evaluated on full composites and
+        # counters reconcile by summation: host-only passes
+        return _host_round(h, batches, purgeable_ts_fn,
+                           "counters or range tombstone bounds")
     with _LED_RESIDENT.busy("merge.resident.pack", prof=prof, key="pack",
                             cells=h.n) as sp:
         built = build_resident_operands(cat, gc_before, now,
@@ -292,17 +324,18 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
             sp.items = int(operands["lanes"].shape[0])
             sp.nbytes = sum(int(v.nbytes) for v in operands.values())
     if built is None:   # >= 4 GiB frame: let the host path fail loudly
-        _resident_fallback(h.n, "frame exceeds the u32 offset lane")
-        h.mode = "host"
-        h.fallback = dmerge.submit_merge(batches, gc_before, now,
-                                         purgeable_ts_fn, prof=prof)
-        return h
+        return _host_round(h, batches, purgeable_ts_fn,
+                           "frame exceeds the u32 offset lane")
     with _LED_RESIDENT.busy("merge.resident.dispatch") as sp:
         h.out = _resident_program(operands)
+    # jit compiles synchronously inside the dispatch call, per shape AND
+    # per device: the lane's device is part of the key, or lanes 2..n's
+    # compiles read as warm dispatches
     if _kprof.record_dispatch(
             "merge.resident",
             (int(operands["lanes"].shape[0]),
-             int(operands["lanes"].shape[1])), sp.seconds):
+             int(operands["lanes"].shape[1]),
+             getattr(device, "id", None)), sp.seconds):
         _kprof.maybe_record_cost("merge.resident", _resident_program,
                                  (operands,))
     h.mode = "resident"
@@ -321,10 +354,8 @@ def collect_merge_resident(h: ResidentHandle):
     _collect_seq += 1
     if h.mode == "done":
         return promote_round(h.result)
-    if h.mode == "host":
-        return promote_round(dmerge.collect_merge(h.fallback))
     cat, prof = h.cat, h.prof
-    n_keep_d, n_amb_d, n_exp_d, perm_out_d, cols, perm_d, packed_d = h.out
+    n_keep_d, n_amb_d, n_exp_d, perm_out_d, cols = h.out[:5]
     with _LED_RESIDENT.stall("merge.resident.wait", prof=prof,
                              key="device") as sp:
         n_keep = int(n_keep_d)      # blocks until the program finishes
@@ -337,21 +368,10 @@ def collect_merge_resident(h: ResidentHandle):
         if n_amb or n_exp_kept:
             # exact-resolution round: equal-(identity, ts) runs need the
             # host's full-value tie-break, kept expired cells need the
-            # tombstone conversion's payload rewrite — materialize on
-            # the host exactly like ops/merge.py's v1/v2 collect
+            # tombstone conversion's payload rewrite
             _resident_fallback(
                 h.n, "equal-(identity, ts) ties or kept expired-TTL cells")
-            n = h.n
-            perm = np.asarray(perm_d).astype(np.int64)[:n]
-            keep, amb, expired, shadowed = dmerge.unpack_masks(
-                np.asarray(packed_d)[:n])
-            pts_sorted = h.pts[perm] if h.pts is not None else None
-            if amb.any():
-                dmerge.host_tiebreak(cat, perm, keep, amb, shadowed,
-                                     expired, h.gc_before, pts_sorted)
-            out = dmerge.finalize_merged(cat, perm, keep, expired,
-                                         shadowed)
-            return promote_round(out)
+            return promote_round(_materialize(h))
 
         # resident round: pull ONLY the kept permutation (the payload
         # gather's index vector) — the columns stay on the device
@@ -359,6 +379,49 @@ def collect_merge_resident(h: ResidentHandle):
         payload, off, val_start = _gather_payload(cat, perm_kept)
         return DeviceRound(n_keep, cols, payload, off, val_start,
                            dict(cat.pk_map), cat.ck_fits_prefix)
+
+
+def _materialize(h: ResidentHandle) -> CellBatch:
+    """The merged round as a host CellBatch, from a FINISHED program's
+    full permutation and packed masks: exact tie-breaks with full values,
+    then the payload gather and expired -> tombstone conversion."""
+    cat, n = h.cat, h.n
+    perm_d, packed_d = h.out[-2:]
+    perm = np.asarray(perm_d).astype(np.int64)[:n]
+    keep, amb, expired, shadowed = dmerge.unpack_masks(
+        np.asarray(packed_d)[:n])
+    pts_sorted = h.pts[perm] if h.pts is not None else None
+    if amb.any():
+        dmerge.host_tiebreak(cat, perm, keep, amb, shadowed, expired,
+                             h.gc_before, pts_sorted)
+    return dmerge.finalize_merged(cat, perm, keep, expired, shadowed)
+
+
+def materialize_round(h: ResidentHandle) -> CellBatch:
+    """Block on a submitted round and return it as a host CellBatch —
+    the collect of callers with no write lane: the mesh lanes (their
+    shards drain through the host writer in token order) and
+    merge_sorted_device. The kept-cell compaction and column gather the
+    program also did go unused here; that is the price of one program."""
+    if h.mode == "done":
+        return h.result
+    with _LED_RESIDENT.stall("merge.resident.wait", prof=h.prof,
+                             key="device") as sp:
+        jax.block_until_ready(h.out[-2:])
+    _kprof.record_execute("merge.resident", sp.seconds)
+    with _LED_RESIDENT.busy("merge.resident.gather", prof=h.prof,
+                            key="gather", cells=h.n):
+        return _materialize(h)
+
+
+def merge_sorted_device(batches: list[CellBatch], gc_before: int = 0,
+                        now: int = 0, purgeable_ts_fn=None,
+                        prof: dict | None = None) -> CellBatch:
+    """Drop-in equivalent of storage.cellbatch.merge_sorted running the
+    sort/reconcile on the default JAX device. `prof` (optional)
+    accumulates per-phase wall seconds: pack / device / gather."""
+    return materialize_round(submit_merge_resident(
+        batches, gc_before, now, purgeable_ts_fn, prof))
 
 
 def promote_round(batch: CellBatch) -> DeviceRound:

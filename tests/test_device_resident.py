@@ -205,6 +205,108 @@ def test_reverse_completion_order_drains_in_order(tmp_path):
     assert device == serial
 
 
+# ------------------------------------------- one program, host rounds counted --
+
+def _counter_table():
+    return make_table("devres", "cnt", pk=["id"],
+                      cols={"id": "int", "hits": "counter"})
+
+
+def _counter_inputs(cfs, table):
+    cid = table.columns["hits"].column_id
+    for gen in range(1, 4):
+        b = CellBatchBuilder(table)
+        for p in range(300):
+            b.append_raw(table.serialize_partition_key([p]), b"", cid, b"",
+                         (gen * 1000 + p).to_bytes(8, "big"),
+                         ts=100 * gen + p % 7, flags=cb.FLAG_COUNTER)
+        # small segments: the round loop advances a segment at a time
+        w = SSTableWriter(Descriptor(cfs.directory, gen), table,
+                          estimated_partitions=300, segment_cells=100)
+        w.append(cb.merge_sorted([b.seal()]))
+        w.finish()
+
+
+def _range_bound_inputs(cfs, table):
+    from cassandra_tpu.storage.rangetomb import Slice
+    vcol = table.columns["v"].column_id
+    ck = lambda c: table.serialize_clustering([c])
+    for gen in range(1, 4):
+        b = CellBatchBuilder(table)
+        for p in range(100):
+            pk = table.serialize_partition_key([p])
+            for c in range(6):
+                b.add_cell(pk, ck(c), vcol, bytes([gen, c]) * 8,
+                           1000 * gen + c)
+            if gen == 2:   # every partition, so every round, holds one
+                b.add_range_tombstone(
+                    pk, Slice(ck(1), True, ck(3), False, 1500, 0))
+        w = SSTableWriter(Descriptor(cfs.directory, gen), table,
+                          estimated_partitions=100, segment_cells=100)
+        w.append(cb.merge_sorted([b.seal()]))
+        w.finish()
+
+
+@pytest.mark.parametrize("mk_table,build", [
+    (_counter_table, _counter_inputs),
+    (lambda: _table("rt"), _range_bound_inputs),
+], ids=["counter", "range_bound"])
+def test_named_device_task_counts_rounds_it_cannot_encode(
+        tmp_path, mk_table, build, monkeypatch):
+    """choose_engine keeps counter and range-bound inputs off the device
+    engine; a caller that names engine="device" anyway gets numpy's
+    bytes, and each such round is counted twice: it left the resident
+    lane, and the numpy spec merged it."""
+    from cassandra_tpu.compaction import task as task_mod
+    from cassandra_tpu.service.metrics import GLOBAL as METRICS
+    names = ("compaction.device_host_rounds",
+             "compaction.device_resident_fallback")
+    rounds = []
+    submit = task_mod.submit_merge_resident
+    monkeypatch.setattr(
+        task_mod, "submit_merge_resident",
+        lambda slices, **kw: rounds.append(1) or submit(slices, **kw))
+    out, rose = {}, {}
+    table = mk_table()
+    for engine in ("numpy", "device"):
+        cfs = ColumnFamilyStore(table, str(tmp_path / engine),
+                                commitlog=None)
+        build(cfs, table)
+        cfs.reload_sstables()
+        before = [METRICS.counter(n) for n in names]
+        CompactionTask(cfs, cfs.tracker.view(), engine=engine,
+                       round_cells=300).execute()
+        rose[engine] = [METRICS.counter(n) - b
+                        for n, b in zip(names, before)]
+        out[engine] = _hashes(cfs.directory)
+        for r in cfs.live_sstables():
+            r.close()
+    assert out["numpy"] and out["device"] == out["numpy"]
+    assert rose["numpy"] == [0, 0]
+    assert len(rounds) >= 2 and rose["device"] == [len(rounds)] * 2
+
+
+def test_compaction_dispatches_one_merge_program(tmp_path):
+    """Whoever asks — the serial round loop, a mesh lane on its own
+    device, merge_sorted_device — a device-engine round is merged by
+    `merge.resident` and nothing else."""
+    from cassandra_tpu.service import profiling
+    profiling.GLOBAL.reset()
+    table = _table("oneprog")
+    kw = dict(engine="device", compress_pool=0)
+    serial = _compact(tmp_path, "serial", table, **kw)
+    mesh = _compact(tmp_path, "mesh2", table, mesh_devices=2, **kw)
+    assert serial and mesh == serial
+    b = CellBatchBuilder(table)
+    b.add_cell(table.serialize_partition_key([1]),
+               table.serialize_clustering([1]),
+               table.columns["v"].column_id, b"v", 5)
+    assert len(dwrite.merge_sorted_device([b.seal()])) == 1
+    kernels = profiling.GLOBAL.snapshot()["kernels"]
+    assert kernels["merge.resident"]["calls"] >= 3
+    assert set(kernels) <= {"merge.resident", "write.serialize"}
+
+
 # ------------------------------------------------------- decode-ahead knob --
 
 def test_decode_ahead_knob_flip_mid_compaction(tmp_path):

@@ -162,7 +162,6 @@ def test_the_writer_records_cell_kinds_and_the_task_reads_them(
     monkeypatch.setattr(CompactionTask, "DEVICE_MIN_CELLS", 1)
     task = CompactionTask(cfs, ssts, backend_probe=lambda: True)
     assert task.engine_chosen and task.engine == want
-    assert task.device_resident == (want == "device")
 
 
 def test_counter_inputs_go_to_the_host_engine(engine, session, monkeypatch):
@@ -215,7 +214,6 @@ def test_on_the_cpu_a_task_resolves_as_it_always_did(plain_store):
     task = CompactionTask(plain_store, plain_store.live_sstables())
     assert task.engine == HOST and task.engine_chosen
     assert task.round_cells == CompactionTask.ROUND_CELLS_HOST
-    assert task.device_resident is False
 
 
 def test_the_module_probe_is_what_an_unnamed_task_asks(plain_store,
@@ -224,7 +222,6 @@ def test_the_module_probe_is_what_an_unnamed_task_asks(plain_store,
     task = CompactionTask(plain_store, plain_store.live_sstables())
     assert task.engine == "device" and task.engine_chosen
     assert task.round_cells == CompactionTask.ROUND_CELLS_DEVICE
-    assert task.device_resident is True
 
 
 def _ring():

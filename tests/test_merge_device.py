@@ -6,7 +6,7 @@ import random
 import numpy as np
 import pytest
 
-from cassandra_tpu.ops import merge as dmerge
+from cassandra_tpu.ops.device_write import merge_sorted_device
 from cassandra_tpu.schema import COL_REGULAR_BASE, make_table
 from cassandra_tpu.storage import cellbatch as cb
 
@@ -67,7 +67,7 @@ def random_batches(seed, n_batches=4, n_cells=300, n_parts=12, n_cks=6):
 def test_random_equivalence(seed):
     batches = random_batches(seed)
     ref = cb.merge_sorted(batches)
-    dev = dmerge.merge_sorted_device(batches)
+    dev = merge_sorted_device(batches)
     assert_equal_batches(ref, dev)
 
 
@@ -75,7 +75,7 @@ def test_random_equivalence(seed):
 def test_random_equivalence_with_gc(seed):
     batches = random_batches(seed)
     ref = cb.merge_sorted(batches, gc_before=50, now=60)
-    dev = dmerge.merge_sorted_device(batches, gc_before=50, now=60)
+    dev = merge_sorted_device(batches, gc_before=50, now=60)
     assert_equal_batches(ref, dev)
 
 
@@ -83,7 +83,7 @@ def test_equivalence_with_purge_guard(seed=11):
     batches = random_batches(seed)
     guard = lambda s: (s.ts % 7) * 5  # arbitrary per-cell guard
     ref = cb.merge_sorted(batches, gc_before=80, now=60, purgeable_ts_fn=guard)
-    dev = dmerge.merge_sorted_device(batches, gc_before=80, now=60,
+    dev = merge_sorted_device(batches, gc_before=80, now=60,
                                      purgeable_ts_fn=guard)
     assert_equal_batches(ref, dev)
 
@@ -101,7 +101,7 @@ def test_directed_cases_on_device():
     b.add_cell(pk(3), ck(1), V, b"shadowed", 400)
     batch = b.seal()
     ref = cb.merge_sorted([batch])
-    dev = dmerge.merge_sorted_device([batch])
+    dev = merge_sorted_device([batch])
     assert_equal_batches(ref, dev)
     # sanity on content
     vals = {dev.cell_value(i) for i in range(len(dev))}
@@ -110,11 +110,11 @@ def test_directed_cases_on_device():
 
 
 def test_empty_and_single():
-    assert len(dmerge.merge_sorted_device([cb.CellBatchBuilder(T).seal()])) == 0
+    assert len(merge_sorted_device([cb.CellBatchBuilder(T).seal()])) == 0
     b = cb.CellBatchBuilder(T)
     b.add_cell(pk(1), ck(1), COL_REGULAR_BASE, b"v", 1)
     ref = cb.merge_sorted([b.seal()])
-    dev = dmerge.merge_sorted_device([b.seal()])
+    dev = merge_sorted_device([b.seal()])
     assert_equal_batches(ref, dev)
 
 
@@ -132,7 +132,7 @@ def test_counter_sum_both_paths():
                          ts=100 * gen + j, flags=cb.FLAG_COUNTER)
         batches.append(b.seal())
     ref = cb.merge_sorted(batches)
-    dev = dmerge.merge_sorted_device(batches)
+    dev = merge_sorted_device(batches)
     assert len(ref) == 1 and len(dev) == 1
     for m in (ref, dev):
         v = int.from_bytes(m.cell_value(0), "big", signed=True)

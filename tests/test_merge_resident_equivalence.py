@@ -1,15 +1,15 @@
-"""The truncated-key device fast path (ops/merge.py v3) must be
-bit-identical to the numpy spec. It activates only for sorted runs with no
-deletions/counters; these tests construct qualifying rounds — including
-timestamps that collide in the truncated (ts >> 24) space, where exact
-ordering is resolved host-side — and verify both the result and that the
-fast path was actually taken."""
+"""The one device merge program (ops/device_write.py `merge.resident`)
+must be bit-identical to the numpy spec on the rounds that once had a
+layout of their own: sorted live runs whose timestamps cluster or collide
+exactly (the host resolves equal-(identity, ts) runs with full values),
+wide timestamps, TTL expiry under a purge guard, and unsorted or
+tombstone rounds."""
 import random
 
 import numpy as np
 import pytest
 
-from cassandra_tpu.ops import merge as dmerge
+from cassandra_tpu.ops.device_write import merge_sorted_device
 from cassandra_tpu.schema import COL_REGULAR_BASE, make_table
 from cassandra_tpu.storage import cellbatch as cb
 
@@ -67,17 +67,11 @@ def sorted_live_batches(seed, n_batches=4, n_cells=400, n_parts=16,
     return out
 
 
-def assert_fast(batches):
-    h = dmerge.submit_merge(batches)
-    assert h.mode == "fast", h.mode
-    return dmerge.collect_merge(h)
-
-
 @pytest.mark.parametrize("seed", [1, 2, 3, 4])
 def test_collision_equivalence(seed):
     batches = sorted_live_batches(seed)
     ref = cb.merge_sorted(batches)
-    dev = assert_fast(batches)
+    dev = merge_sorted_device(batches)
     assert_equal_batches(ref, dev)
 
 
@@ -85,7 +79,7 @@ def test_collision_equivalence(seed):
 def test_wide_ts_equivalence(seed):
     batches = sorted_live_batches(seed, collide=False)
     ref = cb.merge_sorted(batches)
-    dev = assert_fast(batches)
+    dev = merge_sorted_device(batches)
     assert_equal_batches(ref, dev)
 
 
@@ -93,12 +87,12 @@ def test_wide_ts_equivalence(seed):
 def test_ttl_expiry_and_purge(seed):
     batches = sorted_live_batches(seed, ttl_frac=0.3)
     ref = cb.merge_sorted(batches, gc_before=35, now=30)
-    dev = dmerge.merge_sorted_device(batches, gc_before=35, now=30)
+    dev = merge_sorted_device(batches, gc_before=35, now=30)
     assert_equal_batches(ref, dev)
     guard = lambda s: (s.ts % 7) * (1 << 28)
     ref = cb.merge_sorted(batches, gc_before=35, now=30,
                           purgeable_ts_fn=guard)
-    dev = dmerge.merge_sorted_device(batches, gc_before=35, now=30,
+    dev = merge_sorted_device(batches, gc_before=35, now=30,
                                      purgeable_ts_fn=guard)
     assert_equal_batches(ref, dev)
 
@@ -113,26 +107,23 @@ def test_equal_ts_value_tiebreak():
             b.add_cell(pk(1), ck(1), COL_REGULAR_BASE, v, 100)
             batches.append(cb.merge_sorted([b.seal()]))
         ref = cb.merge_sorted(batches)
-        dev = assert_fast(batches)
+        dev = merge_sorted_device(batches)
         assert_equal_batches(ref, dev)
         outs.append(dev.cell_value(0))
     assert outs == [b"abcdZ", b"abcdZ"]
 
 
-def test_unsorted_or_deleting_rounds_fall_back():
+def test_unsorted_or_deleting_rounds():
     b = cb.CellBatchBuilder(T)
     b.add_cell(pk(2), ck(1), COL_REGULAR_BASE, b"v", 5)
     b.add_cell(pk(1), ck(1), COL_REGULAR_BASE, b"v", 5)
     unsorted = b.seal()
-    assert dmerge.submit_merge([unsorted]).mode != "fast"
     b2 = cb.CellBatchBuilder(T)
     b2.add_tombstone(pk(1), ck(1), COL_REGULAR_BASE, 10, 100)
     tomb = cb.merge_sorted([b2.seal()])
-    assert dmerge.submit_merge([tomb]).mode != "fast"
-    # both still produce correct results through their fallback paths
     for batches in ([unsorted], [tomb]):
         assert_equal_batches(cb.merge_sorted(batches),
-                             dmerge.merge_sorted_device(batches))
+                             merge_sorted_device(batches))
 
 
 def test_pipelined_task_matches_numpy(tmp_path):

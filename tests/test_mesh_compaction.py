@@ -140,7 +140,16 @@ def test_boundaries_from_indexes_skewed_fixture(tmp_path):
 
 # ------------------------------------------------ compaction identity --
 
-def test_mesh_compaction_byte_identity(tmp_path):
+@pytest.mark.parametrize("legs", [
+    {"serial": dict(mesh_devices=0),
+     "mesh1": dict(mesh_devices=1),
+     "mesh4": dict(mesh_devices=4)},
+    # the device engine's lanes: the serial loop's program
+    # (merge.resident), one jax device per lane, against the numpy spec
+    {"serial": dict(engine="numpy", mesh_devices=0),
+     "device_mesh2": dict(engine="device", mesh_devices=2)},
+], ids=["host", "device"])
+def test_mesh_compaction_byte_identity(tmp_path, legs):
     """serial vs mesh-1 vs mesh-4: sha256-identical components and
     equal merged-view digests — the mesh drains shard results in token
     order through the same writer, so bytes cannot depend on the lane
@@ -154,11 +163,6 @@ def test_mesh_compaction_byte_identity(tmp_path):
                           estimated_partitions=256)
         w.append(ab._mixed_batch(table, seed=gen, n=60_000))
         w.finish()
-    legs = {
-        "serial": dict(mesh_devices=0),
-        "mesh1": dict(mesh_devices=1),
-        "mesh4": dict(mesh_devices=4),
-    }
     results = {tag: ab._compaction_leg(str(tmp_path), pristine, table,
                                        tag, **kw)
                for tag, kw in legs.items()}

@@ -301,7 +301,7 @@ def test_metric_name_check_script():
 def test_kernel_profiler_splits_compile_from_execute():
     import numpy as np
 
-    from cassandra_tpu.ops import merge as dmerge
+    from cassandra_tpu.ops.device_write import merge_sorted_device
     from cassandra_tpu.schema import make_table
     from cassandra_tpu.storage import cellbatch as cb
     from cassandra_tpu.tools import bulk
@@ -317,8 +317,8 @@ def test_kernel_profiler_splits_compile_from_execute():
             rng.integers(0, 256, (n, 8), dtype=np.uint8),
             rng.integers(1, 1 << 40, n).astype(np.int64))
         batches.append(cb.merge_sorted([b]))
-    a = dmerge.merge_sorted_device(batches)
-    b2 = dmerge.merge_sorted_device(batches)
+    a = merge_sorted_device(batches)
+    b2 = merge_sorted_device(batches)
     assert len(a) == len(b2)
     snap = profiling.GLOBAL.snapshot()
     kernels = snap["kernels"]

@@ -101,34 +101,6 @@ def test_resident_round(one_chip):
     _compile("merge.resident", _resident_program, ops)
 
 
-def test_plane_fast_round(one_chip):
-    """The v3 truncated-key program (mesh lanes and non-resident device
-    rounds), with the plane layout the seeded stress data really packs
-    to."""
-    from cassandra_tpu.ops import merge as dmerge
-    from cassandra_tpu.schema import make_table
-    from cassandra_tpu.storage import cellbatch as cb
-    from cassandra_tpu.tools import bulk
-    table = make_table("bench", "stress", pk=["id"], ck=["c"],
-                       cols={"id": "int", "c": "int", "v": "blob"})
-    rng = np.random.default_rng(0)
-    runs = []
-    for _ in range(4):
-        n = 1 << 13
-        runs.append(cb.merge_sorted([bulk.build_int_batch(
-            table, rng.integers(0, 4096, n), rng.integers(1, 50_000, n),
-            rng.integers(0, 256, (n, 64), dtype=np.uint8),
-            rng.integers(1, 1 << 40, n).astype(np.int64))]))
-    _buf, cfg, _meta = dmerge._plane_pack_fast(cb.CellBatch.concat(runs),
-                                               runs)
-    rank_dt, lane_dts, q_dts, k = cfg
-    cell_bytes = sum(np.dtype(d).itemsize
-                     for d in (rank_dt,) + lane_dts + q_dts)
-    buf = jax.ShapeDtypeStruct((ROUND * cell_bytes + 4 * (k + 1),),
-                               jnp.uint8, sharding=one_chip)
-    _compile("merge.plane_fast", dmerge._plane_program_fast, buf, cfg=cfg)
-
-
 def test_meta_block_segment(one_chip):
     from cassandra_tpu.ops.device_write import _meta_block_kernel
 
