@@ -99,11 +99,23 @@ def zonemap_columns(table: TableMetadata) -> list[tuple[int, str, int]]:
 # ---------------------------------------------------------------- scan keys --
 
 def _fold_be(b: np.ndarray) -> np.ndarray:
-    """Big-endian fold of a [n, w] uint8 byte matrix into u64."""
-    k = np.zeros(len(b), dtype=np.uint64)
-    for j in range(b.shape[1]):
-        k = (k << np.uint64(8)) | b[:, j].astype(np.uint64)
-    return k
+    """Big-endian fold of a [n, w] uint8 byte matrix (w <= 8) into u64:
+    the rows right-aligned in 8 bytes, read as one big-endian word."""
+    wide = np.zeros((len(b), 8), dtype=np.uint8)
+    wide[:, 8 - b.shape[1]:] = b
+    return wide.view(">u8").reshape(len(b)).astype(np.uint64)
+
+
+# _PREFIX_MASK[k]: the k most significant bytes of a u64
+_PREFIX_MASK = np.array([(_U64_MAX >> (8 * (8 - k))) << (8 * (8 - k))
+                         for k in range(9)], dtype=np.uint64)
+
+
+def _bytes_at(payload: np.ndarray, at: np.ndarray) -> np.ndarray:
+    """[n, 8] uint8: the 8 payload bytes from each offset on (zeros past
+    the payload's end) — one gather of n rows, not of 8n bytes."""
+    padded = np.concatenate([payload, np.zeros(8, dtype=np.uint8)])
+    return np.lib.stride_tricks.sliding_window_view(padded, 8)[at]
 
 
 def _f64_order(vals: np.ndarray) -> np.ndarray:
@@ -124,24 +136,16 @@ def keys_from_values(kind: str, width: int, payload: np.ndarray,
     conservative, and such cells cannot appear through the write path).
     """
     n = len(vs)
-    keys = np.zeros(n, dtype=np.uint64)
     ln = ve - vs
     if n == 0:
-        return keys, np.ones(0, dtype=bool)
-    if len(payload) == 0:       # all-empty frames: nothing to gather
-        payload = np.zeros(1, dtype=np.uint8)
+        return np.zeros(0, dtype=np.uint64), np.ones(0, dtype=bool)
     if kind == "prefix":
-        take = np.minimum(ln, 8)
-        idx = vs[:, None] + np.arange(8, dtype=vs.dtype)[None, :]
-        have = np.arange(8)[None, :] < take[:, None]
-        b = np.where(have,
-                     payload[np.minimum(idx, len(payload) - 1)],
-                     np.uint8(0))
-        return _fold_be(b), np.ones(n, dtype=bool)
+        # the first 8 bytes as one big-endian word, the bytes past the
+        # value's end masked off
+        return (_fold_be(_bytes_at(payload, vs))
+                & _PREFIX_MASK[np.minimum(ln, 8)]), np.ones(n, dtype=bool)
     valid = ln == width
-    safe_vs = np.where(valid, vs, 0)
-    idx = safe_vs[:, None] + np.arange(width, dtype=vs.dtype)[None, :]
-    b = payload[np.minimum(idx, len(payload) - 1)].reshape(n, width)
+    b = _bytes_at(payload, np.where(valid, vs, 0))[:, :width]
     raw = _fold_be(b)
     if kind == "bool":
         return raw, valid
@@ -279,30 +283,60 @@ def segment_zone_entries(zone_cols, col_lane, flags, vs, ve, payload):
     shared by the writer tail (flush/compaction) and the rebuild path.
     `dead` counts death-flagged cells of the column (tombstones at any
     scope); empty-range sentinels are (U64_MAX, 0). A cell the kind
-    cannot key widens the column to the full key range (never prunes)."""
+    cannot key widens the column to the full key range (never prunes).
+
+    All columns in one pass: the cells are binned by column slot and the
+    keys folded per (kind, width), so a segment costs a fixed number of
+    array calls whatever the table's width — the writer runs this on
+    the thread that gates a compaction, where under a server's other
+    Python threads every array call is a wait for the GIL."""
     from ..storage.cellbatch import DEATH_FLAGS
+    C = len(zone_cols)
+    if not C:
+        return []
     col_lane = np.asarray(col_lane)
-    flags = np.asarray(flags)
-    out = []
-    for cid, kind, width in zone_cols:
-        sel = col_lane == cid
-        n_col = int(sel.sum())
-        if n_col == 0:
-            out.append((_U64_MAX, 0, 0, 0))
+    cids = np.asarray([cid for cid, _, _ in zone_cols], dtype=col_lane.dtype)
+    # slot C: a cell of no mapped column, or (for the keys) a dead one
+    slot_of = np.full(int(cids.max()) + 2, C, dtype=np.int64)
+    slot_of[cids] = np.arange(C)
+    slot = slot_of[np.minimum(col_lane, len(slot_of) - 1)]
+    n_col = np.bincount(slot, minlength=C + 1)
+    slot = np.where((np.asarray(flags) & DEATH_FLAGS) != 0, C, slot)
+    live = np.bincount(slot, minlength=C + 1)
+    kmin = np.full(C + 1, _U64_MAX, dtype=np.uint64)
+    kmax = np.zeros(C + 1, dtype=np.uint64)
+    unkeyed = np.zeros(C + 1, dtype=np.int64)
+    idx = np.flatnonzero(slot < C)
+    slot = slot[idx]
+    kinds = sorted({(kind, width) for _, kind, width in zone_cols})
+    ends = [len(idx)]
+    if len(kinds) > 1:      # the cells of one (kind, width) side by side
+        kind_of = np.asarray([kinds.index((kind, width))
+                              for _, kind, width in zone_cols])
+        by_kind = np.argsort(kind_of[slot], kind="stable")
+        idx, slot = idx[by_kind], slot[by_kind]
+        ends = np.searchsorted(kind_of[slot], np.arange(len(kinds)),
+                               side="right")
+    lo = 0
+    for (kind, width), hi in zip(kinds, ends):
+        sel, s = idx[lo:hi], slot[lo:hi]
+        lo = hi
+        if not len(sel):
             continue
-        alive = sel & ((flags & DEATH_FLAGS) == 0)
-        live = int(alive.sum())
-        dead = n_col - live
-        if live == 0:
-            out.append((_U64_MAX, 0, 0, dead))
-            continue
-        idx = np.flatnonzero(alive)
         keys, valid = keys_from_values(kind, width, payload,
-                                       vs[idx], ve[idx])
-        if not valid.all():
-            out.append((0, _U64_MAX, live, dead))
-            continue
-        out.append((int(keys.min()), int(keys.max()), live, dead))
+                                       vs[sel], ve[sel])
+        np.minimum.at(kmin, s, keys)
+        np.maximum.at(kmax, s, keys)
+        unkeyed += np.bincount(s[~valid], minlength=C + 1)
+    out = []
+    for c in range(C):
+        dead = int(n_col[c]) - int(live[c])
+        if live[c] == 0:
+            out.append((_U64_MAX, 0, 0, dead))
+        elif unkeyed[c]:
+            out.append((0, _U64_MAX, int(live[c]), dead))
+        else:
+            out.append((int(kmin[c]), int(kmax[c]), int(live[c]), dead))
     return out
 
 

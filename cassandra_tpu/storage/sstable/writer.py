@@ -7,7 +7,8 @@ path), BigTableWriter.java:237-254 (bloom + index build during append).
 The writer consumes *sorted* batches (flush output or merge-kernel output),
 cuts fixed-size segments, compresses each segment's three blocks through
 the table codec's batch API (one FFI crossing per segment), and maintains
-the bloom filter / partition directory / stats as it goes.
+the partition directory / zone map / stats as it goes; the bloom filter
+is built from the directory's keys at seal.
 
 Write-leg staging (docs/compaction-executor.md):
 
@@ -113,6 +114,11 @@ def build_meta_block(ts: "np.ndarray", ldt: "np.ndarray",
         meta[pos:end] = np.ascontiguousarray(arr).view(np.uint8)
         pos = end
     return meta
+
+
+def _cat(chunks: list) -> "np.ndarray":
+    """The directory's per-segment int64 chunks as one array."""
+    return np.concatenate(chunks) if chunks else np.zeros(0, dtype=np.int64)
 
 
 def _part_starts(lanes_c: "np.ndarray", n: int) -> "np.ndarray":
@@ -289,10 +295,14 @@ class SSTableWriter:
         self._allocated = 0
         self._index_entries: list[bytes] = []
         self._bloom = bloom.BloomFilter.create(max(estimated_partitions, 16))
-        # partition directory accumulators
-        self._part_lane4: list[bytes] = []
-        self._part_first_cell: list[int] = []
-        self._part_pk: list[bytes] = []
+        # partition directory accumulators: one chunk per segment that
+        # opened a partition (_index_segment), concatenated at seal
+        # (_write_partitions)
+        self._part_lane4: list[bytes] = []        # m x 16 B, big-endian
+        self._part_first_cell: list[np.ndarray] = []
+        self._part_pk_len: list[np.ndarray] = []
+        self._part_pk: list[bytes] = []           # the m pks, joined
+        self._n_partitions = 0
         self._last_lane4: bytes | None = None
         # adaptive compression skip, per block stream (meta/lanes/payload):
         # after 4 consecutive raw-stored blocks the next 15 skip the
@@ -1105,7 +1115,7 @@ class SSTableWriter:
                       pk_map: dict, seg_stats: tuple,
                       device_pack=None) -> None:
         """Everything downstream of block serialization for ONE segment:
-        ordering guards, partition directory + bloom, stats fold,
+        ordering guards, zone map, partition directory, stats fold,
         adaptive-skip attempt decision, compress (pool / serial / the
         per-block fallback), index entry and digest bookkeeping. The
         host path enters from _cut_segment with blocks it built in
@@ -1141,8 +1151,17 @@ class SSTableWriter:
                        pk_map: dict, seg_stats: tuple) -> int:
         """The sequential bookkeeping of one segment, in append order on
         the appending thread: ordering guard, zone map, partition
-        directory + bloom (one Python iteration per partition START in
-        the segment), stats fold. Returns the partitions it opened."""
+        directory, stats fold. Returns the partitions it opened.
+
+        The directory costs a fixed handful of array calls a segment,
+        whatever it holds: no Python statement runs once per partition.
+        A start is a row where the four pk lanes CHANGE, so no start
+        can carry the key of the start before it, and only the FIRST
+        start of a segment can equal the last partition the previous
+        segment opened ("partition continues from the previous
+        segment"): that is decided once, here, which is exactly what
+        comparing every start with the entry just before it decides.
+        The bloom is fed from the same chunks at seal (_write_filter)."""
         # cross-segment ordering guard; the intra-segment check runs
         # inside segment_pack's delta loop (fast path) or the numpy
         # comparison below (fallback path)
@@ -1165,25 +1184,30 @@ class SSTableWriter:
         if self._zone_acc is not None:
             self._accumulate_zone(n, meta, lanes_c, payload_b)
 
-        # --- partition directory + bloom: one native pass over the
+        # --- partition directory: one native pass over the
         # lanes finds the rows where the 4 pk lanes change (the numpy
         # strided slice-copy + row-compare this replaces was a measured
         # write-leg hotspot)
         starts = _part_starts(lanes_c, n)
-        new_keys = []
-        for s in starts:
-            l4 = lanes_c[s, :4].astype(">u4").tobytes()
-            if l4 == self._last_lane4:
-                continue  # partition continues from previous segment
-            pk = pk_map.get(l4)
-            if pk is None:
-                raise ValueError("pk_map missing partition key")
-            self._part_lane4.append(l4)
-            self._part_first_cell.append(self._total_cells + int(s))
-            self._part_pk.append(pk)
-            new_keys.append(pk)
-            self._last_lane4 = l4
-        self._bloom.add_batch(new_keys)
+        # the directory stores the lanes big-endian: a row's 16 bytes
+        # ARE its pk_map key (reader._segment_keys reads them back so)
+        lane4 = lanes_c[starts, :4].astype(">u4")
+        if lane4[0].tobytes() == self._last_lane4:
+            starts, lane4 = starts[1:], lane4[1:]
+        opened = len(starts)
+        if opened:
+            try:
+                pks = list(map(pk_map.__getitem__,
+                               lane4.view("V16").ravel().tolist()))
+            except KeyError:
+                raise ValueError("pk_map missing partition key") from None
+            self._part_lane4.append(lane4.tobytes())
+            self._part_first_cell.append(self._total_cells + starts)
+            self._part_pk_len.append(
+                np.fromiter(map(len, pks), dtype=np.int64, count=opened))
+            self._part_pk.append(b"".join(pks))
+            self._n_partitions += opened
+            self._last_lane4 = lane4[-1].tobytes()
 
         # --- stats
         st = self._stats
@@ -1203,7 +1227,7 @@ class SSTableWriter:
         # the flags plane of the "ce" META block (build_meta_block):
         # ts-delta 8 + ldt 4 + ttl 4 bytes per cell precede it
         st["cell_flags"] |= int(np.bitwise_or.reduce(meta[16 * n:17 * n]))
-        return len(new_keys)
+        return opened
 
     def _pack_segment(self, n: int, meta: "np.ndarray",
                       lanes_c: "np.ndarray", payload_b: "np.ndarray",
@@ -1364,17 +1388,26 @@ class SSTableWriter:
         self._write_component(Component.INDEX, bytes(out))
 
     def _write_partitions(self) -> None:
-        np_count = len(self._part_lane4)
-        out = bytearray(struct.pack("<I", np_count))
+        """Partitions.db: count, the 16-byte keys, `<i8` first cells,
+        `<i8` pk offsets, the pk bytes, from the per-segment chunks."""
+        count = self._n_partitions
+        out = bytearray(struct.pack("<I", count))
         out += b"".join(self._part_lane4)
-        out += np.array(self._part_first_cell, dtype="<i8").tobytes()
-        pk_off = np.zeros(np_count + 1, dtype="<i8")
-        np.cumsum([len(p) for p in self._part_pk], out=pk_off[1:])
+        out += _cat(self._part_first_cell).astype("<i8", copy=False).tobytes()
+        pk_off = np.zeros(count + 1, dtype="<i8")
+        np.cumsum(_cat(self._part_pk_len), out=pk_off[1:])
         out += pk_off.tobytes()
         out += b"".join(self._part_pk)
         self._write_component(Component.PARTITIONS, bytes(out))
 
     def _write_filter(self) -> None:
+        # the bloom takes every pk of the sstable here, at seal, in a
+        # few bounded batches: hashing a segment's keys as they came
+        # cost the appending thread ~100 array calls a segment, and under
+        # a server's other Python threads each of them is a wait for
+        # the GIL (PERF.md, PR 30)
+        self._bloom.add_blob(b"".join(self._part_pk),
+                             _cat(self._part_pk_len))
         with open(self.desc.tmp_path(Component.FILTER), "wb") as f:
             f.write(self._bloom.serialize())
 
@@ -1387,7 +1420,7 @@ class SSTableWriter:
             "n_lanes": self.K,
             "segment_cells": self.segment_cells,
             "n_cells": self._total_cells,
-            "n_partitions": len(self._part_lane4),
+            "n_partitions": self._n_partitions,
             "compression": self.params.to_dict(),
             "level": self.level,
             "repaired_at": self.repaired_at,

@@ -41,13 +41,29 @@ class BloomFilter:
             idx = (h1[:, None] + i[None, :] * h2[:, None]) % np.uint64(self.bits)
         return idx
 
+    # keys hashed in one pass: bounds the hash's temporaries (≈ 200 B a
+    # key and 16-byte block) however many keys a caller brings
+    _BATCH = 1 << 18
+
     def add_batch(self, keys: list[bytes]) -> None:
-        if not keys:
-            return
-        h1, h2 = murmur3.hash128_batch(keys)
-        idx = self._indexes(h1, h2).ravel()
-        np.bitwise_or.at(self.words, (idx >> np.uint64(6)).astype(np.int64),
-                         np.uint64(1) << (idx & np.uint64(63)))
+        self.add_blob(b"".join(keys),
+                      np.fromiter(map(len, keys), dtype=np.int64,
+                                  count=len(keys)))
+
+    def add_blob(self, blob: bytes, lens: np.ndarray) -> None:
+        """Add n keys stored back to back in `blob` (key i is lens[i]
+        bytes): a fixed number of array calls per _BATCH keys."""
+        end = np.cumsum(lens)
+        view = memoryview(blob)
+        for lo in range(0, len(lens), self._BATCH):
+            part = lens[lo:lo + self._BATCH]
+            a, b = int(end[lo] - lens[lo]), int(end[lo + len(part) - 1])
+            h1, h2 = murmur3.hash128_mat(murmur3.pad_blob(view[a:b], part),
+                                         part)
+            idx = self._indexes(h1, h2).ravel()
+            np.bitwise_or.at(self.words,
+                             (idx >> np.uint64(6)).astype(np.int64),
+                             np.uint64(1) << (idx & np.uint64(63)))
 
     def add(self, key: bytes) -> None:
         self.add_batch([key])

@@ -114,17 +114,27 @@ MIN_TOKEN = -(1 << 63)  # ring origin; no key hashes to it after normalisation
 
 # ---------------------------------------------------------------- batch ----
 
-def _pad_keys(keys: list[bytes]) -> tuple[np.ndarray, np.ndarray]:
-    """Pack variable-length keys into a (n, maxlen) uint8 matrix + lengths."""
-    n = len(keys)
-    lens = np.fromiter((len(k) for k in keys), dtype=np.int64, count=n)
+def pad_blob(blob: bytes, lens: np.ndarray) -> np.ndarray:
+    """(n, width) zero-padded uint8 matrix of n keys stored back to back
+    in `blob` (key i is lens[i] bytes), in the form hash128_mat takes: one
+    scatter, no Python statement per key."""
+    n = len(lens)
     maxlen = int(lens.max()) if n else 0
     # round up to a 16-byte block boundary (+16 so tail logic has room)
     width = ((maxlen + 15) // 16 + 1) * 16
     mat = np.zeros((n, width), dtype=np.uint8)
-    for i, k in enumerate(keys):
-        mat[i, : len(k)] = np.frombuffer(k, dtype=np.uint8)
-    return mat, lens
+    flat = np.frombuffer(blob, dtype=np.uint8)
+    # byte j of the blob lands at row_start[its key] + (j - key_start)
+    shift = np.arange(n, dtype=np.int64) * width - (np.cumsum(lens) - lens)
+    mat.reshape(-1)[np.arange(len(flat), dtype=np.int64)
+                    + np.repeat(shift, lens)] = flat
+    return mat
+
+
+def _pad_keys(keys: list[bytes]) -> tuple[np.ndarray, np.ndarray]:
+    """Pack variable-length keys into a (n, maxlen) uint8 matrix + lengths."""
+    lens = np.fromiter(map(len, keys), dtype=np.int64, count=len(keys))
+    return pad_blob(b"".join(keys), lens), lens
 
 
 def hash128_batch(keys: list[bytes], seed: int = 0) -> tuple[np.ndarray, np.ndarray]:

@@ -458,3 +458,91 @@ def test_in_and_text_prefix_predicates(session, eng):
         "SELECT k FROM tp WHERE v IN (3, 17, 44, 99) "
         "ALLOW FILTERING").rows}
     assert got == {3, 17, 44}
+
+
+# ---------------------------------------------- zone entries, all columns --
+
+def _zone_entries_column_by_column(zone_cols, col_lane, flags, vs, ve,
+                                   payload):
+    """segment_zone_entries the plain way: one column at a time, one
+    value at a time (Python ints and bytes; no scan-key arithmetic
+    shared with the program except key_of_value's definition of a
+    prefix/int key)."""
+    import struct
+
+    from cassandra_tpu.storage.cellbatch import DEATH_FLAGS
+    raw = bytes(payload)
+    out = []
+    for cid, kind, width in zone_cols:
+        keys, live, dead, unkeyed = [], 0, 0, False
+        for i in np.flatnonzero(np.asarray(col_lane) == cid):
+            if flags[i] & DEATH_FLAGS:
+                dead += 1
+                continue
+            live += 1
+            v = raw[int(vs[i]):int(ve[i])]
+            if kind == "prefix":
+                keys.append(int.from_bytes(v[:8].ljust(8, b"\0"), "big"))
+            elif len(v) != width:
+                unkeyed = True
+            elif kind == "bool":
+                keys.append(v[0])
+            elif kind == "i64":
+                keys.append(int.from_bytes(v, "big", signed=True) + (1 << 63))
+            else:
+                f = struct.unpack(">f" if width == 4 else ">d", v)[0] + 0.0
+                bits = struct.unpack(">Q", struct.pack(">d", f))[0]
+                keys.append(bits ^ (2**64 - 1) if bits >> 63
+                            else bits | 1 << 63)
+        if not live:
+            out.append((2**64 - 1, 0, 0, dead))
+        elif unkeyed:
+            out.append((0, 2**64 - 1, live, dead))
+        else:
+            out.append((min(keys), max(keys), live, dead))
+    return out
+
+
+ZONE_TABLES = {
+    "ten_text_columns": [(8 + i, "prefix", 0) for i in range(10)],
+    "mixed_kinds": [(8, "prefix", 0), (9, "i64", 4), (10, "f64", 8),
+                    (11, "bool", 1), (12, "i64", 8), (13, "f64", 4),
+                    (20, "prefix", 0)],
+    "one_int_column": [(9, "i64", 4)],
+    "ids_not_ascending": [(30, "prefix", 0), (10, "i64", 2)],
+}
+
+
+@pytest.mark.parametrize("dead_share, misfit_share",
+                         [(0.0, 0.0), (0.2, 0.0), (1.0, 0.0), (0.1, 0.02)])
+@pytest.mark.parametrize("table", list(ZONE_TABLES))
+def test_zone_entries_match_column_by_column(table, dead_share,
+                                             misfit_share):
+    zone_cols = ZONE_TABLES[table]
+    rng = np.random.default_rng(len(table))
+    widths = {c: w for c, k, w in zone_cols if k != "prefix"}
+    for n in (1, 7, 600):
+        # cells of the mapped columns, of an unmapped one (3) and of one
+        # past the last id, some dead, some with a value no kind fits
+        col_lane = rng.choice([c for c, _, _ in zone_cols] + [3, 999],
+                              n).astype(np.uint32)
+        flags = np.where(rng.random(n) < dead_share,
+                         rng.choice([1, 4, 8, 32], n), 0).astype(np.uint8)
+        flags |= rng.choice([0, 2], n).astype(np.uint8)   # not a death flag
+        lens = np.array([widths.get(int(c), int(rng.integers(0, 14)))
+                         for c in col_lane])
+        lens = np.where(rng.random(n) < misfit_share, lens + 1, lens)
+        off = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(lens + 2, out=off[1:])
+        payload = rng.integers(0, 256, int(off[-1]), dtype=np.uint8)
+        # finite floats only: the reference orders by value
+        for i in np.flatnonzero(np.isin(col_lane, [10, 13])):
+            w = int(lens[i])
+            if w in (4, 8) and table == "mixed_kinds":
+                v = np.array([rng.normal() * 1e3], dtype=">f4" if w == 4
+                             else ">f8")
+                payload[off[i] + 2:off[i] + 2 + w] = v.view(np.uint8)
+        args = (col_lane, flags, off[:-1] + 2, off[1:], payload)
+        assert ds.segment_zone_entries(zone_cols, *args) == \
+            _zone_entries_column_by_column(zone_cols, *args)
+    assert ds.segment_zone_entries([], *args) == []

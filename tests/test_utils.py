@@ -196,3 +196,23 @@ def test_bloom_filter():
     data = bf.serialize()
     bf2 = bloom.BloomFilter.deserialize(data)
     assert bf2.might_contain_batch(keys).all()
+
+
+@pytest.mark.parametrize("batch", [1 << 18, 7])
+def test_bloom_add_blob_is_add_by_add(batch, monkeypatch):
+    """Keys stored back to back, hashed in bounded batches, set the bits
+    the scalar hash sets one key at a time — whatever the batch size."""
+    monkeypatch.setattr(bloom.BloomFilter, "_BATCH", batch)
+    rng = random.Random(5)
+    keys = [bytes(rng.randrange(256) for _ in range(rng.choice(
+        (0, 1, 9, 15, 16, 17, 23, 32, 33, 50)))) for _ in range(40)]
+    bf = bloom.BloomFilter.create(100)
+    bf.add_blob(b"".join(keys), np.array([len(k) for k in keys]))
+    want = 0
+    for key in keys:
+        h1, h2 = murmur3.hash128(key)
+        for j in range(bf.k):
+            want |= 1 << (((h1 + j * h2) & (2**64 - 1)) % bf.bits)
+    assert bf.words.tobytes() == want.to_bytes(bf.bits // 8, "little")
+    bf.add_blob(b"", np.zeros(0, dtype=np.int64))      # no key: no change
+    assert bf.words.tobytes() == want.to_bytes(bf.bits // 8, "little")
