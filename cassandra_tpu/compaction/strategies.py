@@ -12,10 +12,7 @@ max local-deletion-time).
 """
 from __future__ import annotations
 
-import time
-
 from ..storage.sstable import SSTableReader
-from ..utils import timeutil
 
 
 class AbstractCompactionStrategy:
@@ -53,36 +50,13 @@ class AbstractCompactionStrategy:
     # ---- helpers
 
     def _fully_expired(self) -> list[SSTableReader]:
-        """SSTables whose every cell is an expired tombstone older than
-        gc grace with no overlap concern (TWCS-style drop;
-        CompactionController.getFullyExpiredSSTables)."""
-        gc_before = timeutil.now_seconds() - \
-            self.cfs.table.params.gc_grace_seconds
-        out = []
-        live = self.cfs.live_sstables()   # overlap guard: ALL live
-        cands = self.candidates()
-        # the purge guard consults the memtable; dropping against a hot
-        # memtable could rewrite the sstable unchanged and re-select it
-        # forever (livelock) — wait for a flush instead
-        if not self.cfs.memtable.is_empty:
-            return out
-        for s in cands:
-            if s.max_ldt is None or s.max_ldt >= gc_before:
-                continue
-            if s.n_tombstones < s.n_cells:
-                continue  # has live data
-            # overlap guard: any other source with older data?
-            others = [o for o in live if o is not s]
-            if any(o.min_ts is not None and s.max_ts is not None
-                   and o.min_ts <= s.max_ts and self._token_overlap(o, s)
-                   for o in others):
-                continue
-            out.append(s)
-        return out
-
-    @staticmethod
-    def _token_overlap(a: SSTableReader, b: SSTableReader) -> bool:
-        return a.min_token() <= b.max_token() and b.min_token() <= a.max_token()
+        """This side's sstables that can be dropped whole: every cell
+        past gc grace, nothing they shadow left behind (TWCS-style
+        drop; the rule is CompactionController.fully_expired, which the
+        drop task re-checks when it runs)."""
+        from .task import CompactionController
+        return CompactionController.fully_expired(self.cfs,
+                                                  self.candidates())
 
 
 class SizeTieredCompactionStrategy(AbstractCompactionStrategy):

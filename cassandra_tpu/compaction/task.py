@@ -170,6 +170,42 @@ class CompactionController:
         return [s for s in self.cfs.live_sstables()
                 if s.desc.generation not in self.compacting_gens]
 
+    @staticmethod
+    def fully_expired(cfs, candidates) -> list[SSTableReader]:
+        """The candidates that can be deleted without a rewrite
+        (CompactionController.getFullyExpiredSSTables): every cell is
+        past gc grace — `max_ldt < gc_before`; a live cell without a
+        TTL carries NO_DELETION_TIME and keeps max_ldt above any
+        gc_before, so an sstable of TTL'd cells that all ran out
+        qualifies whether or not a compaction ever rewrote them as
+        tombstones — and nothing that stays could hold data its cells
+        shadow: no live sstable that may hold live data, and no
+        candidate that itself has to stay, with a cell as old as the
+        candidate's newest inside its token span. Candidates do not
+        block one another. A non-empty memtable blocks every drop: the
+        purge guard consults it, and dropping against a hot memtable
+        could rewrite the sstable unchanged and re-select it forever."""
+        if not cfs.memtable.is_empty:
+            return []
+        gc_before = timeutil.now_seconds() - \
+            cfs.table.params.gc_grace_seconds
+
+        def expired(s) -> bool:
+            return s.max_ldt is not None and s.max_ldt < gc_before \
+                and s.max_ts is not None
+
+        stay = [o for o in cfs.live_sstables() if not expired(o)]
+        out = []
+        for s in sorted((c for c in candidates if expired(c)),
+                        key=lambda c: c.max_ts, reverse=True):
+            if any(o.min_ts is not None and o.min_ts <= s.max_ts
+                   and o.min_token() <= s.max_token()
+                   and s.min_token() <= o.max_token() for o in stay):
+                stay.append(s)
+            else:
+                out.append(s)
+        return out
+
     def purgeable_ts_fn(self, batch: cb.CellBatch) -> np.ndarray:
         n = len(batch)
         out = np.full(n, np.iinfo(np.int64).max, dtype=np.int64)
@@ -218,10 +254,9 @@ def tpu_backend() -> bool:
 
 
 # cell kinds whose rounds the resident program hands to the host
-# (ops/device_write.py submit/collect_merge_resident; ROADMAP B3):
-# range tombstone bounds and counters by construction, TTLs because an
-# expired cell kept inside gc grace needs the host's payload rewrite
-_HOST_ROUND_FLAGS = cb.FLAG_RANGE_BOUND | cb.FLAG_COUNTER | cb.FLAG_EXPIRING
+# (ops/device_write.py submit_merge_resident; ROADMAP B2): range
+# tombstone bounds and counters, by construction
+_HOST_ROUND_FLAGS = cb.FLAG_RANGE_BOUND | cb.FLAG_COUNTER
 
 
 def host_engine() -> str:
@@ -241,9 +276,10 @@ def choose_engine(inputs, backend_probe=None) -> tuple[str, str]:
                 shape nobody compiled (tens of seconds on a TPU) to
                 save milliseconds.
       metadata  the inputs' Statistics (`cell_flags`, the OR of their
-                cells' flags) show range tombstone bounds, counters or
-                TTLs — or do not say: the resident program would send
-                those rounds to the host anyway.
+                cells' flags) show range tombstone bounds or counters
+                — or do not say: the resident program would send those
+                rounds to the host anyway. TTLs stay: the program
+                converts a kept expired cell itself.
       backend   the probe last, and only for what passed the others:
                 `device` on a TPU, else the host engine."""
     host = host_engine()
@@ -253,7 +289,7 @@ def choose_engine(inputs, backend_probe=None) -> tuple[str, str]:
     if any(f is None for f in flags):
         return host, "an input's statistics do not record its cell kinds"
     if any(f & _HOST_ROUND_FLAGS for f in flags):
-        return host, "inputs hold range tombstones, counters or TTLs"
+        return host, "inputs hold range tombstones or counters"
     if not (backend_probe or tpu_backend)():
         return host, "no TPU backend"
     return "device", "TPU backend, resident-encodable inputs"
@@ -302,7 +338,7 @@ class CompactionTask:
         itself, choose_engine(): 'device' when jax's default backend is
         a TPU, the inputs hold at least DEVICE_MIN_CELLS cells and their
         statistics show nothing the resident program sends to the host
-        (range tombstones, counters, TTLs); otherwise 'native' when the
+        (range tombstones, counters); otherwise 'native' when the
         library is available, else 'numpy' (a failed g++ build is
         logged by ops/native/build.py) — which is what None resolved to
         before, and still does on any backend but a TPU.
@@ -408,12 +444,12 @@ class CompactionTask:
         self.round_cells = round_cells or (
             self.ROUND_CELLS_DEVICE if self.engine == "device"
             else self.ROUND_CELLS_HOST)
-        # drop_only: the selecting strategy asserts every input is a
-        # fully-expired tombstone sstable safe to delete without a
-        # rewrite (TWCS expired drop). execute() re-verifies the guard
-        # against the CURRENT live set/memtable and falls back to the
-        # normal merge (which purges correctly) if anything changed
-        # between selection and execution.
+        # drop_only: the selecting strategy asserts every input is
+        # fully expired and safe to delete without a rewrite (TWCS
+        # expired drop, CompactionController.fully_expired). execute()
+        # re-verifies the guard against the CURRENT live set/memtable
+        # and falls back to the normal merge (which purges correctly)
+        # if anything changed between selection and execution.
         self.drop_only = bool(drop_only)
         # per-phase wall seconds, accumulated across rounds (published by
         # bench.py -- the breakdown the perf work navigates by)
@@ -767,30 +803,11 @@ class CompactionTask:
     def _drop_safe(self) -> bool:
         """Re-verify the fully-expired drop guard at EXECUTE time (the
         selecting strategy checked at selection; a flush or an
-        out-of-order write may have landed since): every input all
-        expired tombstones past gc grace, a quiet memtable, and no
-        other live sstable holding data as old as the input's newest
-        cell within its token span (dropping the tombstones must not
-        resurrect anything they shadow)."""
-        cfs = self.cfs
-        gc_before = timeutil.now_seconds() - \
-            cfs.table.params.gc_grace_seconds
-        if not cfs.memtable.is_empty:
-            return False
-        in_ids = {id(r) for r in self.inputs}
-        others = [o for o in cfs.live_sstables() if id(o) not in in_ids]
-        for s in self.inputs:
-            if s.max_ldt is None or s.max_ldt >= gc_before:
-                return False
-            if s.n_tombstones < s.n_cells:
-                return False
-            if any(o.min_ts is not None and s.max_ts is not None
-                   and o.min_ts <= s.max_ts
-                   and o.min_token() <= s.max_token()
-                   and s.min_token() <= o.max_token()
-                   for o in others):
-                return False
-        return True
+        out-of-order write may have landed since): the rule the
+        strategy selected by, against the CURRENT live set and memtable
+        (dropping must not resurrect anything the inputs shadow)."""
+        safe = CompactionController.fully_expired(self.cfs, self.inputs)
+        return len(safe) == len(self.inputs)
 
     def _execute_drop(self) -> dict:
         """Rewrite-free expired drop: obsolete the inputs in one
@@ -800,15 +817,19 @@ class CompactionTask:
         cfs = self.cfs
         t0 = time.time()
         cells_read = sum(r.n_cells for r in self.inputs)
-        txn = LifecycleTransaction(cfs.directory)
-        for r in self.inputs:
-            txn.track_obsolete(r.desc.generation)
-        txn.commit()
-        cfs.tracker.replace(self.inputs, [])
-        if cfs.row_cache is not None:
-            cfs.row_cache.clear()
-        for r in self.inputs:
-            r.release()
+        with pipeline_ledger.span(
+                "compaction.drop", items=len(self.inputs),
+                cells=cells_read,
+                nbytes=sum(r.data_size for r in self.inputs)):
+            txn = LifecycleTransaction(cfs.directory)
+            for r in self.inputs:
+                txn.track_obsolete(r.desc.generation)
+            txn.commit()
+            cfs.tracker.replace(self.inputs, [])
+            if cfs.row_cache is not None:
+                cfs.row_cache.clear()
+            for r in self.inputs:
+                r.release()
         stats = {
             "inputs": len(self.inputs), "outputs": 0,
             "bytes_read": 0, "bytes_written": 0,

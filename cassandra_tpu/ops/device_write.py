@@ -32,17 +32,21 @@ ROUND (each counted, `compaction.device_resident_fallback`) —
 
   * equal-(identity, ts) duplicate runs (the device sort does not order
     the Cells.resolveRegular tie-break lanes; the host resolves them
-    with full values) and kept expired-TTL cells (tombstone conversion
-    rewrites flags AND drops the value bytes — a payload rewrite):
-    `materialize_round` on the program's own outputs,
+    with full values): `materialize_round` on the program's own outputs,
   * counter cells / range-tombstone bounds (host-only reconcile) and
     frames past the u32 offset lanes, which the program cannot encode:
     the numpy spec, `cellbatch.merge_sorted`, merges the round
     (`_host_round`; also counted `compaction.device_host_rounds`),
 
 and `scripts/check_compaction_ab.py`'s device legs pin the whole-file
-sha256 equality. Scalar counts of the first two conditions are computed
-in the same fused program, so the decision costs three tiny transfers.
+sha256 equality. The scalar count of the first condition is computed in
+the same fused program, so the decision costs one tiny transfer.
+
+Kept expired-TTL cells stay resident: the tombstone conversion is column
+arithmetic (the value is the tail of a cell's frame, so `flags8 |=
+FLAG_TOMBSTONE` and frame length = header length drop it), done in the
+program; the host's payload gather then copies the shortened frames
+(counted, `compaction.device_expired_converted`).
 """
 from __future__ import annotations
 
@@ -53,7 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
-                                 FLAG_RANGE_BOUND, CellBatch, merge_sorted)
+                                 FLAG_RANGE_BOUND, FLAG_TOMBSTONE, CellBatch,
+                                 merge_sorted)
 from ..service.metrics import GLOBAL as _METRICS
 from ..service.profiling import GLOBAL as _kprof
 from ..utils import pipeline_ledger
@@ -117,11 +122,11 @@ RESIDENT_COLS = ("lanes", "ts_h", "ts_l", "ldt", "ttl", "flags8",
 
 @jax.jit
 def _resident_program(operands):
-    """One dispatch: LSD sort, reconcile+purge, kept-cell compaction and
-    column gather — the merged round stays on the device, in output
-    order, kept cells first. Returns (n_keep, n_amb, n_exp_kept,
-    perm_out, cols, perm, packed); the last two feed the host fallback
-    when the scalar counts demand it."""
+    """One dispatch: LSD sort, reconcile+purge, kept-cell compaction,
+    column gather and the expired -> tombstone conversion — the merged
+    round stays on the device, in output order, kept cells first.
+    Returns (n_keep, n_amb, n_exp_kept, perm_out, cols, perm, packed);
+    the last two feed the host fallback when n_amb demands it."""
     # named_scope: metadata only (op names in a profiler trace), the
     # program and its bytes are unchanged
     with jax.named_scope("sort"):
@@ -144,6 +149,16 @@ def _resident_program(operands):
              jnp.arange(N, dtype=jnp.int32)), num_keys=1, is_stable=True)
         perm_out = perm[ord_]
         cols = {k: operands[k][perm_out] for k in RESIDENT_COLS}
+    with jax.named_scope("convert"):
+        # an expired cell that is kept becomes a tombstone without its
+        # value (finalize_merged's flags |= FLAG_TOMBSTONE + drop_values):
+        # the value is the frame's tail, so the frame shrinks to its
+        # header; ldt and ttl stay
+        exp_out = expired[ord_]
+        cols["flags8"] = jnp.where(
+            exp_out, cols["flags8"] | jnp.uint8(FLAG_TOMBSTONE),
+            cols["flags8"])
+        cols["fl"] = jnp.where(exp_out, cols["vr"], cols["fl"])
     return n_keep, n_amb, n_exp_kept, perm_out, cols, perm, packed
 
 
@@ -363,20 +378,26 @@ def collect_merge_resident(h: ResidentHandle):
         n_exp_kept = int(n_exp_d)
     _kprof.record_execute("merge.resident", sp.seconds)
 
+    # cells = the cells the round kept, items = the cells it read
     with _LED_RESIDENT.busy("merge.resident.gather", prof=prof,
-                            key="gather", cells=n_keep):
-        if n_amb or n_exp_kept:
+                            key="gather", cells=n_keep, items=h.n):
+        if n_amb:
             # exact-resolution round: equal-(identity, ts) runs need the
-            # host's full-value tie-break, kept expired cells need the
-            # tombstone conversion's payload rewrite
-            _resident_fallback(
-                h.n, "equal-(identity, ts) ties or kept expired-TTL cells")
+            # host's full-value tie-break
+            _resident_fallback(h.n, "equal-(identity, ts) ties")
             return promote_round(_materialize(h))
 
         # resident round: pull ONLY the kept permutation (the payload
         # gather's index vector) — the columns stay on the device
         perm_kept = np.asarray(perm_out_d).astype(np.int64)[:n_keep]
-        payload, off, val_start = _gather_payload(cat, perm_kept)
+        lens = None
+        if n_exp_kept:
+            # the program converted kept expired cells: their frames are
+            # the headers now, and the converted length column says so
+            _METRICS.incr("compaction.device_expired_converted",
+                          n_exp_kept)
+            lens = np.asarray(cols["fl"]).astype(np.int64)[:n_keep]
+        payload, off, val_start = _gather_payload(cat, perm_kept, lens)
         return DeviceRound(n_keep, cols, payload, off, val_start,
                            dict(cat.pk_map), cat.ck_fits_prefix)
 
@@ -425,10 +446,10 @@ def merge_sorted_device(batches: list[CellBatch], gc_before: int = 0,
 
 
 def promote_round(batch: CellBatch) -> DeviceRound:
-    """Lift a host-materialized round (fallback rounds: ties, expired
-    conversions, counters, range bounds) onto the device so the write
-    lane consumes ONE ordered stream — interleaving host appends with
-    device-pending cells would cut segments out of order. Values are
+    """Lift a host-materialized round (fallback rounds: ties, counters,
+    range bounds) onto the device so the write lane consumes ONE
+    ordered stream — interleaving host appends with device-pending
+    cells would cut segments out of order. Values are
     copied verbatim, so the serialized bytes are identical to feeding
     the batch through the host writer."""
     n = len(batch)
@@ -459,14 +480,19 @@ def promote_round(batch: CellBatch) -> DeviceRound:
                        dict(batch.pk_map), batch.ck_fits_prefix)
 
 
-def _gather_payload(cat: CellBatch, perm: np.ndarray):
+def _gather_payload(cat: CellBatch, perm: np.ndarray,
+                    lens: np.ndarray | None = None):
     """Host-side ragged payload gather (the one part of the round that
     never went to the device) — same native path apply_permutation
-    uses, without touching the fixed-width columns."""
+    uses, without touching the fixed-width columns. lens: the bytes to
+    copy from the head of each gathered frame where that is not the
+    whole frame (cells the program converted to tombstones keep their
+    header and lose their value)."""
     from ..storage.cellbatch import _native_gather
     n = len(perm)
     starts = cat.off[:-1][perm]
-    lens = (cat.off[1:] - cat.off[:-1])[perm]
+    if lens is None:
+        lens = (cat.off[1:] - cat.off[:-1])[perm]
     new_off = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(lens, out=new_off[1:])
     total = int(new_off[-1])

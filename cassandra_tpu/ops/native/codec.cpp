@@ -858,18 +858,22 @@ int64_t part_boundaries(const uint32_t* lanes, int64_t nrows,
 }
 
 // ------------------------------------------------------------ gather -----
-// Permuted ragged-frame gather: out[new_off[i] .. new_off[i+1]) =
-// payload[off[perm[i]] .. off[perm[i]+1]). The CellBatch payload shuffle is
-// the host-side hot loop of compaction (numpy's fancy indexing builds a
-// per-byte index array; this is a straight memcpy per frame).
+// Permuted ragged-frame gather: out[new_off[i] .. new_off[i+1]) = the
+// first new_off[i+1]-new_off[i] bytes of frame perm[i]. The CellBatch
+// payload shuffle is the host-side hot loop of compaction (numpy's fancy
+// indexing builds a per-byte index array; this is a straight memcpy per
+// frame).
 
 int64_t gather_frames(const uint8_t* payload, const int64_t* off,
                       const int64_t* perm, int64_t n,
                       const int64_t* new_off, uint8_t* out) {
     for (int64_t i = 0; i < n; i++) {
         int64_t j = perm[i];
-        int64_t len = off[j + 1] - off[j];
-        if (len != new_off[i + 1] - new_off[i]) return -1;
+        // new_off gives the bytes to take from the HEAD of frame j: the
+        // whole frame, or less (a cell that lost its value keeps its
+        // header); never more
+        int64_t len = new_off[i + 1] - new_off[i];
+        if (len < 0 || len > off[j + 1] - off[j]) return -1;
         memcpy(out + new_off[i], payload + off[j], len);
     }
     return 0;

@@ -59,14 +59,14 @@ def test_the_floor_is_one_full_device_round():
 
 
 @pytest.mark.parametrize("flag", [cb.FLAG_RANGE_BOUND, cb.FLAG_COUNTER,
-                                  cb.FLAG_EXPIRING])
+                                  cb.FLAG_RANGE_BOUND | cb.FLAG_EXPIRING])
 def test_host_engine_for_what_the_resident_program_cannot_encode(flag):
     calls = []
     n = CompactionTask.DEVICE_MIN_CELLS
     inputs = [FakeInput(n), FakeInput(n, cb.FLAG_TOMBSTONE | flag)]
     engine, why = choose_engine(inputs, _probe(True, calls))
     assert engine == HOST and calls == []
-    assert "range tombstones, counters or TTLs" in why
+    assert "range tombstones or counters" in why
 
 
 def test_plain_tombstones_and_row_markers_stay_on_the_device():
@@ -74,6 +74,19 @@ def test_plain_tombstones_and_row_markers_stay_on_the_device():
     flags = cb.FLAG_TOMBSTONE | cb.FLAG_ROW_DEL | cb.FLAG_PARTITION_DEL \
         | cb.FLAG_ROW_LIVENESS | cb.FLAG_COMPLEX_DEL
     assert choose_engine([FakeInput(n, flags)], lambda: True)[0] == "device"
+
+
+@pytest.mark.parametrize("flags", [
+    cb.FLAG_EXPIRING, cb.FLAG_EXPIRING | cb.FLAG_ROW_LIVENESS,
+    cb.FLAG_EXPIRING | cb.FLAG_TOMBSTONE | cb.FLAG_ROW_DEL])
+def test_ttls_stay_on_the_device(flags):
+    """The resident program converts a kept expired cell itself (PR 31):
+    a table with default_time_to_live reaches the device."""
+    calls = []
+    n = CompactionTask.DEVICE_MIN_CELLS
+    engine, why = choose_engine([FakeInput(n), FakeInput(n, flags)],
+                                _probe(True, calls))
+    assert engine == "device" and calls == [True] and "TPU" in why
 
 
 def test_host_engine_when_an_input_does_not_say_what_it_holds():
@@ -138,7 +151,7 @@ PLAIN = ["INSERT INTO {t} (k, c, v) VALUES ({{i}}, 1, 'a')",
 KINDS = {
     "plain": (PLAIN, 0, "device"),
     "ttl": (PLAIN + ["INSERT INTO {t} (k, c, v) VALUES ({{i}}, 3, 'x') "
-                     "USING TTL 100000"], cb.FLAG_EXPIRING, HOST),
+                     "USING TTL 100000"], cb.FLAG_EXPIRING, "device"),
     "range_tombstone": (PLAIN + ["DELETE FROM {t} WHERE k = {{i}} "
                                  "AND c > 5 AND c < 9"],
                         cb.FLAG_RANGE_BOUND, HOST),

@@ -184,6 +184,109 @@ def test_twcs_fully_expired_drop(tmp_path):
     eng.close()
 
 
+def put_ttl(eng, t, p, c, ts, ldt, ttl=3600, converted=False):
+    """A TTL'd row as an INSERT leaves it (row liveness + value, both
+    expiring at `ldt`), or as a merge past its expiry rewrote it."""
+    from cassandra_tpu.storage.cellbatch import (FLAG_EXPIRING,
+                                                 FLAG_ROW_LIVENESS,
+                                                 FLAG_TOMBSTONE)
+    dead = FLAG_TOMBSTONE if converted else 0
+    m = Mutation(t.id, t.columns["id"].cql_type.serialize(p))
+    ck = t.serialize_clustering([c])
+    m.add(ck, COL_ROW_LIVENESS, b"", b"", ts, ldt=ldt, ttl=ttl,
+          flags=FLAG_ROW_LIVENESS | FLAG_EXPIRING | dead)
+    m.add(ck, t.columns["v"].column_id, b"",
+          b"" if converted else t.columns["v"].cql_type.serialize("x"),
+          ts, ldt=ldt, ttl=ttl, flags=FLAG_EXPIRING | dead)
+    eng.apply(m)
+
+
+TWCS_HOURLY = {"class": "TimeWindowCompactionStrategy",
+               "compaction_window_unit": "HOURS",
+               "compaction_window_size": 1}
+
+
+@pytest.mark.parametrize("converted", [False, True],
+                         ids=["never_rewritten", "rewritten_as_tombstones"])
+def test_twcs_drops_a_window_of_ttl_cells_past_gc_grace_whole(
+        tmp_path, converted):
+    """Upstream's rule (CompactionController.getFullyExpiredSSTables):
+    max local deletion time before gc_before. Whether a compaction ever
+    rewrote the expired cells as tombstones does not matter: the sstable
+    goes without being decoded."""
+    eng, t, cfs = new_engine(tmp_path, compaction=TWCS_HOURLY)
+    now = int(time.time())
+    for p in range(5):
+        put_ttl(eng, t, p, 0, ts=1_000_000 + p, ldt=now - 20 * 86400,
+                converted=converted)
+    cfs.flush()
+    for p in range(100, 105):
+        put_ttl(eng, t, p, 0, ts=9_000_000_000 + p, ldt=now + 86400)
+    cfs.flush()
+    old = min(cfs.live_sstables(), key=lambda s: s.max_ts)
+    assert old.n_tombstones == (old.n_cells if converted else 0)
+    strat = get_strategy(cfs)
+    assert strat._fully_expired() == [old]
+    task = strat.next_background_task()
+    assert task.drop_only and list(task.inputs) == [old]
+    scanned = []
+    old.scanner = lambda *a, **kw: scanned.append(1)
+    stats = task.execute()
+    assert stats["dropped"] and stats["outputs"] == 0 and not scanned
+    assert [s.max_ts for s in cfs.live_sstables()] == [9_000_000_104]
+    eng.close()
+
+
+@pytest.mark.parametrize("survivor", ["a_live_cell_without_ttl",
+                                      "a_ttl_cell_inside_gc_grace"])
+def test_one_cell_that_is_not_past_grace_keeps_the_sstable(tmp_path,
+                                                           survivor):
+    """A live cell without TTL carries NO_DELETION_TIME, so max_ldt says
+    it is there; so does a TTL'd cell that ran out inside gc grace."""
+    eng, t, cfs = new_engine(tmp_path, compaction=TWCS_HOURLY)
+    now = int(time.time())
+    for p in range(5):
+        put_ttl(eng, t, p, 0, ts=1_000_000 + p, ldt=now - 20 * 86400)
+    if survivor == "a_live_cell_without_ttl":
+        put(eng, t, 7, 0, "forever", ts=1_000_007)
+    else:
+        put_ttl(eng, t, 7, 0, ts=1_000_007, ldt=now - 86400)
+    cfs.flush()
+    strat = get_strategy(cfs)
+    assert strat._fully_expired() == []
+    assert strat.next_background_task() is None
+    eng.close()
+
+
+def test_expired_sstables_do_not_block_one_another(tmp_path):
+    """Two expired sstables of one window overlap in tokens and in time:
+    neither holds anything the other's cells shadow that would stay, so
+    both go in one drop (upstream lets expired candidates not block one
+    another). An sstable that stays, with older data in their span,
+    blocks both."""
+    eng, t, cfs = new_engine(tmp_path, compaction=TWCS_HOURLY)
+    now = int(time.time())
+    for half in range(2):
+        for p in range(20):
+            put_ttl(eng, t, p, half, ts=1_000_000 + 2 * p + half,
+                    ldt=now - 20 * 86400)
+        cfs.flush()
+    a, b = cfs.live_sstables()
+    assert a.min_ts <= b.max_ts and b.min_ts <= a.max_ts
+    strat = get_strategy(cfs)
+    assert sorted(strat._fully_expired(), key=id) == sorted([a, b], key=id)
+    task = strat.next_background_task()
+    assert task.drop_only and len(task.inputs) == 2
+    # older live data of the same partitions lands before the task runs:
+    # the re-check refuses the drop and the task merges instead
+    for p in range(20):
+        put(eng, t, p, 5, "old and live", ts=500 + p)
+    cfs.flush()
+    assert strat._fully_expired() == []
+    assert not task._drop_safe()
+    eng.close()
+
+
 def _component_hashes(cfs, gens):
     """{(generation, component): sha256} for the given generations —
     the check_compaction_ab.py byte-identity contract."""
