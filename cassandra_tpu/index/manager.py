@@ -16,6 +16,7 @@ latency; the MXU removes the tradeoff at this scale).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 
 import numpy as np
@@ -228,6 +229,13 @@ class VectorIndex(_AttachedIndex):
     def __init__(self, backend, table: TableMetadata, column: str):
         super().__init__(backend, table, column)
         self.dim = table.columns[column].cql_type.dimension
+        # the ONE resident entry, ((version, similarity), device matrix,
+        # keys), swapped in as one tuple: a query in flight keeps the
+        # matrix AND the key list it started with
+        self._resident = None
+        # single-flight fills. Not self._lock: a fill's _gather() ->
+        # _component() takes that one, and it is not re-entrant
+        self._fill_lock = threading.Lock()
 
     def _build(self, reader):
         ssi.build_vector(reader, self.table, self.col_id, self.dim)
@@ -247,19 +255,31 @@ class VectorIndex(_AttachedIndex):
             else np.zeros((0, self.dim), np.float32)
         return mat, np.asarray(tss, dtype=np.int64), keys
 
+    def _version(self) -> tuple:
+        """What the matrix was assembled from: the live sstables'
+        generations, the memtable's identity and its op count."""
+        cfs = self._cfs()
+        mem = cfs.memtable
+        return (tuple(sorted(r.desc.generation
+                             for r in cfs.live_sstables())),
+                id(mem), mem.ops)
+
+    def _current(self, similarity: str) -> tuple:
+        """(the resident entry if it is of this version of the table,
+        else None; the key this version's entry carries)."""
+        key = (self._version(), similarity)
+        entry = self._resident
+        if entry is not None and entry[0] != key:
+            entry = None
+        return entry, key
+
     def _gather(self):
         """(matrix, keys): memtable vectors + every live sstable's
         persisted matrix, newest-first so duplicate locators keep the
-        freshest embedding. Cached until the live set or memtable
-        changes (repeat ANN queries pay one matmul, not re-assembly)."""
-        cfs = self._cfs()
-        mem = cfs.memtable
-        ver = (tuple(sorted(r.desc.generation
-                            for r in cfs.live_sstables())),
-               id(mem), mem.ops)
-        cached = getattr(self, "_gather_cache", None)
-        if cached is not None and cached[0] == ver:
-            return cached[1]
+        freshest embedding. Assembled once per version of the table, by
+        the fill of the resident entry; nothing here is kept (the
+        stacked matrix would be a second host copy beside the
+        per-sstable components)."""
         # newest CELL TIMESTAMP wins per (pk, ck): generation order is
         # not write order (USING TIMESTAMP), and a stale embedding must
         # not rank the row
@@ -284,35 +304,67 @@ class VectorIndex(_AttachedIndex):
                 if k not in best or rank > best[k][0]:
                     best[k] = (rank, mat[i])
         if not best:
-            result = (np.zeros((0, self.dim), np.float32), [])
-        else:
-            keys = list(best)
-            result = (np.stack([best[k][1] for k in keys]), keys)
-        self._gather_cache = (ver, result)
-        return result
+            return np.zeros((0, self.dim), np.float32), []
+        keys = list(best)
+        return np.stack([best[k][1] for k in keys]), keys
 
     def ann(self, query: np.ndarray, k: int,
             similarity: str = "cosine") -> list:
         """Top-k (pk, ck, score). One matmul + top_k on the device — the
-        MXU path (index/sai vector search role)."""
-        with _LED_ANN.busy("index.ann.gather") as sp:
-            m, keys = self._gather()
-            sp.cells = len(m)
-        if len(m) == 0:
-            return []
+        MXU path (index/sai vector search role) — against the matrix
+        that is resident there: prepared (assembled, for cosine
+        normalised, uploaded) once per version of the table, by the
+        first query that finds the entry stale, while the others that
+        find it stale wait for that one fill. A table under live writes
+        changes version per write and fills per query (ROADMAP B4)."""
+        from ..service.metrics import GLOBAL as _M
         q = np.asarray(query, dtype=np.float32)
-        if similarity == "cosine":
-            with _LED_ANN.busy("index.ann.normalise", cells=len(m),
-                               nbytes=m.nbytes):
-                m = m / np.maximum(
-                    np.linalg.norm(m, axis=1, keepdims=True), 1e-9)
-                q = q / max(float(np.linalg.norm(q)), 1e-9)
-        # the call uploads the matrix and dispatches; the pull blocks on
-        # the device's answer (no upload span of its own: splitting it
-        # out would take a sync the program does not have)
-        with _LED_ANN.busy("index.ann.call", cells=len(m),
-                           nbytes=m.nbytes + q.nbytes):
-            vals, idx = ann_program()(m, q, k=min(k, len(m)),
+        with _LED_ANN.busy("index.ann.resident") as res, \
+                contextlib.ExitStack() as fill:
+            with _LED_ANN.busy("index.ann.gather") as sp:
+                entry, key = self._current(similarity)
+                if entry is None:
+                    # held until the new entry is swapped in; whoever
+                    # waited here finds the entry the holder filled
+                    fill.enter_context(self._fill_lock)
+                    entry, key = self._current(similarity)
+                filling = entry is None
+                # `key` was read before the rows: an entry is never
+                # labelled newer than what it holds
+                m, keys = self._gather() if filling else (None, entry[2])
+                sp.cells = len(keys)
+            if not keys:
+                self._resident = None   # an emptied table holds nothing
+                return []
+            if similarity == "cosine":
+                with _LED_ANN.busy("index.ann.normalise") as sp:
+                    if filling:
+                        # on the host, the expression every query used to
+                        # run: the device holds the bytes it scored
+                        m = m / np.maximum(
+                            np.linalg.norm(m, axis=1, keepdims=True), 1e-9)
+                        sp.cells, sp.nbytes = len(m), m.nbytes
+                    q = q / max(float(np.linalg.norm(q)), 1e-9)
+                    sp.nbytes += q.nbytes
+            if filling:
+                import jax
+                with _LED_ANN.busy("index.ann.upload", cells=len(m),
+                                   nbytes=m.nbytes):
+                    dev = jax.device_put(m)
+                    dev.block_until_ready()
+                # the superseded device array goes with the old tuple
+                entry = self._resident = (key, dev, keys)
+                res.nbytes = m.nbytes
+                _M.incr("index.ann.resident_fills")
+            else:
+                res.items = 1
+                _M.incr("index.ann.resident_hits")
+        _, dev, keys = entry
+        # the call pushes the query vector and dispatches; the pull
+        # blocks on the device's answer
+        with _LED_ANN.busy("index.ann.call", cells=len(keys),
+                           nbytes=q.nbytes):
+            vals, idx = ann_program()(dev, q, k=min(k, len(keys)),
                                       similarity=similarity)
         with _LED_ANN.stall("index.ann.pull"):
             vals, idx = np.asarray(vals), np.asarray(idx)
