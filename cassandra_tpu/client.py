@@ -28,10 +28,66 @@ class DriverError(Exception):
     pass
 
 
+class Unavailable(DriverError):
+    """UNAVAILABLE (0x1000): the coordinator knew too few replicas alive
+    for the level; nothing was attempted."""
+
+    def __init__(self, msg, consistency, required, alive):
+        super().__init__(msg)
+        self.consistency = consistency
+        self.required, self.alive = required, alive
+
+
+class RequestTimeout(DriverError):
+    """What WRITE_TIMEOUT and READ_TIMEOUT share: the level, the
+    responses received and those it blocks for."""
+
+    def __init__(self, msg, consistency, received, block_for):
+        super().__init__(msg)
+        self.consistency = consistency
+        self.received, self.block_for = received, block_for
+
+
+class WriteTimeout(RequestTimeout):
+    """WRITE_TIMEOUT (0x1100); the write may or may not be applied."""
+
+    def __init__(self, msg, consistency, received, block_for, write_type):
+        super().__init__(msg, consistency, received, block_for)
+        self.write_type = write_type
+
+
+class ReadTimeout(RequestTimeout):
+    """READ_TIMEOUT (0x1200)."""
+
+    def __init__(self, msg, consistency, received, block_for,
+                 data_present):
+        super().__init__(msg, consistency, received, block_for)
+        self.data_present = data_present
+
+
+def _error(body: bytes) -> DriverError:
+    """The typed error of an ERROR body, with the protocol's fields."""
+    (code,) = struct.unpack_from(">i", body, 0)
+    msg, pos = ts._read_string(body, 4)
+    text = f"[{code:#06x}] {msg}"
+    if code in (ts.ERR_UNAVAILABLE, ts.ERR_WRITE_TIMEOUT,
+                ts.ERR_READ_TIMEOUT):
+        cl, a, b = struct.unpack_from(">Hii", body, pos)
+        level = ts.CONSISTENCY_LEVELS.get(cl, cl)
+        pos += 10
+        if code == ts.ERR_UNAVAILABLE:
+            return Unavailable(text, level, a, b)
+        if code == ts.ERR_WRITE_TIMEOUT:
+            return WriteTimeout(text, level, a, b,
+                                ts._read_string(body, pos)[0])
+        return ReadTimeout(text, level, a, b, body[pos] != 0)
+    return DriverError(text)
+
+
 # consistency-level names -> wire codes, shared with the server side
-# (transport/frame.py is the single source of truth). The server tags
-# the per-CL client_requests hists off the declared level; coordination
-# CL policy is the backend's (cluster Node default_cl) for now.
+# (transport/frame.py is the single source of truth). The server
+# coordinates every request at the level it declares: `consistency=` on
+# execute / execute_prepared is what reaches the coordinator.
 CONSISTENCY_CODES = ts.CONSISTENCY_CODES
 
 
@@ -283,9 +339,7 @@ class ClientSession:
 
     def _decode_result(self, op: int, body: bytes) -> Rows:
         if op == ts.OP_ERROR:
-            (code,) = struct.unpack_from(">i", body, 0)
-            msg, _ = ts._read_string(body, 4)
-            raise DriverError(f"[{code:#06x}] {msg}")
+            raise _error(body)
         if op != ts.OP_RESULT:
             raise DriverError(f"unexpected opcode {op}")
         (kind,) = struct.unpack_from(">i", body, 0)
@@ -335,9 +389,7 @@ class ClientSession:
             req += struct.pack(">I", 0)    # v5 prepare flags
         op, body = self._request(ts.OP_PREPARE, req)
         if op == ts.OP_ERROR:
-            (code,) = struct.unpack_from(">i", body, 0)
-            msg, _ = ts._read_string(body, 4)
-            raise DriverError(f"[{code:#06x}] {msg}")
+            raise _error(body)
         (kind,) = struct.unpack_from(">i", body, 0)
         if kind != ts.RESULT_PREPARED:
             raise DriverError(f"unexpected result kind {kind}")

@@ -16,6 +16,8 @@ from ..service import tracing
 from ..service.metrics import GLOBAL as METRICS
 from ..storage import cellbatch as cb
 from ..storage.mutation import Mutation
+from ..transport.frame import CONSISTENCY_CODES
+from ..utils import pipeline_ledger
 from .messaging import MessagingService, Verb
 from .replication import ConsistencyLevel, ReplicationStrategy
 from .ring import Endpoint, Ring
@@ -26,11 +28,53 @@ REQUEST = METRICS.group("request")
 
 
 class UnavailableException(Exception):
-    """Not enough live replicas to even attempt the operation."""
+    """Not enough live replicas to even attempt the operation. Carries
+    what the native protocol's UNAVAILABLE error carries: the level
+    asked for, the replicas it needs alive and those known alive."""
+
+    def __init__(self, msg: str, cl: str | None = None, required: int = 0,
+                 alive: int = 0):
+        super().__init__(msg)
+        self.cl, self.required, self.alive = cl, required, alive
 
 
 class TimeoutException(Exception):
-    """Live replicas did not ack within the timeout."""
+    """Live replicas did not ack within the timeout: the level, the
+    responses received and those the level blocks for (the protocol's
+    READ_TIMEOUT / WRITE_TIMEOUT fields)."""
+
+    data_present = False        # READ_TIMEOUT's last field
+
+    def __init__(self, msg: str, cl: str | None = None, received: int = 0,
+                 block_for: int = 0):
+        super().__init__(msg)
+        self.cl, self.received, self.block_for = cl, received, block_for
+
+
+class WriteTimeoutException(TimeoutException):
+    write_type = "SIMPLE"
+
+
+class ReadTimeoutException(TimeoutException):
+    def __init__(self, msg: str, cl: str | None = None, received: int = 0,
+                 block_for: int = 0, data_present: bool = False):
+        super().__init__(msg, cl, received, block_for)
+        self.data_present = data_present
+
+
+# metric names of the per-level request counters, built once per
+# (verb, level) like messaging's per-verb names
+_REQUEST_COUNTERS: dict = {}
+
+
+def _count_request(verb: str, cl: str) -> None:
+    """`coordinator.requests.<read|write>.<level>`: requests by the
+    level the proxy was actually called with."""
+    name = _REQUEST_COUNTERS.get((verb, cl))
+    if name is None:
+        name = _REQUEST_COUNTERS[verb, cl] = \
+            f"coordinator.requests.{verb}.{cl.lower()}"
+    METRICS.incr(name)
 
 
 class _Await:
@@ -192,15 +236,25 @@ class StorageProxy:
 
     def mutate(self, keyspace: str, mutation: Mutation,
                cl: str = ConsistencyLevel.ONE) -> None:
-        with REQUEST.timer("write"):
-            self._mutate(keyspace, mutation, cl)
+        _count_request("write", cl)
+        # one span per request: items = replicas addressed, cells =
+        # block_for, bytes = the level's protocol code
+        with REQUEST.timer("write"), pipeline_ledger.span(
+                "coordinator.write",
+                nbytes=CONSISTENCY_CODES.get(cl, 0)) as sp:
+            self._mutate(keyspace, mutation, cl, sp)
 
-    def _mutate(self, keyspace: str, mutation: Mutation,
-                cl: str = ConsistencyLevel.ONE) -> None:
+    def _store_hint(self, target, mutation) -> None:
+        METRICS.incr("writes.hints_stored")
+        self.node.hints.store(target, mutation)
+
+    def _mutate(self, keyspace: str, mutation: Mutation, cl: str,
+                sp) -> None:
         replicas, strat, token = self._plan(keyspace, mutation.pk)
         block_for = ConsistencyLevel.block_for(cl, strat,
                                                self.node.endpoint.dc)
         live, dead = self._split_live(replicas)
+        sp.cells, sp.items = block_for, len(live)
         local_dc = self.node.endpoint.dc
         countable = [r for r in live
                      if self._counts_toward(cl, r, local_dc)]
@@ -209,16 +263,18 @@ class StorageProxy:
         elif len(countable) < block_for:
             raise UnavailableException(
                 f"{cl} requires {block_for} replicas, "
-                f"{len(countable)} countable alive")
+                f"{len(countable)} countable alive",
+                cl, block_for, len(countable))
         elif cl == ConsistencyLevel.EACH_QUORUM:
             bad = ConsistencyLevel.each_quorum_unavailable_dcs(strat, live)
             if bad:
                 raise UnavailableException(
-                    f"EACH_QUORUM: quorum unreachable in {bad}")
+                    f"EACH_QUORUM: quorum unreachable in {bad}",
+                    cl, block_for, len(countable))
         handler = _Await(block_for)
         for target in dead:
             if self.node.should_hint(target):
-                self.node.hints.store(target, mutation)
+                self._store_hint(target, mutation)
                 if cl == ConsistencyLevel.ANY:
                     handler.ack()
         for target in live:
@@ -247,21 +303,24 @@ class StorageProxy:
                 except Exception:
                     # same contract as a failed remote send: hint so the
                     # join converges (the hint loop replays self-hints)
-                    self.node.hints.store(target, mutation)
+                    self._store_hint(target, mutation)
             else:
                 self.messaging.send_with_callback(
                     Verb.MUTATION_REQ, mutation.serialize(), target,
                     on_response=lambda m: None,
                     on_failure=lambda mid, t=target:
-                        self.node.hints.store(t, mutation),
+                        self._store_hint(t, mutation),
                     timeout=self.write_timeout)
-        if not handler.await_(self.write_timeout):
-            raise TimeoutException(
-                f"{len(handler.responses)}/{block_for} acks for {cl}")
+        with pipeline_ledger.span("coordinator.write.await", "stall"):
+            done = handler.await_(self.write_timeout)
+        if not done:
+            raise WriteTimeoutException(
+                f"{len(handler.responses)}/{block_for} acks for {cl}",
+                cl, len(handler.responses), block_for)
 
     def _write_timeout(self, handler, target, mutation):
         handler.fail()
-        self.node.hints.store(target, mutation)
+        self._store_hint(target, mutation)
 
     # --------------------------------------------------------------- read
 
@@ -288,12 +347,17 @@ class StorageProxy:
         limits until the merged live-row count reaches the target or no
         replica was truncated
         (service/reads/ShortReadPartitionsProtection.java:40)."""
-        with REQUEST.timer("read"):
+        _count_request("read", cl)
+        # one span per request: items = replicas addressed (the blockFor
+        # set), cells = block_for, bytes = the level's protocol code
+        with REQUEST.timer("read"), pipeline_ledger.span(
+                "coordinator.read",
+                nbytes=CONSISTENCY_CODES.get(cl, 0)) as sp:
             return self._read_partition(keyspace, table_name, pk, cl,
-                                        limits)
+                                        limits, sp)
 
-    def _read_partition(self, keyspace, table_name, pk, cl,
-                        limits=None) -> cb.CellBatch:
+    def _read_partition(self, keyspace, table_name, pk, cl, limits,
+                        sp) -> cb.CellBatch:
         if cl == ConsistencyLevel.EACH_QUORUM:
             raise ValueError(
                 "EACH_QUORUM ConsistencyLevel is only supported for writes")
@@ -304,10 +368,12 @@ class StorageProxy:
         local_dc = self.node.endpoint.dc
         countable = [r for r in live
                      if self._counts_toward(cl, r, local_dc)]
+        sp.cells, sp.items = block_for, min(block_for, len(countable))
         if len(countable) < block_for:
             raise UnavailableException(
                 f"{cl} requires {block_for} replicas, "
-                f"{len(countable)} countable alive")
+                f"{len(countable)} countable alive",
+                cl, block_for, len(countable))
         # replica ordering: self first, then fastest by EWMA latency
         # (dynamic snitch role); only countable replicas serve the
         # blockFor set (LOCAL_* never reads across DCs for the quorum)
@@ -324,7 +390,7 @@ class StorageProxy:
                 effective = None        # final round: no truncation
             merged, results = self._read_round(
                 keyspace, table_name, pk, targets, spares, block_for,
-                effective)
+                effective, cl)
             if effective is None or target_rows is None:
                 return merged
             truncated = [b for _, b, more in results if more]
@@ -351,27 +417,32 @@ class StorageProxy:
         return merged
 
     def _read_round(self, keyspace, table_name, pk, targets, spares,
-                    block_for, limits):
+                    block_for, limits, cl=None):
         """One digest-checked read round at the given limits. Returns
         (merged, results) with results = [(ep, batch, more)]."""
         results, digests = self._fetch(keyspace, table_name, pk,
                                        targets[:1], targets[1:],
                                        spares=spares, limits=limits)
         if len(results) + len(digests) < block_for:
-            raise TimeoutException(
-                f"{len(results) + len(digests)}/{block_for} read responses")
+            raise ReadTimeoutException(
+                f"{len(results) + len(digests)}/{block_for} read responses",
+                cl, len(results) + len(digests), block_for, bool(results))
         want = {self._digest(b) for _, b, _ in results} | \
             {d for _, d in digests}
         if len(want) > 1:
             # digest mismatch: full-data second round from every target
+            METRICS.incr("reads.digest_mismatches")
             tracing.trace("Digest mismatch: full data round + read repair")
-            results, _ = self._fetch(keyspace, table_name, pk, targets,
-                                     [], limits=limits)
-            if len(results) < block_for:
-                raise TimeoutException(
-                    f"{len(results)}/{block_for} data responses")
-            self._read_repair(keyspace, table_name,
-                              [(ep, b) for ep, b, _ in results])
+            with pipeline_ledger.span("coordinator.read.repair",
+                                      items=len(targets)):
+                results, _ = self._fetch(keyspace, table_name, pk, targets,
+                                         [], limits=limits)
+                if len(results) < block_for:
+                    raise ReadTimeoutException(
+                        f"{len(results)}/{block_for} data responses",
+                        cl, len(results), block_for, True)
+                self._read_repair(keyspace, table_name,
+                                  [(ep, b) for ep, b, _ in results])
         merged = cb.merge_sorted([b for _, b, _ in results])
         return merged, results
 
@@ -458,7 +529,9 @@ class StorageProxy:
 
         for target in data_targets + digest_targets:
             send_to(target, target in digest_targets)
-        done = handler.await_(min(self.speculative_delay, self.read_timeout))
+        with pipeline_ledger.span("coordinator.read.await", "stall"):
+            done = handler.await_(min(self.speculative_delay,
+                                      self.read_timeout))
         if not done and spares:
             from ..service.metrics import GLOBAL
             GLOBAL.incr("reads.speculative_retries")
@@ -471,7 +544,10 @@ class StorageProxy:
             handler.add_target()
             send_to(spares[0], False, speculative=True)
         # the read budget is self.read_timeout TOTAL, not per wait
-        handler.await_(max(self.read_timeout - (time.monotonic() - t0), 0.0))
+        if not done:
+            with pipeline_ledger.span("coordinator.read.await", "stall"):
+                handler.await_(max(
+                    self.read_timeout - (time.monotonic() - t0), 0.0))
         with lock:
             return list(results), list(digests)
 
@@ -489,6 +565,7 @@ class StorageProxy:
             m = batch_to_mutation(t, merged)
             if m is None:
                 continue
+            METRICS.incr("reads.read_repairs")
             if ep == self.node.endpoint:
                 self.node.engine.apply(m)
             else:
@@ -525,7 +602,8 @@ class StorageProxy:
             if len(live) < block_for:
                 raise UnavailableException(
                     f"filtered read at {cl}: range (..., {hi}] has "
-                    f"{len(live)} live replicas < {block_for}")
+                    f"{len(live)} live replicas < {block_for}",
+                    cl, block_for, len(live))
             live.sort(key=lambda r: (r != self.node.endpoint,
                                      self._latency_of(r)))
             targets.update(live[:block_for])
@@ -566,9 +644,10 @@ class StorageProxy:
                     on_failure=lambda mid: handler.fail(),
                     timeout=self.read_timeout)
         if not handler.await_(self.read_timeout):
-            raise TimeoutException(
+            raise ReadTimeoutException(
                 f"index candidates: {len(handler.responses)}/"
-                f"{len(targets)} responses")
+                f"{len(targets)} responses",
+                cl, len(handler.responses), len(targets))
         with lock:
             # dedupe locators by (pk, ck); the caller re-reads and
             # re-checks every candidate anyway, so which replica's copy
@@ -643,7 +722,7 @@ class StorageProxy:
             if len(live) < max(block_for, 1):
                 raise UnavailableException(
                     f"range ({s_lo}, {s_hi}]: {len(live)} live replicas "
-                    f"< {block_for}")
+                    f"< {block_for}", cl, max(block_for, 1), len(live))
             live.sort(key=lambda r: r != self.node.endpoint)
             targets = live[:max(block_for, 1)]
             effective = limits
@@ -654,7 +733,7 @@ class StorageProxy:
                     effective = None    # final round: no truncation
                 arc_res = self._arc_round(keyspace, table_name, s_lo,
                                           s_hi, targets, ck_comp,
-                                          effective)
+                                          effective, cl)
                 merged = cb.merge_sorted(
                     [b for _, b, _ in arc_res if len(b)]) \
                     if any(len(b) for _, b, _ in arc_res) \
@@ -736,7 +815,7 @@ class StorageProxy:
                         Verb.MUTATION_REQ, m.serialize(), ep)
 
     def _arc_round(self, keyspace, table_name, s_lo, s_hi, targets,
-                   ck_comp, limits):
+                   ck_comp, limits, cl=None):
         """One fetch of an arc from its targets at the given limits.
         Returns [(batch, more)]."""
         wire_limits = limits.to_wire() if limits is not None else None
@@ -774,9 +853,10 @@ class StorageProxy:
                     on_failure=lambda mid: handler.fail(),
                     timeout=self.range_timeout)
         if not handler.await_(self.range_timeout):
-            raise TimeoutException(
+            raise ReadTimeoutException(
                 f"range ({s_lo}, {s_hi}]: "
-                f"{len(handler.responses)}/{len(targets)} responses")
+                f"{len(handler.responses)}/{len(targets)} responses",
+                cl, len(handler.responses), len(targets))
         with lock:
             return list(got)
 
@@ -798,7 +878,7 @@ class StorageProxy:
                                                     ConsistencyLevel.LOCAL_ONE):
             raise UnavailableException(
                 f"range read at {cl} with {len(all_eps) - len(peers)} "
-                "endpoints down")
+                "endpoints down", cl, len(all_eps), len(peers))
         handler = _Await(len(peers))
         results = []
         lock = threading.Lock()
@@ -822,9 +902,9 @@ class StorageProxy:
                     on_failure=lambda mid: handler.fail(),
                     timeout=self.range_timeout)
         if not handler.await_(self.range_timeout):
-            raise TimeoutException(
+            raise ReadTimeoutException(
                 f"range read: {len(handler.responses)}/{len(peers)} "
-                "responses")
+                "responses", cl, len(handler.responses), len(peers))
         with lock:
             return cb.merge_sorted(results) if results else cb.CellBatch.empty()
 

@@ -470,8 +470,9 @@ class MessagingService:
 
     def _process_handler(self, msg: Message) -> None:
         """Verb-handler execution (pool workers; inline in sim mode).
-        Bills the per-verb ledger stage so the where-did-the-wall-go
-        table can attribute replica-side time by verb."""
+        One `messaging.handle.<verb>` span per message bills the
+        per-verb ledger stage, so the where-did-the-wall-go table and
+        the span ring attribute replica-side time by verb."""
         handler = self.handlers.get(msg.verb)
         if handler is None:
             return
@@ -490,9 +491,9 @@ class MessagingService:
                                      source=self.ep.name)
             rst.add(f"{msg.verb} received from {msg.sender.name}")
             token = tracing.activate(rst)
-        t0 = time.monotonic()
         try:
-            result = handler(msg)
+            with vstage.busy("messaging.handle." + vstage.name):
+                result = handler(msg)
         except Exception as e:
             if rst is not None:
                 rst.add(f"{msg.verb} failed: {type(e).__name__}")
@@ -500,7 +501,6 @@ class MessagingService:
                                  trace_events=rst.events if rst else None)
             return
         finally:
-            vstage.add_busy(time.monotonic() - t0)
             vstage.add_items(1)
             if token is not None:
                 tracing.deactivate(token)

@@ -472,20 +472,25 @@ class Node:
     def monitor(self):
         return getattr(self.engine, "monitor", None)
 
-    def apply(self, mutation: Mutation, durable: bool = True) -> None:
+    def apply(self, mutation: Mutation, durable: bool = True,
+              cl: str | None = None) -> None:
+        """Coordinate one write at `cl`, the level the request declared
+        (a wire request always declares one); `default_cl` serves the
+        callers that declare none (an in-process Session)."""
         t = self.schema.table_by_id(mutation.table_id)
         if t is None:
             raise KeyError(f"unknown table id {mutation.table_id}")
+        cl = cl or self.default_cl
         from ..storage.cellbatch import FLAG_COUNTER
         if any(op[7] & FLAG_COUNTER for op in mutation.ops):
             # increments are not idempotent: route through the counter
             # leader (cluster/counters.py), never the plain write path
-            self.counters.mutate(t.keyspace, mutation, self.default_cl)
+            self.counters.mutate(t.keyspace, mutation, cl)
         else:
-            self.proxy.mutate(t.keyspace, mutation, self.default_cl)
+            self.proxy.mutate(t.keyspace, mutation, cl)
 
-    def store(self, keyspace: str, name: str):
-        return _DistributedStore(self, keyspace, name)
+    def store(self, keyspace: str, name: str, cl: str | None = None):
+        return _DistributedStore(self, keyspace, name, cl)
 
     def add_table(self, t):
         # shared-schema round 1: every node opens a store for the table
@@ -780,24 +785,30 @@ class Node:
 class _DistributedStore:
     """Read facade the CQL executor uses; routes through the coordinator."""
 
-    def __init__(self, node: Node, keyspace: str, name: str):
+    def __init__(self, node: Node, keyspace: str, name: str,
+                 cl: str | None = None):
         self.node = node
         self.keyspace = keyspace
         self.name = name
+        self._cl = cl
+
+    @property
+    def cl(self) -> str:
+        """The level this store's reads are coordinated at: the one the
+        request declared, else the node's `default_cl` as it stands at
+        the read (tests flip it between statements)."""
+        return self._cl or self.node.default_cl
 
     def read_partition(self, pk: bytes, now=None, limits=None):
         return self.node.proxy.read_partition(self.keyspace, self.name, pk,
-                                              self.node.default_cl,
-                                              limits=limits)
+                                              self.cl, limits=limits)
 
     def scan_all(self, now=None):
-        return self.node.proxy.scan_all(self.keyspace, self.name,
-                                        self.node.default_cl)
+        return self.node.proxy.scan_all(self.keyspace, self.name, self.cl)
 
     def scan_window(self, lo: int, hi: int, now=None, limits=None):
         return self.node.proxy.scan_window(self.keyspace, self.name, lo,
-                                           hi, self.node.default_cl,
-                                           limits=limits)
+                                           hi, self.cl, limits=limits)
 
     def iter_scan(self, now=None, after: int = -(1 << 63),
                   window_parts: int = 64, limits=None):

@@ -24,6 +24,7 @@ import struct
 import threading
 import zlib
 
+from ..utils import pipeline_ledger
 from . import wire
 from .messaging import MessageFilters
 from .ring import Endpoint
@@ -135,7 +136,9 @@ class TcpTransport:
     def deliver(self, msg) -> None:
         if self.filters.should_drop(msg):
             return
-        body = wire.encode_message(msg)
+        with pipeline_ledger.span("messaging.encode") as sp:
+            body = wire.encode_message(msg)
+            sp.nbytes = len(body)
         conn = self._connection(msg.to)
         if conn is None:
             return          # unreachable: timeouts drive the failure path
@@ -233,7 +236,9 @@ class TcpTransport:
                 if body is None:
                     return
                 try:
-                    msg = wire.decode_message(body)
+                    with pipeline_ledger.span("messaging.decode",
+                                              nbytes=len(body)):
+                        msg = wire.decode_message(body)
                 except (ValueError, IndexError, KeyError, TypeError,
                         struct.error):
                     continue     # malformed frame: drop, keep the conn

@@ -247,7 +247,8 @@ class _MutationCollector:
             return None
         return getattr(self._backend, "triggers", None)
 
-    def apply(self, mutation, durable: bool = True) -> None:
+    def apply(self, mutation, durable: bool = True,
+              cl: str | None = None) -> None:
         self.mutations.append(mutation)
 
     def __getattr__(self, name):
@@ -260,8 +261,18 @@ class Executor:
     add_table/drop_table/create-keyspace hooks (StorageEngine satisfies
     this; the distributed StorageProxy will too)."""
 
-    def __init__(self, backend):
+    def __init__(self, backend, cl: str | None = None):
         self.backend = backend
+        # the consistency level the request being executed declared
+        # (`execute(consistency=...)` binds it); None = the caller
+        # declared none and the backend's own policy applies
+        self.cl = cl
+
+    def _apply(self, mutation) -> None:
+        self.backend.apply(mutation, cl=self.cl)
+
+    def _store(self, keyspace: str, name: str):
+        return self.backend.store(keyspace, name, cl=self.cl)
 
     @property
     def schema(self):
@@ -296,7 +307,19 @@ class Executor:
     def execute(self, stmt, params=(), keyspace: str | None = None,
                 now_micros: int | None = None,
                 user: str | None = None, page_size: int | None = None,
-                paging_state: bytes | None = None) -> ResultSet:
+                paging_state: bytes | None = None,
+                consistency: str | None = None) -> ResultSet:
+        """`consistency` is the level the request declared (the wire's
+        <consistency>): every read and write the statement causes, its
+        batch's, its views' and its index candidates' included, reaches
+        the backend with it (`backend.apply(m, cl=...)`,
+        `backend.store(ks, t, cl=...)`). None leaves the backend's own
+        policy in place (a cluster Node's `default_cl`). Serial
+        consistency (LWT) is not taken from the request."""
+        if consistency is not None and consistency != self.cl:
+            return Executor(self.backend, consistency).execute(
+                stmt, params, keyspace, now_micros, user, page_size,
+                paging_state)
         name = type(stmt).__name__
         auth = getattr(self.backend, "auth", None)
         if auth is not None and auth.enabled:
@@ -703,7 +726,7 @@ class Executor:
 
     def _backfill_view(self, base, vt) -> None:
         from ..storage.paging import paged_rows
-        cfs = self.backend.store(base.keyspace, base.name)
+        cfs = self._store(base.keyspace, base.name)
         now = timeutil.now_micros()
         for row in paged_rows(cfs, base):
             if row.is_static:
@@ -741,18 +764,18 @@ class Executor:
             # reference). Collecting backends record them so logged
             # batches journal trigger output alongside the base writes.
             for em in trig.augment(t, m, self.backend):
-                self.backend.apply(em)
+                self._apply(em)
         views = self._views_of(t) if t is not None else []
         if not views or getattr(self.backend, "collects_only", False):
             # a collecting backend (logged batch) records the base
             # mutation only: pre==post there and deriving view updates
             # from it would log stale rows — maintenance happens when
             # the collected mutations are REALLY applied
-            self.backend.apply(m)
+            self._apply(m)
             return
         view_ts = max((op[4] for op in m.ops), default=now)
         pre = self._affected_rows(t, m)
-        self.backend.apply(m)
+        self._apply(m)
         post = self._affected_rows(t, m)
         for vt in views:
             for key in set(pre) | set(post):
@@ -772,7 +795,7 @@ class Executor:
         cks = {op[0] for op in m.ops
                if op[1] not in (schema_mod.COL_PARTITION_DEL,
                                 schema_mod.COL_RANGE_TOMB)}
-        batch = self.backend.store(t.keyspace, t.name).read_partition(m.pk)
+        batch = self._store(t.keyspace, t.name).read_partition(m.pk)
         out = {}
         for r in rows_from_batch(t, batch):
             if r.is_static:
@@ -825,7 +848,7 @@ class Executor:
                 m.add(ck, c.column_id, b"", b"", now, now_s, 0,
                       cb.FLAG_TOMBSTONE)
         if apply:
-            self.backend.apply(m)
+            self._apply(m)
         return m
 
     def _update_view(self, vt, pre: dict | None, post: dict | None,
@@ -841,7 +864,7 @@ class Executor:
             m = Mutation(vt.id, pk)
             m.add(ck, schema_mod.COL_ROW_DEL, b"", b"", now, now_s, 0,
                   cb.FLAG_ROW_DEL)
-            self.backend.apply(m)
+            self._apply(m)
         if new_key is not None:
             self._view_row_mutation(
                 vt, post, now, apply=True,
@@ -1143,7 +1166,7 @@ class Executor:
         if gr is not None:
             gr.check_drop_truncate("TRUNCATE")
         t = self._table(s, keyspace)
-        self.backend.store(t.keyspace, t.name).truncate()
+        self._store(t.keyspace, t.name).truncate()
         return ResultSet([], [])
 
     def _exec_UseStatement(self, s, params, keyspace, now):
@@ -1618,7 +1641,7 @@ class Executor:
             import copy as copy_mod
             collector = _MutationCollector(self.backend,
                                            fire_triggers=False)
-            sub_exec = Executor(collector)
+            sub_exec = Executor(collector, self.cl)
             for sub, _ck in per_stmt:
                 sub2 = copy_mod.copy(sub)
                 if hasattr(sub2, "if_not_exists"):
@@ -1692,7 +1715,7 @@ class Executor:
             # a crash mid-apply replays the remainder at boot
             # (BatchStatement.executeWithConditions logged path)
             collector = _MutationCollector(self.backend)
-            sub_exec = Executor(collector)
+            sub_exec = Executor(collector, self.cl)
             for sub in s.statements:
                 sub_exec.execute(sub, params, keyspace, now_micros=now,
                                  user=user)
@@ -1726,7 +1749,7 @@ class Executor:
     # -------------------------------------------------------------- SELECT
 
     def _read_row(self, t, pk, ck, now_micros) -> dict | None:
-        cfs = self.backend.store(t.keyspace, t.name)
+        cfs = self._store(t.keyspace, t.name)
         batch = cfs.read_partition(pk)
         for r in rows_from_batch(t, batch):
             if r.ck_frame == ck and not r.is_static:
@@ -1779,7 +1802,7 @@ class Executor:
                 return rs
 
         t = self._table(s, keyspace)
-        cfs = self.backend.store(t.keyspace, t.name)
+        cfs = self._store(t.keyspace, t.name)
         pk_vals, ck_rel, filters = self._split_where(t, s.where, params)
 
         if s.ann is not None:
@@ -2314,7 +2337,7 @@ class Executor:
             # re-read + re-check below drops stale matches)
             locators = proxy.index_candidates(
                 t.keyspace, t.name, col.name, op, dist_value,
-                getattr(self.backend, "default_cl", "ONE"))
+                self.cl or getattr(self.backend, "default_cl", "ONE"))
         out = []
         for pk, ck in locators:
             batch = cfs.read_partition(pk)
@@ -2359,7 +2382,8 @@ class Executor:
             # the union (bigger score = better)
             cands = proxy.index_candidates(
                 t.keyspace, t.name, col_name, "ANN",
-                (q.tolist(), k), getattr(self.backend, "default_cl", "ONE"))
+                (q.tolist(), k),
+                self.cl or getattr(self.backend, "default_cl", "ONE"))
             cands.sort(key=lambda x: -x[2])
             hits = cands[:k]
         else:

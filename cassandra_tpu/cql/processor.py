@@ -89,23 +89,27 @@ class QueryProcessor:
                          keyspace: str | None = None,
                          user: str | None = None,
                          page_size: int | None = None,
-                         paging_state: bytes | None = None) -> ResultSet:
+                         paging_state: bytes | None = None,
+                         consistency: str | None = None) -> ResultSet:
         prep = self.get_prepared(qid)
         if prep is None:
             raise InvalidRequest("unknown prepared statement")
         return self.execute_statement(prep, params, keyspace, user=user,
                                       page_size=page_size,
-                                      paging_state=paging_state)
+                                      paging_state=paging_state,
+                                      consistency=consistency)
 
     def execute_statement(self, prep: Prepared, params=(),
                           keyspace: str | None = None,
                           user: str | None = None,
                           page_size: int | None = None,
-                          paging_state: bytes | None = None) -> ResultSet:
+                          paging_state: bytes | None = None,
+                          consistency: str | None = None) -> ResultSet:
         """Execute an already-resolved Prepared. The transport fetches
         the Prepared ONCE (for the UNPREPARED check and verb
         classification) and executes that same object — no second
-        lookup that could race LRU eviction into the wrong error."""
+        lookup that could race LRU eviction into the wrong error.
+        `consistency`: the level the request declared (Executor.execute)."""
         audit = getattr(self.executor.backend, "audit_log", None)
         if audit is not None:
             audit.log(type(prep.statement).__name__, prep.query, user,
@@ -125,7 +129,8 @@ class QueryProcessor:
                                        prep.statement)
         return self.executor.execute(prep.statement, params, keyspace,
                                      user=user, page_size=page_size,
-                                     paging_state=paging_state)
+                                     paging_state=paging_state,
+                                     consistency=consistency)
 
     def _ddl_sync_for(self, stmt):
         """The schema-sync service, iff `stmt` is DDL that must
@@ -153,7 +158,8 @@ class QueryProcessor:
     def process(self, query: str, params=(),
                 keyspace: str | None = None,
                 user: str | None = None, page_size: int | None = None,
-                paging_state: bytes | None = None) -> ResultSet:
+                paging_state: bytes | None = None,
+                consistency: str | None = None) -> ResultSet:
         import time as time_mod
 
         from ..service.metrics import GLOBAL
@@ -192,7 +198,8 @@ class QueryProcessor:
                     rs = self.executor.execute(
                         stmt, params, keyspace, user=user,
                         page_size=page_size,
-                        paging_state=paging_state)
+                        paging_state=paging_state,
+                        consistency=consistency)
                 finally:
                     t_ser = time_mod.perf_counter()
                     phases["execute"] = t_ser - t_exec

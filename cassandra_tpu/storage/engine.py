@@ -78,6 +78,7 @@ class StorageEngine:
         self.cdc = CDCLog(os.path.join(data_dir, "cdc_raw"))
         self.commitlog = CommitLog(
             os.path.join(data_dir, "commitlog"),
+            segment_size=int(self.settings.get("commitlog_segment_size")),
             sync_mode=commitlog_sync,
             sync_period_ms=int(commitlog_sync_period_ms),
             archive_dir=commitlog_archive_dir,
@@ -512,7 +513,10 @@ class StorageEngine:
             if self.commitlog:
                 self.commitlog.forget_table(t.id)
 
-    def store(self, keyspace: str, name: str) -> ColumnFamilyStore:
+    def store(self, keyspace: str, name: str,
+              cl: str | None = None) -> ColumnFamilyStore:
+        """`cl` is the request's consistency level: one node is every
+        replica there is, so it is accepted and ignored."""
         t = self.schema.get_table(keyspace, name)
         return self.stores[t.id]
 
@@ -521,10 +525,12 @@ class StorageEngine:
 
     # -------------------------------------------------------------- write --
 
-    def apply(self, mutation: Mutation, durable: bool = True) -> None:
+    def apply(self, mutation: Mutation, durable: bool = True,
+              cl: str | None = None) -> None:
         """Keyspace.apply: commitlog first, then memtable (one atomic unit
         vs concurrent flushes); flush when the memtable crosses its
-        threshold."""
+        threshold. `cl`, a request's consistency level, is accepted and
+        ignored, as in `store`."""
         self.failures.check_can_write()
         cfs = self.stores.get(mutation.table_id)
         if cfs is None:

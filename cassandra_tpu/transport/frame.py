@@ -45,7 +45,10 @@ RESULT_SCHEMA_CHANGE = 0x0005
 ERR_SERVER = 0x0000
 ERR_PROTOCOL = 0x000A
 ERR_BAD_CREDENTIALS = 0x0100
+ERR_UNAVAILABLE = 0x1000
 ERR_OVERLOADED = 0x1001
+ERR_WRITE_TIMEOUT = 0x1100
+ERR_READ_TIMEOUT = 0x1200
 ERR_INVALID = 0x2200
 ERR_UNPREPARED = 0x2500
 
@@ -53,15 +56,16 @@ EVENT_TYPES = ("TOPOLOGY_CHANGE", "STATUS_CHANGE", "SCHEMA_CHANGE")
 
 # consistency-level wire codes (spec §3) — the ONE table both sides of
 # the wire derive from: the client encodes names through it, the server
-# tags the per-CL client_requests hists through its inverse
+# reads the level a request is coordinated at (and the tag of the per-CL
+# client_requests hists) through its inverse
 CONSISTENCY_CODES = {
     "ANY": 0x00, "ONE": 0x01, "TWO": 0x02, "THREE": 0x03,
     "QUORUM": 0x04, "ALL": 0x05, "LOCAL_QUORUM": 0x06,
     "EACH_QUORUM": 0x07, "SERIAL": 0x08, "LOCAL_SERIAL": 0x09,
     "LOCAL_ONE": 0x0A,
 }
-CONSISTENCY_NAMES = {code: name.lower()
-                     for name, code in CONSISTENCY_CODES.items()}
+CONSISTENCY_LEVELS = {code: name
+                      for name, code in CONSISTENCY_CODES.items()}
 
 # envelope body length cap (native_transport_max_frame_size ceiling —
 # a length field larger than this is a framing error, not an allocation)
@@ -241,6 +245,32 @@ def _encode_rows(rs) -> bytes:
 
 def error_body(code: int, msg: str) -> bytes:
     return struct.pack(">i", code) + _string(msg)
+
+
+def _cl_short(cl: str) -> bytes:
+    return struct.pack(">H", CONSISTENCY_CODES[cl])
+
+
+def unavailable_body(msg: str, cl: str, required: int, alive: int) -> bytes:
+    """UNAVAILABLE: <cl><required><alive> after the message (spec §9,
+    0x1000): the level asked for, the replicas it needs alive, the
+    replicas known alive when the request was refused."""
+    return error_body(ERR_UNAVAILABLE, msg) + _cl_short(cl) \
+        + struct.pack(">ii", required, alive)
+
+
+def write_timeout_body(msg: str, cl: str, received: int, block_for: int,
+                       write_type: str) -> bytes:
+    """WRITE_TIMEOUT: <cl><received><blockfor><writeType> (0x1100)."""
+    return error_body(ERR_WRITE_TIMEOUT, msg) + _cl_short(cl) \
+        + struct.pack(">ii", received, block_for) + _string(write_type)
+
+
+def read_timeout_body(msg: str, cl: str, received: int, block_for: int,
+                      data_present: bool) -> bytes:
+    """READ_TIMEOUT: <cl><received><blockfor><data_present> (0x1200)."""
+    return error_body(ERR_READ_TIMEOUT, msg) + _cl_short(cl) \
+        + struct.pack(">iiB", received, block_for, int(data_present))
 
 
 def unprepared_body(qid: bytes) -> bytes:

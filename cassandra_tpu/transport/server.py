@@ -49,7 +49,7 @@ from ..service.metrics import GLOBAL as METRICS
 from ..utils.ratelimit import RateLimiter
 from ..utils import lockwitness, pipeline_ledger
 from .admission import OverloadSignals, PermitGate
-from .frame import (CONSISTENCY_NAMES, ERR_BAD_CREDENTIALS, ERR_INVALID,
+from .frame import (CONSISTENCY_LEVELS, ERR_BAD_CREDENTIALS, ERR_INVALID,
                     ERR_OVERLOADED, ERR_PROTOCOL, ERR_SERVER, EVENT_TYPES,
                     MAX_ENVELOPE_BODY, OP_AUTH_RESPONSE, OP_AUTH_SUCCESS,
                     OP_AUTHENTICATE, OP_ERROR, OP_EVENT, OP_EXECUTE,
@@ -60,7 +60,8 @@ from .frame import (CONSISTENCY_NAMES, ERR_BAD_CREDENTIALS, ERR_INVALID,
                     _crc32_v5, _encode_rows, _inet, _read_bytes,
                     _read_long_string, _read_string, _string,
                     decode_segment_header, encode_envelope, error_body,
-                    frame_envelope, unprepared_body)
+                    frame_envelope, read_timeout_body, unavailable_body,
+                    unprepared_body, write_timeout_body)
 
 # opcodes that run on the dispatch executor; everything else (handshake,
 # registration) is cheap enough to handle inline on the event loop
@@ -84,12 +85,29 @@ def server_thread_count(port: int) -> int:
                 if t.name.startswith(pfx) and t.is_alive()])
 
 
-def _error_response(e: Exception) -> tuple[int, bytes]:
-    """Uncaught execution error -> wire ERROR (InvalidRequest subclasses
+def _error_response(e: Exception, level: str = "ONE") -> tuple[int, bytes]:
+    """Uncaught execution error -> wire ERROR. A level the live replicas
+    cannot serve answers in the protocol's terms: UNAVAILABLE (0x1000),
+    WRITE_TIMEOUT (0x1100), READ_TIMEOUT (0x1200), with the fields the
+    coordinator's exception carries (`level`, the one the request
+    declared, where it carries none). InvalidRequest subclasses
     ValueError, so CQL-level rejections map to 0x2200; everything else
-    is a server bug, 0x0000)."""
+    is a server bug, 0x0000."""
+    from ..cluster.coordinator import (TimeoutException,
+                                       UnavailableException,
+                                       WriteTimeoutException)
+    msg = f"{type(e).__name__}: {e}"
+    if isinstance(e, UnavailableException):
+        return OP_ERROR, unavailable_body(msg, e.cl or level, e.required,
+                                          e.alive)
+    if isinstance(e, WriteTimeoutException):
+        return OP_ERROR, write_timeout_body(msg, e.cl or level, e.received,
+                                            e.block_for, e.write_type)
+    if isinstance(e, TimeoutException):
+        return OP_ERROR, read_timeout_body(
+            msg, e.cl or level, e.received, e.block_for, e.data_present)
     code = ERR_INVALID if isinstance(e, ValueError) else ERR_SERVER
-    return OP_ERROR, error_body(code, f"{type(e).__name__}: {e}")
+    return OP_ERROR, error_body(code, msg)
 
 
 def _cert_identity(sock) -> str | None:
@@ -1117,6 +1135,12 @@ class CQLServer:
              pos: int, prep=None):
         consistency, = struct.unpack_from(">H", body, pos)
         pos += 2
+        # the level the request is coordinated at: handed down as an
+        # argument to every read and write the statement causes
+        level = CONSISTENCY_LEVELS.get(consistency)
+        if level is None:
+            return OP_ERROR, error_body(
+                ERR_PROTOCOL, f"unknown consistency level {consistency:#06x}")
         if conn.version >= 0x05:          # v5 widened flags to [int]
             (flags,) = struct.unpack_from(">I", body, pos)
             pos += 4
@@ -1145,23 +1169,28 @@ class CQLServer:
             is_read = type(prep.statement).__name__ == "SelectStatement"
         else:
             is_read = query.lstrip()[:6].upper() == "SELECT"
-        with pipeline_ledger.span("cql.execute", nbytes=len(body)) as sp:
-            if prep is not None:   # EXECUTE: resolved, no re-parse
-                rs = processor.execute_statement(
-                    prep, params, conn.keyspace, user=conn.user,
-                    page_size=page_size, paging_state=paging_state)
-            else:
-                rs = processor.process(query, params, conn.keyspace,
-                                       user=conn.user,
-                                       page_size=page_size,
-                                       paging_state=paging_state)
+        try:
+            with pipeline_ledger.span("cql.execute",
+                                      nbytes=len(body)) as sp:
+                if prep is not None:   # EXECUTE: resolved, no re-parse
+                    rs = processor.execute_statement(
+                        prep, params, conn.keyspace, user=conn.user,
+                        page_size=page_size, paging_state=paging_state,
+                        consistency=level)
+                else:
+                    rs = processor.process(query, params, conn.keyspace,
+                                           user=conn.user,
+                                           page_size=page_size,
+                                           paging_state=paging_state,
+                                           consistency=level)
+        except Exception as e:
+            return _error_response(e, level)
         us = sp.seconds * 1e6
         verb = "read" if is_read else "write"
-        # the per-CL tag uses the level the client DECLARED, so a
-        # saturation-matrix breach attributes to ONE vs QUORUM instead
-        # of blending them; a code outside the spec table lands in an
-        # explicit "unknown" bucket, never mis-attributed to a real CL
-        cl = CONSISTENCY_NAMES.get(consistency, "unknown")
+        # the per-CL tag uses the level the client DECLARED (and the
+        # request was coordinated at), so a saturation-matrix breach
+        # attributes to ONE vs QUORUM instead of blending them
+        cl = level.lower()
         # blended hist (the historical surface + default SLO objective)
         # AND the per-CL family the matrix attributes breaches through
         METRICS.hist(f"client_requests.{verb}").update_us(us)

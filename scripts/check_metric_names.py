@@ -64,10 +64,12 @@ SINGLE_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 # groups" + the mesh.* group from docs/multichip.md)
 KNOWN_GROUPS = {
     "audit", "client_requests", "clients", "commitlog", "compaction",
-    "compress_pool", "controller", "cql", "flush", "hints", "history",
+    "compress_pool", "controller", "coordinator", "cql", "flush", "hints",
+    "history",
     "index", "mesh",
     "pipeline", "prepared_statements", "profile", "reads", "request",
     "scan", "slo", "storage", "streaming", "system", "table", "verb",
+    "writes",
 }
 
 

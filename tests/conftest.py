@@ -14,3 +14,18 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _fresh_sstable_caches():
+    """The chunk cache and the key cache are process-global and keyed by
+    (directory, generation): an sstable's identity for the life of a node.
+    Since PR 33 pytest removes a passing test's tmp_path at once
+    (pytest.ini: the disk filled otherwise) and hands its numbered name to
+    the next test, so two tests can hold different sstables under one
+    directory and generation. Each test starts with both caches empty."""
+    from cassandra_tpu.storage import chunk_cache, key_cache
+    chunk_cache.GLOBAL.clear()
+    key_cache.GLOBAL.clear()
