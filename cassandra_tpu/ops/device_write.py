@@ -122,15 +122,17 @@ RESIDENT_COLS = ("lanes", "ts_h", "ts_l", "ldt", "ttl", "flags8",
 
 @jax.jit
 def _resident_program(operands):
-    """One dispatch: LSD sort, reconcile+purge, kept-cell compaction,
-    column gather and the expired -> tombstone conversion — the merged
-    round stays on the device, in output order, kept cells first.
-    Returns (n_keep, n_amb, n_exp_kept, perm_out, cols, perm, packed);
-    the last two feed the host fallback when n_amb demands it."""
+    """One dispatch: LSD sort (only the passes whose key varies in this
+    round), reconcile+purge, kept-cell compaction, column gather and the
+    expired -> tombstone conversion — the merged round stays on the
+    device, in output order, kept cells first.
+    Returns (n_keep, n_amb, n_exp_kept, n_passes, perm_out, cols, perm,
+    packed); n_passes is how many sort passes the round ran, and the last
+    two feed the host fallback when n_amb demands it."""
     # named_scope: metadata only (op names in a profiler trace), the
     # program and its bytes are unchanged
     with jax.named_scope("sort"):
-        perm = dmerge.device_sort_perm(operands)
+        perm, n_passes = dmerge.device_sort_perm(operands)
     with jax.named_scope("reconcile"):   # its purge stage names itself
         packed = dmerge.reconcile_kernel(operands, perm)
     with jax.named_scope("compact"):
@@ -159,7 +161,7 @@ def _resident_program(operands):
             exp_out, cols["flags8"] | jnp.uint8(FLAG_TOMBSTONE),
             cols["flags8"])
         cols["fl"] = jnp.where(exp_out, cols["vr"], cols["fl"])
-    return n_keep, n_amb, n_exp_kept, perm_out, cols, perm, packed
+    return n_keep, n_amb, n_exp_kept, n_passes, perm_out, cols, perm, packed
 
 
 # ------------------------------------------------------ serialize kernel --
@@ -300,6 +302,16 @@ _TEST_COLLECT_DELAY = None
 _collect_seq = 0
 
 
+def _note_passes(sp, h: ResidentHandle) -> None:
+    """Read how many sort passes a FINISHED round ran (one more scalar of
+    the pull that waited for it) into the wait span — items = the passes
+    the sort has, cells = the passes this round ran — and the counters."""
+    sp.items = dmerge.n_sort_keys(h.cat.n_lanes)
+    sp.cells = int(h.out[3])
+    _METRICS.incr("merge.resident.passes_run", sp.cells)
+    _METRICS.incr("merge.resident.passes_skipped", sp.items - sp.cells)
+
+
 def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
                           now: int = 0, purgeable_ts_fn=None,
                           prof: dict | None = None,
@@ -370,12 +382,13 @@ def collect_merge_resident(h: ResidentHandle):
     if h.mode == "done":
         return promote_round(h.result)
     cat, prof = h.cat, h.prof
-    n_keep_d, n_amb_d, n_exp_d, perm_out_d, cols = h.out[:5]
+    n_keep_d, n_amb_d, n_exp_d, _, perm_out_d, cols = h.out[:6]
     with _LED_RESIDENT.stall("merge.resident.wait", prof=prof,
                              key="device") as sp:
         n_keep = int(n_keep_d)      # blocks until the program finishes
         n_amb = int(n_amb_d)
         n_exp_kept = int(n_exp_d)
+        _note_passes(sp, h)
     _kprof.record_execute("merge.resident", sp.seconds)
 
     # cells = the cells the round kept, items = the cells it read
@@ -429,6 +442,7 @@ def materialize_round(h: ResidentHandle) -> CellBatch:
     with _LED_RESIDENT.stall("merge.resident.wait", prof=h.prof,
                              key="device") as sp:
         jax.block_until_ready(h.out[-2:])
+        _note_passes(sp, h)
     _kprof.record_execute("merge.resident", sp.seconds)
     with _LED_RESIDENT.busy("merge.resident.gather", prof=h.prof,
                             key="gather", cells=h.n):

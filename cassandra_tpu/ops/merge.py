@@ -12,9 +12,15 @@ Sorting strategy (the load-bearing TPU decision): XLA's TPU sort compile
 time explodes with the number of operands (a 2-operand sort compiles in
 seconds; an 18-operand variadic sort takes tens of minutes), while warm
 runs are fast. So the lexicographic sort is an LSD radix composition:
-16 passes of ONE reused jitted (key, perm) stable sort, least-significant
-lane first. One small program compiles once; the passes chain on-device
-with no host synchronisation.
+passes of ONE (key, perm) stable sort, least-significant lane first, one
+per key — validity, the identity lanes, ~ts: 16 at 13 lanes. The passes
+chain on-device with no host synchronisation. A round runs only the
+passes whose key VARIES among its valid cells: a stable sort by a
+constant key moves nothing, and a table with no clustering column and no
+collection holds 0 in eight of its thirteen lanes. The program asks each
+key on the device (a masked min/max), skips the pass under `lax.cond`,
+and returns how many it ran; no host round trip, no static mask, no
+second compiled shape.
 
 Tie-breaks beyond (identity, timestamp) — tombstone-beats-data and
 larger-value-wins at equal timestamps (db/rows/Cells.java:68) — are
@@ -101,16 +107,38 @@ def _sort_keys(operands) -> list:
     return keys
 
 
-def _traced_sort_perm(operands) -> jnp.ndarray:
-    """LSD composition. Works eagerly (each _lsd_pass hits the one cached
-    jit program; dispatches pipeline without host sync) and under an
-    enclosing jit/shard_map (nested jit inlines)."""
+def n_sort_keys(n_lanes: int) -> int:
+    """How many keys _sort_keys yields — the passes a round can run."""
+    return n_lanes + 3
+
+
+def _traced_sort_perm(operands):
+    """LSD composition over the keys that VARY in this round: a stable
+    pass by a key that is the same in every valid cell leaves the valid
+    cells where they are, so the program asks each key (one masked
+    min/max reduction, on the device, from its own input) and runs the
+    pass only where the answer is yes. Padding rows are masked out of
+    the question — they carry 0xFFFFFFFF lanes and ts 0, so unmasked
+    every key of a padded round would vary — and need not sit at the
+    end (a mesh shard's valid rows start anywhere). `valid` itself is
+    always sorted: it is what moves the padding behind the cells.
+
+    Works eagerly and under an enclosing jit/shard_map (nested jit
+    inlines).
+
+    Returns (perm, passes_run); perm is the array the unconditional
+    passes give, element for element."""
     keys = _sort_keys(operands)
-    N = keys[0].shape[0]
-    perm = jnp.arange(N, dtype=jnp.int32)
-    for key in reversed(keys):
-        perm = _lsd_pass(jnp.asarray(key), perm)
-    return perm
+    live = operands["valid"] == 0
+    perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    ran = jnp.int32(1)
+    for key in reversed(keys[1:]):
+        key = jnp.asarray(key)
+        varies = (jnp.max(jnp.where(live, key, jnp.uint32(0)))
+                  > jnp.min(jnp.where(live, key, _U32_MAX)))
+        perm = jax.lax.cond(varies, _lsd_pass, lambda _k, p: p, key, perm)
+        ran += varies
+    return _lsd_pass(jnp.asarray(keys[0]), perm), ran
 
 
 device_sort_perm = _traced_sort_perm
@@ -228,7 +256,7 @@ def merge_reconcile_kernel(operands):
     """Jittable single-call form (driver entry / shard_map body): traced
     sort composition + reconcile. Returns (perm, packed_masks) where
     packed bit0=keep, bit1=ambiguous, bit2=expired, bit3=shadowed."""
-    perm = _traced_sort_perm(operands)
+    perm, _ = _traced_sort_perm(operands)
     packed = reconcile_kernel(operands, perm)
     return perm, packed
 

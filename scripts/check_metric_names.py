@@ -66,7 +66,7 @@ KNOWN_GROUPS = {
     "audit", "client_requests", "clients", "commitlog", "compaction",
     "compress_pool", "controller", "coordinator", "cql", "flush", "hints",
     "history",
-    "index", "mesh",
+    "index", "merge", "mesh",
     "pipeline", "prepared_statements", "profile", "reads", "request",
     "scan", "slo", "storage", "streaming", "system", "table", "verb",
     "writes",
