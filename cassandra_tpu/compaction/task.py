@@ -33,7 +33,7 @@ from ..ops.device_write import (DeviceWriteLane, collect_merge_resident,
 from ..storage import cellbatch as cb
 from ..storage.lifecycle import LifecycleTransaction
 from ..storage.sstable import Descriptor, SSTableReader, SSTableWriter
-from ..utils import pipeline_ledger, timeutil
+from ..utils import gil_probe, pipeline_ledger, timeutil
 
 _log = logging.getLogger(__name__)
 
@@ -861,7 +861,13 @@ class CompactionTask:
                 "compaction.task", task=pipeline_ledger.new_task_id(),
                 cells=sum(r.n_cells for r in self.inputs),
                 nbytes=sum(r.data_size for r in self.inputs)) as root:
-            return self._execute(root.task)
+            # the GIL probe beats for the length of the task: a bare
+            # store compacts with no engine open (utils/gil_probe.py)
+            gil_probe.GLOBAL.set_demand(id(self), True)
+            try:
+                return self._execute(root.task)
+            finally:
+                gil_probe.GLOBAL.set_demand(id(self), False)
 
     def _execute(self, task_id: int) -> dict:
         cfs = self.cfs

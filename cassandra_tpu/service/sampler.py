@@ -23,7 +23,11 @@ process's named daemon threads:
   socket/ssl/subprocess) is parked on a lock, queue, poll or socket —
   `blocked`; any other leaf is presumed running — `cpu`. A documented
   approximation: C-level waits that show the caller's Python frame
-  (time.sleep, native I/O) classify as cpu. The split is what
+  (time.sleep, native I/O) classify as cpu. Its `cpu` class therefore
+  INCLUDES threads that are waiting for the GIL (such a thread's leaf
+  is its own Python frame): for the exact ran / did-not-run split read
+  the spans' `cpu` field and the `runtime.gil.handoff` records
+  (utils/pipeline_ledger.py, utils/gil_probe.py). The split is what
   reconciles against the pipeline ledger's busy/stall accounting
   (bench.py `profiler` section).
 - **Collapsed-stack export** (`collapsed()`): Brendan-Gregg collapsed

@@ -10,7 +10,7 @@ import os
 import threading
 
 from ..schema import Schema, TableMetadata
-from ..utils import pipeline_ledger, timeutil
+from ..utils import gil_probe, pipeline_ledger, timeutil
 from .commitlog import CommitLog
 from .mutation import Mutation
 from .table import ColumnFamilyStore
@@ -372,6 +372,9 @@ class StorageEngine:
             self.settings.get("profiler_retrace_budget"))
         _sampler.GLOBAL.set_demand(
             id(self), self.settings.get("profiler_enabled"))
+        # the GIL hand-off probe (utils/gil_probe.py) beats while any
+        # engine of the process is open: no knob, like the span ring
+        gil_probe.GLOBAL.set_demand(id(self), True)
 
         # compaction-history ring bound: every store's per-compaction
         # stats deque follows the mutable compaction_history_entries
@@ -705,6 +708,7 @@ class StorageEngine:
         from ..service import sampler as _sampler
         diagnostics.GLOBAL.set_demand(id(self), False)
         _sampler.GLOBAL.set_demand(id(self), False)
+        gil_probe.GLOBAL.set_demand(id(self), False)
         self.flight_recorder.close()
         self.settings.remove_listener("compaction_throughput",
                                       self._throttle_listener)

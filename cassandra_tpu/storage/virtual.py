@@ -313,6 +313,7 @@ def build_engine_virtuals(engine) -> VirtualSchema:
                         ck=["stage"],
                         cols={"pipeline": "text", "stage": "text",
                               "busy_seconds": "double",
+                              "busy_cpu_seconds": "double",
                               "stall_seconds": "double",
                               "idle_seconds": "double",
                               "items": "bigint", "bytes": "bigint",
@@ -325,6 +326,7 @@ def build_engine_virtuals(engine) -> VirtualSchema:
             for sname, s in stages.items():
                 yield {"pipeline": pname, "stage": sname,
                        "busy_seconds": s["busy_s"],
+                       "busy_cpu_seconds": s["busy_cpu_s"],
                        "stall_seconds": s["stall_s"],
                        "idle_seconds": s["idle_s"],
                        "items": s["items"], "bytes": s["bytes"],

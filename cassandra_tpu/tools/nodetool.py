@@ -753,8 +753,10 @@ def slostats(engine) -> dict:
 
 def pipelinestats(engine) -> dict:
     """nodetool pipelinestats: the unified pipeline ledger — per-stage
-    busy/stall/idle seconds, items/bytes and queue high-water for every
-    multi-stage pipeline (utils/pipeline_ledger.py; the
+    busy/stall/idle seconds, the CPU seconds inside the busy ones
+    (`busy_cpu_s`: busy 80 % and on the CPU 30 % is a stage that waits
+    for the GIL, a lock or the device), items/bytes and queue high-water
+    for every multi-stage pipeline (utils/pipeline_ledger.py; the
     system_views.pipelines vtable serves the same rows)."""
     from ..utils import pipeline_ledger
     return pipeline_ledger.snapshot_all()

@@ -9,6 +9,9 @@ fanout lanes, the transport dispatch executor) carried its own ad-hoc
 counters — or none. Now they all report through one `Stage` shape:
 
     busy_s       seconds the stage spent doing its own work
+    busy_cpu_s   the part of busy_s its threads were ON the CPU (by the
+                 spans that bill the stage; see `Span`; an estimate where
+                 only one root span in N reads the thread clock)
     stall_s      seconds the stage spent BLOCKED on a downstream stage
                  (full queue, exhausted buffer pool — backpressure paid)
     idle_s       seconds the stage spent waiting for upstream input
@@ -37,11 +40,18 @@ key, and appends one record to the process-global span ring (`RING`):
 the three clocks that used to time one phase are one write. Spans open
 per round, segment, pool job or request — never per cell, partition or
 block. docs/observability.md has the span-name catalogue.
+
+A span reads two clocks: the wall (`CLOCK`) and its own thread's CPU
+time (`CPU_CLOCK`). The ring record keeps both, so `wall - cpu` says
+how long the thread was OFF the CPU inside the span; `gil_probe.py`
+beside this file records through the same ring what one release-and-
+retake of the GIL costs at that instant.
 """
 from __future__ import annotations
 
 import collections
 import itertools
+import math
 import sys
 import threading
 from . import lockwitness
@@ -54,19 +64,57 @@ import time
 # enter/exit time, so a swap takes effect immediately.
 CLOCK = time.perf_counter
 
+# ctpulint: clock-injectable
+# the CPU time of the CALLING thread (CLOCK_THREAD_CPUTIME_ID), read by
+# a span where it reads CLOCK and patchable the same way.
+CPU_CLOCK = time.thread_time
+
+# CLOCK comes from the vDSO; CPU_CLOCK is a system call: 0.3 µs on a stock
+# kernel, 6.3 µs in the chip hosts' sandbox, where two of them a span cost
+# ycsb_a.wire 9 % of its ops_s (PERF.md §6, PR 35). Two things keep a span
+# near what it cost before, neither of them a knob:
+# - span boundaries come in bursts on a thread (a request's nested spans
+#   open within microseconds of each other and close the same way), so a
+#   boundary that follows a READING of the thread clock by less than
+#   CPU_REUSE_S takes that reading plus the wall since: between two of its
+#   own boundaries that close together a thread was running. A GIL
+#   hand-off or a blocking call costs more than this, so none is swallowed
+#   whole; what is, is under 0.1 ms a boundary;
+# - the clock's cost is measured once (`_calibrate`), and where a reading
+#   costs more than CPU_READ_BUDGET_S only one root span in `_CPU_EVERY`
+#   reads it, with everything below that root: the others' `cpu` is None.
+#   A stock kernel reads every span.
+CPU_REUSE_S = 100e-6
+CPU_READ_BUDGET_S = 0.75e-6
+_CPU_EVERY = 0              # 0: not measured yet
+
+
+def _calibrate() -> int:
+    """Measure one reading of CPU_CLOCK (the least of seven, by CLOCK)
+    and set how many root spans share one that reads it."""
+    global _CPU_EVERY
+    cost = float("inf")
+    for _ in range(7):
+        t0 = CLOCK()
+        CPU_CLOCK()
+        cost = min(cost, CLOCK() - t0)
+    _CPU_EVERY = max(1, math.ceil(cost / CPU_READ_BUDGET_S))
+    return _CPU_EVERY
+
 
 class Stage:
     """Accounting for one stage of one pipeline. All mutators take the
     stage lock; they run a handful of times per SEGMENT/SHARD/REQUEST
     (never per cell), so the lock is uncontended noise."""
 
-    __slots__ = ("pipeline", "name", "busy_s", "stall_s", "idle_s",
-                 "items", "bytes", "queue_hwm", "_lock")
+    __slots__ = ("pipeline", "name", "busy_s", "busy_cpu_s", "stall_s",
+                 "idle_s", "items", "bytes", "queue_hwm", "_lock")
 
     def __init__(self, pipeline: str, name: str):
         self.pipeline = pipeline
         self.name = name
         self.busy_s = 0.0
+        self.busy_cpu_s = 0.0
         self.stall_s = 0.0
         self.idle_s = 0.0
         self.items = 0
@@ -76,9 +124,12 @@ class Stage:
 
     # ------------------------------------------------------------ record --
 
-    def add_busy(self, dt: float) -> None:
+    def add_busy(self, dt: float, cpu_dt: float = 0.0) -> None:
+        """`cpu_dt`: the part of `dt` the thread was on the CPU (a busy
+        span passes its own; a caller that times by hand bills none)."""
         with self._lock:
             self.busy_s += dt
+            self.busy_cpu_s += cpu_dt
 
     def add_stall(self, dt: float) -> None:
         with self._lock:
@@ -122,6 +173,7 @@ class Stage:
     def snapshot(self) -> dict:
         with self._lock:
             return {"busy_s": round(self.busy_s, 6),
+                    "busy_cpu_s": round(self.busy_cpu_s, 6),
                     "stall_s": round(self.stall_s, 6),
                     "idle_s": round(self.idle_s, 6),
                     "items": self.items, "bytes": self.bytes,
@@ -129,7 +181,8 @@ class Stage:
 
     def reset(self) -> None:
         with self._lock:
-            self.busy_s = self.stall_s = self.idle_s = 0.0
+            self.busy_s = self.busy_cpu_s = 0.0
+            self.stall_s = self.idle_s = 0.0
             self.items = self.bytes = 0
             self.queue_hwm = 0
 
@@ -138,11 +191,13 @@ class Stage:
 
 # the span ring: one bounded deque for the whole process (like the
 # ledger it survives engine close; deque.append is atomic). A record is
-# a tuple in RECORD_FIELDS order; `start`/`end` are CLOCK readings.
+# a tuple in RECORD_FIELDS order; `start`/`end` are CLOCK readings, `cpu`
+# the seconds of CPU_CLOCK between them (None for a back-dated span and
+# where the span's root did not read the clock: `_calibrate`).
 RING_CAP = 32768
 RING: collections.deque = collections.deque(maxlen=RING_CAP)
 RECORD_FIELDS = ("name", "kind", "thread", "start", "end", "id", "parent",
-                 "task", "cells", "bytes", "items")
+                 "task", "cells", "bytes", "items", "cpu")
 TRACE_PREFIX = "ctpu."
 
 _TLS = threading.local()
@@ -182,6 +237,19 @@ class task_scope:
         _TLS.task = self._prev
 
 
+def _thread_cpu(now: float) -> float:
+    """The calling thread's CPU seconds at the CLOCK reading `now`: a
+    reading of CPU_CLOCK, or the thread's last reading plus the wall
+    since where that is under CPU_REUSE_S ago (never chained: the
+    estimate does not move the anchor)."""
+    last = getattr(_TLS, "cpu_at", None)
+    if last is not None and 0.0 <= now - last[0] < CPU_REUSE_S:
+        return last[1] + (now - last[0])
+    cpu = CPU_CLOCK()
+    _TLS.cpu_at = (now, cpu)
+    return cpu
+
+
 def _annotation(name: str, thread: str, task: int):
     """A TraceAnnotation for the profiler's trace — a no-op object while
     no profiler session runs. It carries the Python thread's name and
@@ -207,11 +275,21 @@ class Span:
     attributes, which may be set while the span is open
     (`sp.nbytes = n`). `since` back-dates the start to a CLOCK reading
     taken earlier (a queue wait stamped at submit): such a span is a
-    ring record and a ledger entry, not an annotation."""
+    ring record and a ledger entry, not an annotation.
+
+    `cpu_s` is the CPU time of the OPENING thread between the same two
+    instants (children included, like `seconds`), the record's last
+    field. `seconds - cpu_s` is the time that thread was off the CPU:
+    waiting for the GIL, for a lock or a condition, for blocking I/O,
+    or for the device. CPU burnt with the GIL released (numpy, LZ4,
+    CRC) is still CPU: the split is ran / did not run. A back-dated
+    span is not thread time: its `cpu_s` is None. So is that of a span
+    under a root that did not read the clock, which happens only where
+    one reading costs more than CPU_READ_BUDGET_S (`_calibrate`)."""
 
     __slots__ = ("name", "kind", "stage", "prof", "key", "task", "cells",
-                 "nbytes", "items", "seconds", "_t0", "_ann", "_id",
-                 "_parent", "_thread")
+                 "nbytes", "items", "seconds", "cpu_s", "_t0", "_c0",
+                 "_ann", "_id", "_parent", "_thread")
 
     def __init__(self, name: str, kind: str = "busy", stage=None, *,
                  prof: dict | None = None, key: str | None = None,
@@ -221,7 +299,9 @@ class Span:
         self.prof, self.key, self.task = prof, key, task
         self.cells, self.nbytes, self.items = cells, nbytes, items
         self.seconds = 0.0
+        self.cpu_s = None
         self._t0 = since
+        self._c0 = None
 
     def __enter__(self):
         stack = getattr(_TLS, "stack", None)
@@ -241,22 +321,37 @@ class Span:
             if self._ann is not None:
                 self._ann.__enter__()
             self._t0 = CLOCK()
+            # a root decides for everything below it (see CPU_REUSE_S);
+            # the id is scrambled, or trees of N spans would resonate
+            if parent is not None:
+                reads = parent._c0 is not None
+            else:
+                reads = (self._id * 0x9E3779B1 >> 16) \
+                    % (_CPU_EVERY or _calibrate()) == 0
+            if reads:
+                self._c0 = _thread_cpu(self._t0)
         return self
 
     def __exit__(self, *exc):
         t1 = CLOCK()
+        cpu = self.cpu_s = None if self._c0 is None \
+            else max(_thread_cpu(t1) - self._c0, 0.0)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         _TLS.stack.pop()
         dt = self.seconds = t1 - self._t0
         if self.stage is not None:
-            getattr(self.stage, "add_" + self.kind)(dt)
+            if self.kind == "busy":
+                # one span in _CPU_EVERY stands for all of them
+                self.stage.add_busy(dt, (cpu or 0.0) * _CPU_EVERY)
+            else:
+                getattr(self.stage, "add_" + self.kind)(dt)
         if self.prof is not None and self.key is not None:
             with _PROF_LOCK:
                 self.prof[self.key] = self.prof.get(self.key, 0.0) + dt
         RING.append((self.name, self.kind, self._thread, self._t0, t1,
                      self._id, self._parent, self.task, int(self.cells),
-                     int(self.nbytes), int(self.items)))
+                     int(self.nbytes), int(self.items), cpu))
 
 
 def span(name: str, kind: str = "busy", **kw) -> Span:
@@ -360,6 +455,8 @@ def _register_stage_gauges(st: Stage) -> None:
     p, n = st.pipeline, st.name
     GLOBAL.register_gauge(f"pipeline.{p}.{n}.busy_s",
                           lambda: round(st.busy_s, 6))
+    GLOBAL.register_gauge(f"pipeline.{p}.{n}.busy_cpu_s",
+                          lambda: round(st.busy_cpu_s, 6))
     GLOBAL.register_gauge(f"pipeline.{p}.{n}.stall_s",
                           lambda: round(st.stall_s, 6))
     GLOBAL.register_gauge(f"pipeline.{p}.{n}.idle_s",

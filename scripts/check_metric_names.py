@@ -68,8 +68,8 @@ KNOWN_GROUPS = {
     "history",
     "index", "merge", "mesh",
     "pipeline", "prepared_statements", "profile", "reads", "request",
-    "scan", "slo", "storage", "streaming", "system", "table", "verb",
-    "writes",
+    "runtime", "scan", "slo", "storage", "streaming", "system", "table",
+    "verb", "writes",
 }
 
 

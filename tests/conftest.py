@@ -29,3 +29,14 @@ def _fresh_sstable_caches():
     from cassandra_tpu.storage import chunk_cache, key_cache
     chunk_cache.GLOBAL.clear()
     key_cache.GLOBAL.clear()
+
+
+@pytest.fixture(autouse=True)
+def _no_probe_left_by_an_earlier_test():
+    """The GIL probe (utils/gil_probe.py) beats while any engine of the
+    process is open, and writes into the one span ring. A test that left
+    an engine open would leave it beating into the rings of the tests
+    after it, which count records: each test starts with no demand."""
+    from cassandra_tpu.utils import gil_probe
+    for owner in list(gil_probe.GLOBAL._demands):
+        gil_probe.GLOBAL.set_demand(owner, False)

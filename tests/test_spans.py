@@ -263,6 +263,7 @@ DEVICE_SPANS = {
     "write.lane.cut.host_meta", "write.lane.cut.payload", "write.emit",
     "write.emit.directory", "write.emit.submit", "write.compress",
     "compress_pool.pack", "write.io"}
+PROBE_SPAN = "runtime.gil.handoff"
 CUT_PARTS = {"write.lane.cut.slice", "write.lane.cut.pull_lanes",
              "write.lane.cut.kernel_dispatch", "write.lane.cut.kernel_pull",
              "write.lane.cut.host_meta", "write.lane.cut.payload"}
@@ -311,6 +312,13 @@ def test_device_compaction_yields_every_span_of_the_catalogue(
              if r["task"] == 0 and r["thread"] in ("compact-w", "sstable-io")
              and root["start"] <= r["start"] <= root["end"]]
     assert not stray, stray[:3]
+    # the catalogue's one span that belongs to no task: the GIL probe
+    # beats for the length of the task, on its own thread (PR 35)
+    beats = [r for r in device_compaction["all"] if r["name"] == PROBE_SPAN]
+    assert beats and all(
+        r["kind"] == "stall" and r["thread"] == "gil-probe"
+        and r["task"] == 0 and r["cpu"] is not None
+        and root["start"] <= r["start"] <= root["end"] for r in beats)
 
 
 def test_task_id_reaches_the_write_lane_the_pool_and_the_io_thread(
@@ -376,6 +384,10 @@ def test_granularity_is_bounded_by_count_not_by_time(device_compaction):
     assert device_compaction["stats"]["cells_read"] > 250_000
     assert len(recs) <= 8 * rounds + 12 * segments + 2 * jobs \
         + fetches + 8
+    # the probe is bounded by time, ten a second, whatever the work
+    root = device_compaction["root"]
+    beats = [r for r in device_compaction["all"] if r["name"] == PROBE_SPAN]
+    assert len(beats) <= 1 + 10 * (root["end"] - root["start"])
     # the attributes that feed the benchmark's readers
     pack = [r for r in recs if r["name"] == "merge.resident.pack"]
     assert all(r["items"] >= r["cells"] > 0 and r["bytes"] > 0
