@@ -6,7 +6,13 @@ data accelerator-resident across decode → merge → pack instead of
 round-tripping the host per stage. This module is that mode for the
 device merge engine: one fused program per round runs the LSD sort, the
 reconcile/purge masks AND the kept-cell compaction (stable partition +
-column gather) on the device, so the CellBatch's fixed-width columns
+column gather) on the device. Outside the sort's passes the program
+holds TWO gathers, both of rows (ops/merge.py `gather_rows`: a gather is
+paid per index — 5.3 ms a lane, 5.8 ms a 26-wide row at 2^19 — and the
+twenty this program once held were 156 of a round's 194 ms): every column
+reconcile or the serializer reads, through the sort's permutation; then
+the serialize-side columns, that permutation and the expired bit, through
+the kept-first order. So the CellBatch's fixed-width columns
 (lanes / ts / ldt / ttl / flags / frame offsets) never come back to the
 host as columns. They stay resident in a device-side pending buffer
 across rounds; segment cuts slice them on-device; and a second fused
@@ -118,6 +124,9 @@ def build_resident_operands(cat: CellBatch, gc_before: int, now: int,
 
 RESIDENT_COLS = ("lanes", "ts_h", "ts_l", "ldt", "ttl", "flags8",
                  "fl", "vr")
+# those of them reconcile does not read: they ride its row gather
+_SERIALIZE_ONLY = tuple(k for k in RESIDENT_COLS
+                        if k not in ("lanes",) + dmerge.RECONCILE_WORDS)
 
 
 @jax.jit
@@ -134,7 +143,11 @@ def _resident_program(operands):
     with jax.named_scope("sort"):
         perm, n_passes = dmerge.device_sort_perm(operands)
     with jax.named_scope("reconcile"):   # its purge stage names itself
-        packed = dmerge.reconcile_kernel(operands, perm)
+        # ONE row gather through perm carries what reconcile reads AND
+        # the serialize-side columns the next stage takes from it
+        s = dmerge.reconcile_columns(operands, perm, also=_SERIALIZE_ONLY)
+        packed = dmerge.reconcile_sorted(s, operands["now"],
+                                         operands["gc_before"])
     with jax.named_scope("compact"):
         keep = (packed & 1) != 0
         amb = (packed & 2) != 0
@@ -149,14 +162,17 @@ def _resident_program(operands):
         _, ord_ = jax.lax.sort(
             (jnp.where(keep, jnp.uint32(0), jnp.uint32(1)),
              jnp.arange(N, dtype=jnp.int32)), num_keys=1, is_stable=True)
-        perm_out = perm[ord_]
-        cols = {k: operands[k][perm_out] for k in RESIDENT_COLS}
+        # the second and last row gather: the sorted columns, perm and
+        # the expired bit through ord_ (s[ord_] is operands[perm[ord_]])
+        cols = dmerge.gather_rows(
+            {**{k: s[k] for k in RESIDENT_COLS},
+             "perm": perm, "expired": expired}, ord_)
+        perm_out, exp_out = cols.pop("perm"), cols.pop("expired")
     with jax.named_scope("convert"):
         # an expired cell that is kept becomes a tombstone without its
         # value (finalize_merged's flags |= FLAG_TOMBSTONE + drop_values):
         # the value is the frame's tail, so the frame shrinks to its
         # header; ldt and ttl stay
-        exp_out = expired[ord_]
         cols["flags8"] = jnp.where(
             exp_out, cols["flags8"] | jnp.uint8(FLAG_TOMBSTONE),
             cols["flags8"])

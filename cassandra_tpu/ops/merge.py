@@ -27,6 +27,19 @@ larger-value-wins at equal timestamps (db/rows/Cells.java:68) — are
 resolved on the host for the rare flagged runs, exactly, with full value
 bytes.
 
+Columns travel as ROWS (the second load-bearing TPU decision): a gather
+on this chip is paid per INDEX, not per byte — at 2^19 indices a one-lane
+gather from HBM takes 5.3 ms, a 13-wide row gather 4.1 and a 26-wide one
+5.8; with twenty gathers outside the passes a round took 194.3 ms, with
+two it takes 44.9 (PERF.md §6, PR 36) — so nothing outside the passes
+gathers a single lane. The per-cell columns are stacked beside `lanes`
+into ONE uint32 matrix, its rows are gathered once through the sort's
+permutation, and reconcile works on column slices of the result
+(`gather_rows`, `reconcile_columns`). int32 columns ride by bitcast, uint8
+and bool widened and narrowed again, and reconcile's four yes/no columns
+share one word, which keeps a round of 2^20 x 13 within the 24 words a
+row gather moves at full speed there. A gather is exact.
+
 Outputs are a permutation + keep mask; the host applies them to the
 variable-length payload with numpy gathers (storage/cellbatch.py).
 Shapes are padded to buckets so programs are traced once per bucket size.
@@ -153,26 +166,67 @@ def unpack_masks(packed: np.ndarray):
             (packed & 4).astype(bool), (packed & 8).astype(bool))
 
 
-def _reconcile_core(lanes, ts_h, ts_l, valid, ldt, expiring, is_cd,
-                    death, purge_h, purge_l, now, gc_before, perm):
-    """Reconcile over a sort permutation; all arrays UNSORTED (gathered
-    through perm here). Returns ONE packed uint8 mask array aligned to
-    SORTED order (bit0=keep, bit1=ambiguous, bit2=expired, bit3=shadowed —
-    decode with unpack_masks). One small transfer instead of four bools.
+# what reconcile reads of a cell beside `lanes`: five whole words ...
+RECONCILE_WORDS = ("ts_h", "ts_l", "ldt", "purge_h", "purge_l")
+# ... and four yes/no answers, each an operand column of 0s and 1s and
+# the value that means yes; they travel as the low bits of ONE word
+_PREDICATES = {"live": ("valid", 0), "expiring": ("expiring", 1),
+               "is_cd": ("cdel", 1), "death": ("death", 1)}
+
+
+def _to_u32(a):
+    """A column of at most 32 bits as uint32 words, exactly: int32 by
+    bitcast, uint8 and bool widened."""
+    if a.dtype == jnp.int32:
+        return jax.lax.bitcast_convert_type(a, jnp.uint32)
+    return a.astype(jnp.uint32)
+
+
+def _from_u32(w, dtype):
+    """_to_u32's inverse."""
+    if dtype == jnp.int32:
+        return jax.lax.bitcast_convert_type(w, jnp.int32)
+    if dtype == jnp.bool_:
+        return w != 0
+    return w.astype(dtype)    # uint8: only the zeros it was widened by go
+
+
+def gather_rows(cols: dict, idx) -> dict:
+    """{name: a[idx]} for every column of `cols` — (N,) or (N, k), at
+    most 32 bits an element — with ONE gather: the columns are stacked
+    side by side into one uint32 matrix, its ROWS are gathered, and the
+    columns are sliced back out in their own dtypes. A gather on the TPU
+    is paid per index, not per byte (the module header has the figures),
+    so every column after the first travels free — up to a size found on
+    the chip, not from bytes: at 2^20 rows a 24-wide matrix gathers in
+    9.3 ms and a 25-wide one in 39.6 (PERF.md §7). A gather is exact: the
+    same elements as the separate gathers give."""
+    wide = [_to_u32(a).reshape(a.shape[0], -1) for a in cols.values()]
+    rows = jnp.concatenate(wide, axis=1)[idx]
+    out, at = {}, 0
+    for (name, a), w in zip(cols.items(), wide):
+        part = rows[:, at:at + w.shape[1]]
+        out[name] = _from_u32(part.reshape(idx.shape + a.shape[1:]), a.dtype)
+        at += w.shape[1]
+    return out
+
+
+def reconcile_sorted(s, now, gc_before):
+    """Reconcile over columns ALREADY in sorted order (`s`: what
+    reconcile_columns returns). Returns ONE packed uint8 mask array
+    aligned to SORTED order (bit0=keep, bit1=ambiguous, bit2=expired,
+    bit3=shadowed — decode with unpack_masks). One small transfer
+    instead of four bools.
 
     ambiguous marks records whose (identity, ts) equal the previous sorted
     record — the host picks the winner there with death/value tie-break
     rules (the device sort does not order by them)."""
-    lanes = lanes[perm]
+    lanes = s["lanes"]
     N, K = lanes.shape
-    g = lambda a: a[perm]
-    ts_h, ts_l = g(ts_h), g(ts_l)
-    valid = g(valid) == 0
-    ldt = g(ldt)
-    expiring = g(expiring) == 1
-    is_cd = g(is_cd) == 1
-    purge_h, purge_l = g(purge_h), g(purge_l)
-    death = g(death) == 1
+    ts_h, ts_l, ldt = s["ts_h"], s["ts_l"], s["ldt"]
+    purge_h, purge_l = s["purge_h"], s["purge_l"]
+    valid, expiring = s["live"], s["expiring"]
+    is_cd, death = s["is_cd"], s["death"]
 
     # ---- boundaries
     prev = jnp.concatenate([jnp.full((1, K), 0xFFFFFFFF, dtype=jnp.uint32),
@@ -237,14 +291,30 @@ def _reconcile_core(lanes, ts_h, ts_l, valid, ldt, expiring, is_cd,
     return packed
 
 
+def reconcile_columns(operands, perm, also=()) -> dict:
+    """In sorted order — one row gather through `perm` (gather_rows) —
+    `lanes`, RECONCILE_WORDS, the columns named in `also`, and the four
+    predicates as bools under _PREDICATES' names. The predicates share a
+    word: at 13 lanes with the write lane's four columns the matrix is
+    23 wide, within the 24 a round of 2^20 gathers at full speed."""
+    cols = {k: operands[k]
+            for k in ("lanes",) + RECONCILE_WORDS + tuple(also)}
+    cols["bits"] = sum(
+        (operands[column] == yes).astype(jnp.uint32) << i
+        for i, (column, yes) in enumerate(_PREDICATES.values()))
+    s = gather_rows(cols, perm)
+    bits = s.pop("bits")
+    for i, name in enumerate(_PREDICATES):
+        s[name] = ((bits >> i) & 1) != 0
+    return s
+
+
 @jax.jit
 def reconcile_kernel(operands, perm):
-    """Dict-operand form (driver entry / shard_map body)."""
-    return _reconcile_core(
-        operands["lanes"], operands["ts_h"], operands["ts_l"],
-        operands["valid"], operands["ldt"], operands["expiring"],
-        operands["cdel"], operands["death"], operands["purge_h"],
-        operands["purge_l"], operands["now"], operands["gc_before"], perm)
+    """Dict-operand form (driver entry / shard_map body); operands
+    UNSORTED, moved through perm here."""
+    return reconcile_sorted(reconcile_columns(operands, perm),
+                            operands["now"], operands["gc_before"])
 
 
 # dual-use like _lsd_pass: host entry ("merge.reconcile") or traced body

@@ -4,7 +4,15 @@ program returns must be the one the sixteen unconditional passes give,
 element for element — padding rows included — and the pass count it
 returns must be the one the round's own lanes dictate. The reference is
 kept here: `np.lexsort` over the same keys, the kept-cell compaction and
-the expired -> tombstone conversion in plain numpy."""
+the expired -> tombstone conversion in plain numpy.
+
+Since PR 36 the columns move through the sort's permutation as ROWS of
+one uint32 matrix a stage (ops/merge.py `gather_rows`): the cases below
+the first six hold what a packing could break — a flags byte with its
+top bit set, negative int32 columns, all-ones words, indices past 2^16,
+a round the host must tie-break, tables of other widths — and the last
+test counts the traced program's gathers."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -97,9 +105,69 @@ def ttl_round(rng):
             now - 10 * 86400, now, 4)     # valid, pk, ck, ~ts_l
 
 
+# ---- what the row packing could break (PR 36)
+
+def top_bits_and_negative_columns(rng):
+    """flags8 with bit 7 set must come back a uint8 with bit 7 set,
+    ldt/ttl below zero must come back the same int32, and a lane, a
+    frame length and a value offset of 0xFFFFFFFF the same words."""
+    n = 683
+    lanes, ts = _standard1(n, rng)
+    lanes[:, 7] = 0xFFFFFFFF                   # a constant lane of ones
+    lanes[::5, 8] = 0xFFFFFFFF                 # and one that varies
+    flags = np.where(np.arange(n) % 3 == 0, 0x80 | cb.FLAG_EXPIRING,
+                     0x80 | cb.FLAG_ROW_LIVENESS)
+    ldt = rng.integers(-(1 << 31), 1 << 31, n)
+    ttl = rng.integers(-(1 << 31), 0, n)
+    cat = _batch(lanes, ts, flags=flags, ldt=ldt, ttl=ttl)
+    # one frame of 0xFFFFFFFF bytes whose value starts at its last byte
+    # but one (the payload is never read by the program)
+    cat.off = cat.off.copy()
+    cat.off[101:] += 0xFFFFFFFF - 8
+    cat.val_start = cat.off[:-1] + 2
+    cat.val_start[100] = cat.off[100] + 0xFFFFFFFE
+    cat.val_start[200] = cat.off[200] + 8      # an empty value: vr == fl
+    # a third of the cells expire; those whose ldt lies inside grace,
+    # negative ones among them, are kept and converted
+    return cat, -(1 << 30), 1 << 30, 8   # valid, 4 + lane 8 + column, ~ts_l
+
+
+def indices_past_two_to_the_sixteenth(rng):
+    lanes, ts = _standard1(70_000, rng)       # bucket 131,072
+    return _batch(lanes, ts), 0, 0, 7
+
+
+def equal_identity_and_timestamp(rng):
+    """Three copies of every cell at ONE timestamp with three values:
+    n_amb > 0, the round the host tie-breaks (larger value wins)."""
+    ids = 120
+    lanes, _ = _standard1(ids, rng)
+    lanes = np.tile(lanes, (3, 1))
+    ts = np.tile(TS0 + np.arange(ids) // 7, 3)
+    cat = _batch(lanes, ts)
+    cat.payload[np.arange(3 * ids) * 8 + 7] = rng.permutation(3 * ids) % 251
+    return cat, 0, 0, 7
+
+
+def _narrow(k):
+    def table(rng):
+        n = 500
+        lanes = np.zeros((n, k), dtype=np.uint32)
+        lanes[:, :4] = rng.integers(0, 1 << 32, (n // 2, 4),
+                                    dtype=np.uint32).repeat(2, axis=0)
+        lanes[:, k - 3] = rng.integers(16, 21, n)
+        ts = TS0 + rng.integers(0, 3_600_000_000, n)
+        passes = 1 + len(set(range(4)) | {k - 3}) + 1
+        return _batch(lanes, ts), 0, 0, passes
+    table.__name__ = f"table_of_{k}_lanes"
+    return table
+
+
 CASES = [no_constant_lane, eight_constant_lanes_a_third_padding,
          constant_at_a_value_the_padding_does_not_hold,
-         one_valid_cell_differs, one_valid_cell, ttl_round]
+         one_valid_cell_differs, one_valid_cell, ttl_round,
+         top_bits_and_negative_columns, indices_past_two_to_the_sixteenth,
+         equal_identity_and_timestamp, _narrow(5), _narrow(9)]
 
 
 def _reference(operands):
@@ -130,7 +198,8 @@ def _reference(operands):
 def test_every_array_is_the_unconditional_passes(case):
     cat, gc_before, now, want_passes = case(np.random.default_rng(34))
     operands, _ = dw.build_resident_operands(cat, gc_before, now, None)
-    assert len(dmerge._sort_keys(operands)) == dmerge.n_sort_keys(K) == 16
+    assert (len(dmerge._sort_keys(operands))
+            == dmerge.n_sort_keys(cat.n_lanes) == cat.n_lanes + 3)
     got = dw._resident_program(operands)
     want = _reference(operands)
     assert [int(x) for x in got[:4]] == list(want[:4])
@@ -142,6 +211,52 @@ def test_every_array_is_the_unconditional_passes(case):
                                       want[5][name], name)
     np.testing.assert_array_equal(np.asarray(got[6]), want[6], "perm")
     np.testing.assert_array_equal(np.asarray(got[7]), want[7], "packed")
+    for name in dw.RESIDENT_COLS:
+        assert got[5][name].dtype == operands[name].dtype, name
+    if want[1]:
+        # the host's turn: the same program's perm and masks through
+        # `_materialize` give what the numpy spec gives
+        merged = dw.materialize_round(
+            dw.submit_merge_resident([cat], gc_before, now))
+        spec = cb.merge_sorted([cat], gc_before, now)
+        for f in ("lanes", "ts", "ldt", "ttl", "flags", "off",
+                  "val_start", "payload"):
+            np.testing.assert_array_equal(getattr(merged, f),
+                                          getattr(spec, f), f)
+
+
+def _gathers(jaxpr, inside=False, out=None):
+    """(inside `_lsd_pass`?, the operand's shape) of every gather of a
+    traced program, through its nested jits and cond branches."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out.append((inside, eqn.invars[0].aval.shape))
+        here = inside or eqn.params.get("name") == "_lsd_pass"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _gathers(sub, here, out)
+    return out
+
+
+def test_columns_travel_as_rows_outside_the_passes():
+    """A gather is paid per index on the chip: beside the sixteen
+    one-lane gathers of the passes (a key through perm) the program
+    holds at most three, none of them of a one-dimensional operand
+    (before PR 36: twenty, eighteen of them one-lane)."""
+    cat, gc_before, now, _ = ttl_round(None)
+    operands, _ = dw.build_resident_operands(cat, gc_before, now, None)
+    found = _gathers(jax.make_jaxpr(dw._resident_program)(operands).jaxpr)
+    passes = [shape for inside, shape in found if inside]
+    outside = [shape for inside, shape in found if not inside]
+    assert passes == [(1024,)] * 16
+    assert 1 <= len(outside) <= 3, outside
+    assert all(len(shape) == 2 for shape in outside), outside
+    # at 13 lanes both stacked matrices stay within 24 words: at 2^20
+    # rows a 25-wide one gathers four times slower on the chip (PERF.md)
+    assert sorted(shape[1] for shape in outside) == [22, 23]
 
 
 def test_the_ttl_round_keeps_and_converts_what_the_host_spec_does():

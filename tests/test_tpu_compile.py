@@ -33,9 +33,17 @@ ANN_ROWS, ANN_DIM = 100_000, 128
 # (program, compile seconds, generated code bytes, temp bytes) — printed
 # with `pytest -s`; CHANGES.md's compile-rehearsal table comes from it
 REPORT: list = []
-# compile seconds an earlier rehearsal printed, shown beside the new ones:
-# merge.resident with its sixteen passes unconditional (PR 21)
-BEFORE = {"merge.resident": 36.3}
+# what the rehearsal printed at PR 36's parent, shown beside the new
+# figures: (compile seconds, code MB, temp MB) with the twenty separate
+# gathers outside the passes (eighteen of them one-lane)
+BEFORE = {"merge.resident": (36.1, 171.6, 36.1),
+          "merge.sharded_step": (26.5, 76.8, 89.2)}
+# merge.resident's temporaries at 2^19 x 13 as the compiler counts them:
+# 24.1 MB with the two stacked matrices 23 and 22 words wide (PR 36's
+# step 0, here and on the chip's host; the parent 36.1). At 26 words the
+# same program took 73.8 MB and, at 2^20, a row gather four times slower:
+# a stacked matrix wider than 24 words passes this.
+RESIDENT_TEMP_LIMIT_MB = 48
 
 
 @pytest.fixture(scope="module")
@@ -56,7 +64,8 @@ def topo():
     for row in REPORT:
         before = BEFORE.get(row[0])
         print("tpu-compile %-24s %6.1f s  code %5.1f MB  temp %6.1f MB"
-              % row + ("  (was %.1f s)" % before if before else ""))
+              % row + ("  (was %.1f s, %.1f MB, %.1f MB)" % before
+                       if before else ""))
 
 
 @pytest.fixture(scope="module")
@@ -107,6 +116,9 @@ def test_resident_round(one_chip):
     assert compiled.as_text().count(" conditional(") == LANES + 2
     n_passes = compiled.out_info[3]
     assert n_passes.shape == () and n_passes.dtype == jnp.int32
+    # the columns travel as rows of two stacked matrices: what those cost
+    temp_mb = compiled.memory_analysis().temp_size_in_bytes / 2**20
+    assert temp_mb < RESIDENT_TEMP_LIMIT_MB, temp_mb
 
 
 def test_meta_block_segment(one_chip):
