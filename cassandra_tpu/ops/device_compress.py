@@ -11,8 +11,9 @@ set (ties → smallest distance), accept iff ≥ MINMATCH, else advance
 one byte. A hash-table matcher's output depends on probe/insertion
 order, which a data-parallel scan cannot replay; the policy's argmax
 is order-free and maps to one vectorized shifted-equality pass per
-candidate distance — a single fused jax program over the device
-pending buffer (lane shuffle + order check + both match scans).
+candidate distance — a single fused jax program over a full
+segment's device-resident blocks (lane shuffle + order check + both
+match scans).
 
 The LZ4 wire emission (greedy parse + token stream) is inherently
 sequential but cheap — O(emitted sequences), not O(bytes × distances)

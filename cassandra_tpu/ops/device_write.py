@@ -14,15 +14,20 @@ reconcile or the serializer reads, through the sort's permutation; then
 the serialize-side columns, that permutation and the expired bit, through
 the kept-first order. So the CellBatch's fixed-width columns
 (lanes / ts / ldt / ttl / flags / frame offsets) never come back to the
-host as columns. They stay resident in a device-side pending buffer
-across rounds; segment cuts slice them on-device; and a second fused
-kernel serializes each full segment's META block (including the "ce"
+host as columns. The write lane (`DeviceWriteLane`) runs two more
+jitted programs, and nothing eager: per round `_lane_append` lays the
+round behind the device-resident carry of cells earlier rounds left
+over and cuts the full segments out of the pair; per full segment
+`_meta_block_kernel` serializes its META block (including the "ce"
 ts-delta pre-transform, format.py) byte-identically to the host
-serializer (storage/sstable/writer.py build_meta_block). The host
+serializer (storage/sstable/writer.py build_meta_block). The offsets
+are traced, so the shapes depend on the round's bucket, the lanes and
+the segment size, never on how many cells a round kept. The host
 receives only the FINISHED blocks the compress pool consumes — the
-META bytes and the row-major LANES matrix `segment_pack` wants — plus
-the variable-length payload, which never went to the device (ragged
-bytes gather through the native C++ path, storage/cellbatch.py).
+META bytes and the row-major LANES matrix `segment_pack` wants, in one
+pull a segment — plus the variable-length payload, which never went to
+the device (ragged bytes gather through the native C++ path,
+storage/cellbatch.py).
 
 This is the ONE jitted merge program compaction dispatches
 (`_resident_program`, kernel name `merge.resident`), and this module owns
@@ -57,6 +62,7 @@ program; the host's payload gather then copies the shortened frames
 from __future__ import annotations
 
 import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -183,12 +189,45 @@ def _resident_program(operands):
 # ------------------------------------------------------ serialize kernel --
 
 @jax.jit
+def _lane_append(carry, cols, rem, n):
+    """The write lane's one program a round. `carry` holds the cells
+    left over from earlier rounds, right-aligned: its last `rem` rows
+    (rem < seg, its row count). `cols` is the round, kept cells first
+    and padded to its bucket N. Behind the carry the round's first `n`
+    cells continue the stream, so the pair holds (rem + n) // seg full
+    segments from row seg - rem on.
+
+    Returns (segments, carry): (seg - 1 + N) // seg column sets of seg
+    rows each, the j-th starting at row seg - rem + j·seg (the ones past
+    the full count are never read), and the next carry, whose last
+    (rem + n) mod seg rows are what is left. Offsets are traced, so the
+    shapes depend on N, the lanes and seg alone."""
+    seg = carry["lanes"].shape[0]
+    N = cols["lanes"].shape[0]
+    both = {k: jnp.concatenate([carry[k], cols[k]])
+            for k in RESIDENT_COLS}
+
+    def window(start):
+        return {k: jax.lax.dynamic_slice_in_dim(v, start, seg)
+                for k, v in both.items()}
+
+    segments = [window(seg - rem + j * seg)
+                for j in range((seg - 1 + N) // seg)]
+    return segments, window(n)
+
+
+# the columns `_meta_block_kernel` reads, in its argument order
+_META_COLS = ("ts_h", "ts_l", "ldt", "ttl", "flags8", "fl", "vr")
+
+
+@jax.jit
 def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
     """Fused META-block serialize for one FULL segment: the "ce"
     ts-delta pre-transform + the 25 B/cell section layout emitted as
-    one u8 buffer, plus the segment's stats reductions — all in a
-    single device program, byte-identical to the host
-    build_meta_block (pinned by test).
+    one u8 buffer, plus the segment's stats reductions packed into one
+    u32 vector (`_segment_stats` reads it) — all in a single device
+    program, byte-identical to the host build_meta_block (pinned by
+    test).
 
     ts planes arrive BIASED (uts = ts + 2^63 mod 2^64, the sort form);
     bias cancels in differences, so the wraparound deltas of the u32
@@ -240,14 +279,25 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
         min_l = jnp.min(jnp.where(ts_h == min_h, ts_l,
                                   _U32(0xFFFFFFFF)))
         tombs = jnp.sum((flags8 & jnp.uint8(DEATH_FLAGS)) != 0)
-        stats = (min_h, min_l, max_h, max_l,
-                 jnp.min(ldt), jnp.max(ldt), tombs)
+        stats = jnp.stack([
+            min_h, min_l, max_h, max_l,
+            jax.lax.bitcast_convert_type(jnp.min(ldt), jnp.uint32),
+            jax.lax.bitcast_convert_type(jnp.max(ldt), jnp.uint32),
+            tombs.astype(jnp.uint32)])
     return meta, stats
 
 
 def _uts_pair_to_i64(h: int, l: int) -> int:
     return int(np.int64(np.uint64((int(h) << 32) | int(l))
                         ^ np.uint64(1 << 63)))
+
+
+def _segment_stats(st: np.ndarray) -> tuple:
+    """The kernel's pulled stats vector as the writer's (min ts, max ts,
+    min ldt, max ldt, tombstones)."""
+    ldt = st[4:6].view(np.int32)
+    return (_uts_pair_to_i64(st[0], st[1]), _uts_pair_to_i64(st[2], st[3]),
+            int(ldt[0]), int(ldt[1]), int(st[6]))
 
 
 # --------------------------------------------------------------- rounds --
@@ -389,11 +439,9 @@ def collect_merge_resident(h: ResidentHandle):
     """Block on a resident round. Returns a DeviceRound (columns still
     on device) for rounds the device reproduced exactly, else a host
     CellBatch computed through the pinned byte-identical fallback."""
-    import time as _time
-
     global _collect_seq
     if _TEST_COLLECT_DELAY is not None:
-        _time.sleep(_TEST_COLLECT_DELAY.get(_collect_seq, 0.0))
+        time.sleep(_TEST_COLLECT_DELAY.get(_collect_seq, 0.0))
     _collect_seq += 1
     if h.mode == "done":
         return promote_round(h.result)
@@ -481,8 +529,11 @@ def promote_round(batch: CellBatch) -> DeviceRound:
     ordered stream — interleaving host appends with device-pending
     cells would cut segments out of order. Values are
     copied verbatim, so the serialized bytes are identical to feeding
-    the batch through the host writer."""
+    the batch through the host writer. The columns are padded to the
+    round's bucket (merge._bucket), as the program's own are, so the
+    lane compiles per bucket and not per length."""
     n = len(batch)
+    N = dmerge._bucket(n)
     lens64 = batch.off[1:] - batch.off[:-1]
     vrel64 = batch.val_start - batch.off[:-1]
     if n and (int(lens64.max()) >= 1 << 32
@@ -493,16 +544,20 @@ def promote_round(batch: CellBatch) -> DeviceRound:
             f"(max frame {int(lens64.max())} bytes)")
     with np.errstate(over="ignore"):
         uts = batch.ts.astype(np.uint64) ^ np.uint64(1 << 63)
+
+    def padded(a, dtype):
+        out = np.zeros((N,) + a.shape[1:], dtype=dtype)
+        out[:n] = a
+        return jnp.asarray(out)
     cols = {
-        "lanes": jnp.asarray(np.ascontiguousarray(batch.lanes)),
-        "ts_h": jnp.asarray((uts >> np.uint64(32)).astype(np.uint32)),
-        "ts_l": jnp.asarray((uts & np.uint64(0xFFFFFFFF))
-                            .astype(np.uint32)),
-        "ldt": jnp.asarray(batch.ldt.astype(np.int32, copy=False)),
-        "ttl": jnp.asarray(batch.ttl.astype(np.int32, copy=False)),
-        "flags8": jnp.asarray(batch.flags.astype(np.uint8, copy=False)),
-        "fl": jnp.asarray(lens64.astype(np.uint32)),
-        "vr": jnp.asarray(vrel64.astype(np.uint32)),
+        "lanes": padded(batch.lanes, np.uint32),
+        "ts_h": padded(uts >> np.uint64(32), np.uint32),
+        "ts_l": padded(uts & np.uint64(0xFFFFFFFF), np.uint32),
+        "ldt": padded(batch.ldt, np.int32),
+        "ttl": padded(batch.ttl, np.int32),
+        "flags8": padded(batch.flags, np.uint8),
+        "fl": padded(lens64, np.uint32),
+        "vr": padded(vrel64, np.uint32),
     }
     return DeviceRound(n, cols, np.asarray(batch.payload),
                        np.asarray(batch.off, dtype=np.int64),
@@ -541,57 +596,94 @@ def _gather_payload(cat: CellBatch, perm: np.ndarray,
 # ----------------------------------------------------------- write lane --
 
 class DeviceWriteLane:
-    """The device-resident write stage: accumulates rounds' columns in
-    a device pending buffer, cuts segments on-device, serializes each
-    full segment's META block with the fused kernel and hands the
-    writer only finished blocks (writer._emit_segment — the exact tail
-    the host path runs after its own serialize). The final partial
-    segment assembles through the host build_meta_block on pulled
-    column slices: one segment per output, and bit-equality with the
-    kernel is the pinned contract, not an optimization target."""
+    """The device-resident write stage. A round's columns never come back
+    to the host as columns, and no program runs at a shape the data
+    chooses: each round is ONE dispatch of `_lane_append` (registry
+    `write.append`), which lays the round behind the carry — the cells
+    earlier rounds left over, fewer than a segment, kept on the device —
+    and returns every full segment's columns and the next carry. Each
+    full segment is then ONE dispatch of `_meta_block_kernel`
+    (`write.serialize`, the fused META serialize + stats), all of a
+    round's dispatched before the first is pulled, and ONE pull of its
+    block, stats and row-major lanes; the writer gets only finished
+    blocks (writer._emit_segment — the exact tail the host path runs
+    after its own serialize). The final partial segment is the carry
+    itself, pulled whole and trimmed on the host, and assembles through
+    the host build_meta_block: one segment per output, and bit-equality
+    with the kernel is the pinned contract, not an optimization target.
+    Counters: `write.lane.dispatches` (one a round plus one a full
+    segment) and `write.lane.segments` (every segment cut)."""
 
     def __init__(self, writer):
         from ..storage.sstable.format import SEGMENT_CELLS
         self.writer = writer
         self.seg_cells = writer.segment_cells or SEGMENT_CELLS
-        self.cols: dict | None = None     # device pending columns
-        self.pending = 0                  # valid cells in self.cols
+        self.carry: dict | None = None    # device columns, seg_cells rows
+        self.pending = 0                  # cells at the carry's end
         self.payloads: list = []          # (payload, off, val_start)
         self.payload_cells = 0
         self.pk_map: dict = {}
 
     def append(self, r: DeviceRound) -> None:
-        w = self.writer
+        w, seg = self.writer, self.seg_cells
         with w._span("serialize", "busy", "write.lane.append",
                      key="serialize", cells=r.n) as sp:
+            N, K = (int(d) for d in r.cols["lanes"].shape)
             if w.K is None:
-                w.K = int(r.cols["lanes"].shape[1])
+                w.K = K
             w._ck_fits = w._ck_fits and r.ck_fits_prefix
-            take = {k: v[:r.n] for k, v in r.cols.items()}
-            sp.items = len(take)       # eager programs dispatched
-            if self.cols is None or self.pending == 0:
-                self.cols = take
-            else:
-                self.cols = {k: jnp.concatenate(
-                    [self.cols[k][:self.pending], take[k]])
-                    for k in RESIDENT_COLS}
-                sp.items += 2 * len(RESIDENT_COLS)
-            self.pending += r.n
+            if self.carry is None:
+                # an empty carry shaped like the round's columns: a plain
+                # transfer, no program
+                self.carry = {k: jax.device_put(
+                    np.zeros((seg,) + v.shape[1:], dtype=v.dtype))
+                    for k, v in r.cols.items()}
+            args = (self.carry, r.cols, np.int32(self.pending),
+                    np.int32(r.n))
+            t0 = time.perf_counter()
+            segments, self.carry = _lane_append(*args)
+            if _kprof.record_dispatch("write.append", (N, K, seg),
+                                      time.perf_counter() - t0):
+                _kprof.maybe_record_cost("write.append", _lane_append,
+                                         args)
+            full, self.pending = divmod(self.pending + r.n, seg)
+            cuts = [(s, self._dispatch_cut(s)) for s in segments[:full]]
+            # items = programs dispatched: this round's, one per full
+            # segment it completed
+            sp.items = 1 + full
+            _METRICS.incr("write.lane.dispatches", sp.items)
+        # the host's side of the round: its payload frames queued, its
+        # partition keys merged (one C-level update: a round of 2^19
+        # cells brings ≈ 100,000 keys)
+        with w._span("serialize", "busy", "write.lane.keys",
+                     key="serialize", cells=r.n) as sp:
             self.payloads.append((r.payload, r.off, r.val_start))
             self.payload_cells += r.n
-            for k, v in r.pk_map.items():
-                self.pk_map[k] = v
-        while self.pending >= self.seg_cells:
-            self._cut(self.seg_cells)
+            self.pk_map.update(r.pk_map)
+            sp.items = len(r.pk_map)
+        for s, out in cuts:
+            self._cut(seg, s, out)
 
     def flush(self) -> None:
-        """Cut everything left (the final partial segment) — the
+        """Cut what is left (the final partial segment) — the
         device-mode analog of finish()'s pending drain; call before
         writer.finish()/roll."""
-        while self.pending >= self.seg_cells:
-            self._cut(self.seg_cells)
         if self.pending:
             self._cut(self.pending)
+            self.pending = 0
+
+    @staticmethod
+    def _dispatch_cut(s: dict):
+        """Dispatch one full segment's serialize (async)."""
+        kargs = tuple(s[k] for k in _META_COLS)
+        t0 = time.perf_counter()
+        out = _meta_block_kernel(*kargs)
+        if _kprof.record_dispatch("write.serialize",
+                                  (int(kargs[0].shape[0]),),
+                                  time.perf_counter() - t0):
+            _kprof.maybe_record_cost("write.serialize", _meta_block_kernel,
+                                     kargs)
+        return out
 
     # ------------------------------------------------------------ internals
 
@@ -621,40 +713,40 @@ class DeviceWriteLane:
         return np.concatenate([payload[:int(off[-1])]
                                for payload, off, _vs in outs])
 
-    def _cut(self, n: int) -> None:
+    def _cut(self, n: int, seg: dict | None = None, out=None) -> None:
+        """Hand one segment to the writer: a full one (`seg`, its device
+        columns, and `out`, its dispatched serialize) or, without them,
+        the final partial one — the last n rows of the carry."""
         w = self.writer
         span = pipeline_ledger.span
-        full = n == self.seg_cells
+        full = out is not None
+        _METRICS.incr("write.lane.segments")
         # one `serialize` span per segment; its parts are children that
         # bill nothing themselves (ring + trace only)
         with w._span("serialize", "busy", "write.lane.cut",
                      key="serialize", cells=n):
-            with span("write.lane.cut.slice",
-                      items=2 * len(RESIDENT_COLS)):
-                seg = {k: self.cols[k][:n] for k in RESIDENT_COLS}
-                self.cols = {k: self.cols[k][n:] for k in RESIDENT_COLS}
-                self.pending -= n
-            with span("write.lane.cut.pull_lanes") as sp:
-                lanes_np = np.ascontiguousarray(np.asarray(seg["lanes"]))
-                sp.nbytes = lanes_np.nbytes
+            # the handoff: the program was dispatched at append time
+            with span("write.lane.cut.slice"):
+                if full:
+                    meta_d, stats_d = out
+                    lanes_d = seg["lanes"]
+                else:
+                    carry = self.carry
+            # the cut's one pull: the kernel's block and stats with the
+            # segment's lanes (it blocks on the kernel), or the carry
+            with span("write.lane.cut.kernel_pull") as sp:
+                if full:
+                    meta, st, lanes_np = jax.device_get(
+                        (meta_d, stats_d, lanes_d))
+                    # the chip hands a matrix back in its own layout
+                    # (column-major); the writer reads rows from memory
+                    lanes_np = np.ascontiguousarray(lanes_np)
+                    stats = _segment_stats(st)
+                    sp.nbytes = meta.nbytes + st.nbytes + lanes_np.nbytes
+                else:
+                    host = jax.device_get(carry)
+                    sp.nbytes = sum(v.nbytes for v in host.values())
             if full:
-                # full segment: the fused kernel serializes + reduces
-                # stats in one device program; the host sees finished
-                # bytes
-                kargs = (seg["ts_h"], seg["ts_l"], seg["ldt"], seg["ttl"],
-                         seg["flags8"], seg["fl"], seg["vr"])
-                with span("write.lane.cut.kernel_dispatch") as sp:
-                    meta_d, st = _meta_block_kernel(*kargs)
-                if _kprof.record_dispatch("write.serialize", (n,),
-                                          sp.seconds):
-                    _kprof.maybe_record_cost(
-                        "write.serialize", _meta_block_kernel, kargs)
-                with span("write.lane.cut.kernel_pull") as sp:
-                    meta = np.asarray(meta_d)   # blocks on the kernel
-                    stats = (_uts_pair_to_i64(st[0], st[1]),
-                             _uts_pair_to_i64(st[2], st[3]),
-                             int(st[4]), int(st[5]), int(st[6]))
-                    sp.nbytes = meta.nbytes + 4 * len(st)
                 _kprof.record_execute("write.serialize", sp.seconds)
             else:
                 # final partial segment: host assembly through the one
@@ -662,17 +754,16 @@ class DeviceWriteLane:
                 # definition)
                 from ..storage.sstable.writer import build_meta_block
                 with span("write.lane.cut.host_meta"):
-                    h = np.asarray(seg["ts_h"]).astype(np.uint64)
-                    l = np.asarray(seg["ts_l"]).astype(np.uint64)
+                    c = {k: v[len(v) - n:] for k, v in host.items()}
+                    lanes_np = np.ascontiguousarray(c["lanes"])
+                    h = c["ts_h"].astype(np.uint64)
+                    l = c["ts_l"].astype(np.uint64)
                     ts = ((h << np.uint64(32)) | l) ^ np.uint64(1 << 63)
                     ts = ts.astype(np.int64)
-                    ldt = np.asarray(seg["ldt"])
-                    ttl = np.asarray(seg["ttl"])
-                    flags = np.asarray(seg["flags8"])
+                    ldt, flags = c["ldt"], c["flags8"]
                     meta = build_meta_block(
-                        ts, ldt, ttl, flags,
-                        np.asarray(seg["fl"]).astype("<u4"),
-                        np.asarray(seg["vr"]).astype("<u4"))
+                        ts, ldt, c["ttl"], flags,
+                        c["fl"].astype("<u4"), c["vr"].astype("<u4"))
                     stats = (int(ts.min()), int(ts.max()),
                              int(ldt.min()), int(ldt.max()),
                              int(((flags & DEATH_FLAGS) != 0).sum()))
@@ -686,7 +777,7 @@ class DeviceWriteLane:
             # emission (O(sequences)) and the pwrite pump
             with w._span("compress", "busy", "write.lane.device_compress",
                          key="compress", cells=n):
-                dc_state = self._device_compress(n, meta_d, seg["lanes"])
+                dc_state = self._device_compress(n, meta_d, lanes_d)
         device_pack = None
         if dc_state is not None:
             planes_np, scans = dc_state

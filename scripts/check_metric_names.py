@@ -69,7 +69,7 @@ KNOWN_GROUPS = {
     "index", "merge", "mesh",
     "pipeline", "prepared_statements", "profile", "reads", "request",
     "runtime", "scan", "slo", "storage", "streaming", "system", "table",
-    "verb", "writes",
+    "verb", "write", "writes",
 }
 
 

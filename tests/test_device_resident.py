@@ -136,12 +136,160 @@ def test_meta_kernel_matches_host_builder():
         jnp.asarray(ldt), jnp.asarray(ttl), jnp.asarray(flags),
         jnp.asarray(fl), jnp.asarray(vr))
     assert bytes(np.asarray(meta_d)) == bytes(host)
-    assert dwrite._uts_pair_to_i64(st[0], st[1]) == int(ts.min())
-    assert dwrite._uts_pair_to_i64(st[2], st[3]) == int(ts.max())
-    assert int(st[4]) == int(ldt.min())
-    assert int(st[5]) == int(ldt.max())
+    # the stats come back as ONE u32 vector: one transfer, not seven
+    assert st.shape == (7,) and st.dtype == np.uint32
     from cassandra_tpu.storage.cellbatch import DEATH_FLAGS
-    assert int(st[6]) == int(((flags & DEATH_FLAGS) != 0).sum())
+    assert dwrite._segment_stats(np.asarray(st)) == (
+        int(ts.min()), int(ts.max()), int(ldt.min()), int(ldt.max()),
+        int(((flags & DEATH_FLAGS) != 0).sum()))
+
+
+# -------------------------------------------------------------- write lane --
+
+LANE_SEG = 1000   # a small segment: a few thousand cells cut several
+
+
+def _merged_cells(table, n, seed=13):
+    """About n distinct live cells in merged order (duplicates dropped)."""
+    rng = np.random.default_rng(seed)
+    return cb.merge_sorted([bulk.build_int_batch(
+        table, rng.integers(0, 400, n), rng.integers(0, 1 << 20, n),
+        rng.integers(0, 256, (n, 24), dtype=np.uint8),
+        rng.integers(1, 1 << 40, n).astype(np.int64))])
+
+
+def _device_round(batch, kind):
+    """A round as the lane receives it: from the merge program itself
+    ("resident": its kept cells come first, padded to the bucket) or
+    lifted from the host ("promoted", a fallback round)."""
+    if kind == "promoted":
+        return dwrite.promote_round(batch)
+    r = dwrite.collect_merge_resident(dwrite.submit_merge_resident([batch]))
+    assert isinstance(r, dwrite.DeviceRound) and r.n == len(batch)
+    return r
+
+
+def _write_rounds(directory, table, batch, plan, device, seg=LANE_SEG):
+    """Write `batch` cut into the rounds `plan` names — (cells, kind), or
+    "roll" to finish the output and open the next — through the device
+    lane or the host writer's own append. Returns the outputs' hashes."""
+    gen = [1]
+
+    def writer():
+        return SSTableWriter(Descriptor(directory, gen[0]), table,
+                             segment_cells=seg)
+    w = writer()
+    lane = dwrite.DeviceWriteLane(w) if device else None
+    lo = 0
+    for step in plan:
+        if step == "roll":
+            if lane is not None:
+                lane.flush()
+            w.finish()
+            gen[0] += 1
+            w = writer()
+            lane = dwrite.DeviceWriteLane(w) if device else None
+            continue
+        n, kind = step
+        part = batch.slice_range(lo, lo + n)
+        lo += n
+        if lane is not None:
+            lane.append(_device_round(part, kind))
+        else:
+            w.append(part)
+    if lane is not None:
+        lane.flush()
+    w.finish()
+    return _hashes(directory)
+
+
+R, P = "resident", "promoted"
+LANE_PLANS = {
+    # kept lengths that end short of, on and just past a segment's edge
+    "straddling_rounds": [(700, R), (1300, R), (1000, R), (999, R),
+                          (1, R), (1500, R)],
+    # one segment gathered from five rounds
+    "segment_of_tiny_rounds": [(300, R), (250, P), (200, R), (120, R),
+                               (400, R), (1100, R)],
+    "promoted_between_resident": [(1500, R), (800, P), (1700, R)],
+    # buckets 1024, 2048, 8192, then 1024 again
+    "larger_bucket_after_smaller": [(600, P), (1500, R), (5000, P),
+                                    (700, R)],
+    # the first output finishes with 200 cells in the carry
+    "roll_with_cells_pending": [(1500, R), (700, R), "roll", (1200, R),
+                                (300, P)],
+}
+
+
+@pytest.mark.parametrize("plan", list(LANE_PLANS))
+def test_write_lane_writes_the_host_writers_bytes(tmp_path, plan):
+    """Rounds through the device lane give, byte for byte, the files the
+    serial host writer gives for the same cells cut the same way."""
+    table = _table("lane")
+    steps = LANE_PLANS[plan]
+    batch = _merged_cells(table, 12_000)
+    assert len(batch) >= sum(s[0] for s in steps if s != "roll")
+    host = _write_rounds(str(tmp_path / "host"), table, batch, steps,
+                         device=False)
+    dev = _write_rounds(str(tmp_path / "dev"), table, batch, steps,
+                        device=True)
+    assert host and dev == host
+    if "roll" in steps:
+        assert len(host) > 6        # two outputs
+
+
+def test_write_lane_orders_pulled_lanes_for_the_writer(tmp_path, monkeypatch):
+    """The chip may hand a pulled (cells, lanes) matrix back in its own
+    layout, column-major; the writer's native directory pass reads rows
+    from memory, so the lane must hand it row-major lanes whatever the
+    pull returned."""
+    import jax
+    pull = jax.device_get
+
+    def column_major(x):
+        return jax.tree_util.tree_map(
+            lambda a: np.asfortranarray(a) if np.ndim(a) == 2 else a,
+            pull(x))
+    monkeypatch.setattr(jax, "device_get", column_major)
+    table = _table("lanelayout")
+    steps = LANE_PLANS["straddling_rounds"]
+    batch = _merged_cells(table, 12_000)
+    host = _write_rounds(str(tmp_path / "host"), table, batch, steps,
+                         device=False)
+    dev = _write_rounds(str(tmp_path / "dev"), table, batch, steps,
+                        device=True)
+    assert host and dev == host
+
+
+def test_write_lane_compiles_per_shape_and_dispatches_per_round_and_segment(
+        tmp_path):
+    """Rounds of many kept lengths in one bucket: the lane compiles its
+    append once for (bucket, lanes, segment) and the serialize once for
+    the segment, and dispatches one program a round plus one a full
+    segment. (Slicing at the kept length compiled per length.)"""
+    from cassandra_tpu.service.metrics import GLOBAL as METRICS
+    table = _table("lanepin")
+    seg = 1536                       # a segment size no other test uses
+    lengths = [1025 + 83 * i for i in range(11)]
+    lengths.append(-sum(lengths) % seg)      # whole segments in all
+    assert len(set(lengths)) == 12 and all(1024 < n <= 2048
+                                           for n in lengths)
+    batch = _merged_cells(table, 30_000)
+    names = ("write.lane.dispatches", "write.lane.segments")
+    before = [METRICS.counter(n) for n in names]
+    caches = (dwrite._lane_append._cache_size(),
+              dwrite._meta_block_kernel._cache_size())
+    dev = _write_rounds(str(tmp_path / "dev"), table, batch,
+                        [(n, P) for n in lengths], device=True, seg=seg)
+    host = _write_rounds(str(tmp_path / "host"), table, batch,
+                         [(n, P) for n in lengths], device=False, seg=seg)
+    assert dev == host
+    assert dwrite._lane_append._cache_size() == caches[0] + 1
+    assert dwrite._meta_block_kernel._cache_size() == caches[1] + 1
+    dispatches, segments = (METRICS.counter(n) - b
+                            for n, b in zip(names, before))
+    assert segments == sum(lengths) // seg
+    assert dispatches == segments + len(lengths)
 
 
 # ----------------------------------------------------------- byte identity --
@@ -304,7 +452,8 @@ def test_compaction_dispatches_one_merge_program(tmp_path):
     assert len(dwrite.merge_sorted_device([b.seal()])) == 1
     kernels = profiling.GLOBAL.snapshot()["kernels"]
     assert kernels["merge.resident"]["calls"] >= 3
-    assert set(kernels) <= {"merge.resident", "write.serialize"}
+    assert set(kernels) <= {"merge.resident", "write.append",
+                            "write.serialize"}
 
 
 # ------------------------------------------------------- decode-ahead knob --

@@ -258,14 +258,12 @@ DEVICE_SPANS = {
     "merge.resident.gather", "compaction.writeq.put_wait",
     "compaction.writeq.drain", "compaction.writeq.get_wait",
     "compaction.seal", "compaction.commit", "write.lane.append",
-    "write.lane.cut", "write.lane.cut.slice", "write.lane.cut.pull_lanes",
-    "write.lane.cut.kernel_dispatch", "write.lane.cut.kernel_pull",
+    "write.lane.keys", "write.lane.cut", "write.lane.cut.slice", "write.lane.cut.kernel_pull",
     "write.lane.cut.host_meta", "write.lane.cut.payload", "write.emit",
     "write.emit.directory", "write.emit.submit", "write.compress",
     "compress_pool.pack", "write.io"}
 PROBE_SPAN = "runtime.gil.handoff"
-CUT_PARTS = {"write.lane.cut.slice", "write.lane.cut.pull_lanes",
-             "write.lane.cut.kernel_dispatch", "write.lane.cut.kernel_pull",
+CUT_PARTS = {"write.lane.cut.slice", "write.lane.cut.kernel_pull",
              "write.lane.cut.host_meta", "write.lane.cut.payload"}
 # what CompactionTask.profile held on this path before the primitive,
 # and what the primitive's spans added
@@ -352,7 +350,8 @@ def test_profile_keys_and_serialize_is_the_sum_of_its_children(
                    if r["name"] in names)
     # one write: the profile key IS the sum of the spans that bill it
     assert prof["serialize"] == pytest.approx(
-        total({"write.lane.append", "write.lane.cut"}), abs=1e-9)
+        total({"write.lane.append", "write.lane.keys", "write.lane.cut"}),
+        abs=1e-9)
     assert prof["directory"] == pytest.approx(
         total({"write.emit.directory"}), abs=1e-9)
     assert prof["compress"] == pytest.approx(
@@ -368,7 +367,7 @@ def test_profile_keys_and_serialize_is_the_sum_of_its_children(
     cut = total({"write.lane.cut"})
     assert 0.95 * cut <= parts <= cut
     assert prof["serialize"] == pytest.approx(
-        parts + total({"write.lane.append"}), rel=0.05)
+        parts + total({"write.lane.append", "write.lane.keys"}), rel=0.05)
 
 
 def test_granularity_is_bounded_by_count_not_by_time(device_compaction):

@@ -37,7 +37,9 @@ REPORT: list = []
 # figures: (compile seconds, code MB, temp MB) with the twenty separate
 # gathers outside the passes (eighteen of them one-lane)
 BEFORE = {"merge.resident": (36.1, 171.6, 36.1),
-          "merge.sharded_step": (26.5, 76.8, 89.2)}
+          "merge.sharded_step": (26.5, 76.8, 89.2),
+          # the serialize before it packed its stats into one vector
+          "write.serialize": (1.1, 1.2, 2.3)}
 # merge.resident's temporaries at 2^19 x 13 as the compiler counts them:
 # 24.1 MB with the two stacked matrices 23 and 22 words wide (PR 36's
 # step 0, here and on the chip's host; the parent 36.1). At 26 words the
@@ -127,8 +129,33 @@ def test_meta_block_segment(one_chip):
     def a(dt):
         return jax.ShapeDtypeStruct((SEGMENT,), dt, sharding=one_chip)
     u32, i32 = jnp.uint32, jnp.int32
-    _compile("write.serialize", _meta_block_kernel,
-             a(u32), a(u32), a(i32), a(i32), a(jnp.uint8), a(u32), a(u32))
+    compiled = _compile("write.serialize", _meta_block_kernel, a(u32),
+                        a(u32), a(i32), a(i32), a(jnp.uint8), a(u32),
+                        a(u32))
+    meta, stats = compiled.out_info
+    assert meta.shape == (SEGMENT * META_BYTES,) and stats.shape == (7,)
+
+
+@pytest.mark.parametrize("bucket", [ROUND, 2 * ROUND])
+def test_lane_append_round(one_chip, bucket):
+    """The write lane's per-round program at the two buckets a full
+    round runs at: the carry and the round in, every full segment the
+    pair can hold and the next carry out."""
+    from cassandra_tpu.ops.device_write import RESIDENT_COLS, _lane_append
+    dtypes = {"lanes": jnp.uint32, "ts_h": jnp.uint32, "ts_l": jnp.uint32,
+              "ldt": jnp.int32, "ttl": jnp.int32, "flags8": jnp.uint8,
+              "fl": jnp.uint32, "vr": jnp.uint32}
+
+    def cols(n):
+        return {k: jax.ShapeDtypeStruct(
+            (n, LANES) if k == "lanes" else (n,), dtypes[k],
+            sharding=one_chip) for k in RESIDENT_COLS}
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(f"write.append@{bucket >> 10}k", _lane_append,
+                        cols(SEGMENT), cols(bucket), scalar, scalar)
+    segments, carry = compiled.out_info
+    assert len(segments) == (SEGMENT - 1 + bucket) // SEGMENT
+    assert carry["lanes"].shape == (SEGMENT, LANES)
 
 
 @pytest.mark.slow   # 51 s: the TPU compiler spends 35 s on the reverse
