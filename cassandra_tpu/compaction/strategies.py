@@ -12,6 +12,7 @@ max local-deletion-time).
 """
 from __future__ import annotations
 
+from ..config import parse_storage
 from ..storage.sstable import SSTableReader
 
 
@@ -190,7 +191,8 @@ class TimeWindowCompactionStrategy(AbstractCompactionStrategy):
 
 class UnifiedCompactionStrategy(AbstractCompactionStrategy):
     """Unified strategy (reference UnifiedCompactionStrategy.java:66,
-    unified/Controller.java:154, UnifiedCompactionStrategy.md):
+    unified/Controller.java:154, ShardManager.java:33,
+    UnifiedCompactionStrategy.md):
 
     * `scaling_parameters` is a PER-LEVEL VECTOR ("T4, T8, N, L4"):
       level i uses W = vector[min(i, len-1)]. Positive W behaves tiered
@@ -198,19 +200,38 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
       2-W, threshold 2), N is the middle (fanout 2, threshold 2) —
       UnifiedCompactionStrategy.fanoutFromScalingParameter /
       thresholdFromScalingParameter.
-    * SSTables form DENSITY levels: boundaries start at
+    * An sstable's DENSITY is its on-disk size over the fraction of the
+      node's token space its first..last partition spans
+      (ShardManager.density), so a shard output of a quarter of the ring
+      reads as the whole compaction's density, not a quarter of it.
+    * SSTables form density levels: boundaries start at
       min_sstable_size x fanout(0) and each level's ceiling multiplies
-      by ITS OWN fanout (Controller.getMaxLevelDensity) — so a mixed
-      vector changes the level geometry, not just thresholds.
-    * Outputs are sharded density-aware (Controller.getNumShards): a
-      power-of-two multiple of `base_shard_count` chosen so each shard
-      lands near `target_sstable_size` x density^sstable_growth, with
-      the min-size clamp below the base count. The shard count is the
-      knob that parallelises one logical compaction across cores/chips
-      (ShardManager.java:33; parallel/mesh.py consumes these shards).
+      by ITS OWN fanout (Controller.getMaxLevelDensity).
+    * A level compacts when the largest set of its sstables that
+      overlap at one token reaches the level's threshold (the overlap
+      sets of UnifiedCompactionStrategy.getCompactionPick): sstables
+      that only sit side by side in token order never compact together.
+    * Outputs are sharded at FIXED token boundaries: the node's token
+      space split evenly into num_shards(density) parts
+      (Controller.getNumShards: a power-of-two multiple of
+      `base_shard_count` chosen so each shard lands near
+      target_sstable_size x density^sstable_growth, with the min-size
+      clamp below the base count), so boundaries align across levels.
+      The task writes one sstable per shard (CompactionTask
+      shard_boundaries).
+
+    Sizes take upstream's option strings ('100MiB', '1GiB'); the
+    defaults are conf/cassandra_latest.yaml's `default_compaction`.
+    The node's owned token space is the whole ring: the store knows no
+    narrower ownership.
     """
 
     MAX_SHARD_SHIFT = 20
+    RING_MIN = -(1 << 63)
+    RING_SPAN = 1 << 64
+    # ShardManager.MINIMUM_TOKEN_COVERAGE: the span of an sstable of one
+    # partition, so its density stays finite
+    MIN_COVERAGE = 2.0 ** -48
 
     def __init__(self, cfs, options=None, repaired=None):
         super().__init__(cfs, options, repaired)
@@ -218,10 +239,10 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
         # the per-level W vector; levels beyond the end repeat the last
         self.scaling_vector = self.parse_scaling_vector(spec)
         self.base_shard_count = int(self.options.get("base_shard_count", 4))
-        self.min_sstable_size = int(self.options.get(
-            "min_sstable_size", 2 * 1024 * 1024))
-        self.target_sstable_size = int(self.options.get(
-            "target_sstable_size", 1 << 30))
+        self.min_sstable_size = parse_storage(self.options.get(
+            "min_sstable_size", "100MiB"))
+        self.target_sstable_size = parse_storage(self.options.get(
+            "target_sstable_size", "1GiB"))
         self.sstable_growth = float(self.options.get("sstable_growth",
                                                      0.333))
 
@@ -258,6 +279,18 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
 
     # ------------------------------------------------- density levels --
 
+    def coverage(self, first: int, last: int) -> float:
+        """The fraction of the node's token space first..last spans."""
+        return max((last - first) / self.RING_SPAN, self.MIN_COVERAGE)
+
+    def density(self, sstables) -> float:
+        """On-disk bytes over the fraction of the token space the
+        sstables span together."""
+        sstables = list(sstables)
+        return sum(s.data_size for s in sstables) / self.coverage(
+            min(s.min_token() for s in sstables),
+            max(s.max_token() for s in sstables))
+
     def level_of(self, density: float) -> int:
         """The density level an sstable of `density` bytes falls in:
         level ceilings grow by each level's OWN fanout
@@ -272,9 +305,28 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
     def form_levels(self, sstables) -> dict:
         levels: dict[int, list] = {}
         for s in sstables:
-            levels.setdefault(self.level_of(float(s.data_size)),
+            levels.setdefault(self.level_of(self.density([s])),
                               []).append(s)
         return levels
+
+    @staticmethod
+    def overlap_set(sstables) -> list:
+        """The largest set of sstables that all hold one token (their
+        first..last spans share a point), in the order given; the
+        lowest such token on ties."""
+        events = sorted([(s.min_token(), 0, i) for i, s in
+                         enumerate(sstables)]
+                        + [(s.max_token(), 1, i) for i, s in
+                           enumerate(sstables)])
+        best, now = set(), set()
+        for _token, end, i in events:
+            if end:
+                now.discard(i)
+            else:
+                now.add(i)
+                if len(now) > len(best):
+                    best = set(now)
+        return [s for i, s in enumerate(sstables) if i in best]
 
     # ------------------------------------------------ shard geometry --
 
@@ -299,7 +351,8 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
             count = density / (self.target_sstable_size * math.sqrt(0.5)
                                * self.base_shard_count)
             count = min(count, float(1 << self.MAX_SHARD_SHIFT))
-            return self.base_shard_count *                 (1 << max(int(count) | 1, 1).bit_length() - 1)
+            return self.base_shard_count * \
+                (1 << max(int(count) | 1, 1).bit_length() - 1)
         # partial growth: exponent of the density/target ratio scaled by
         # (1 - growth), rounded to the nearest power of two
         count = density / (self.target_sstable_size
@@ -311,22 +364,25 @@ class UnifiedCompactionStrategy(AbstractCompactionStrategy):
             self.MAX_SHARD_SHIFT)))
         return self.base_shard_count * (1 << exponent)
 
+    def shard_boundaries(self, n: int) -> list:
+        """The first token of each of n even shards of the node's token
+        space (ShardManager's boundaries)."""
+        return [self.RING_MIN + i * self.RING_SPAN // n for i in range(n)]
+
     # -------------------------------------------------- task selection --
 
     def next_background_task(self):
         from .task import CompactionTask
         levels = self.form_levels(self.candidates())
         for lvl in sorted(levels):
-            group = levels[lvl]
+            group = self.overlap_set(levels[lvl])
             if len(group) >= self.threshold(lvl):
                 inputs = group[: self.max_threshold]
-                total = float(sum(s.data_size for s in inputs))
-                shards = self.num_shards(total)
-                shard_bytes = max(int(total // shards),
-                                  self.min_sstable_size)
-                return CompactionTask(self.cfs, inputs,
-                                      max_output_bytes=shard_bytes,
-                                      level=lvl + 1)
+                density = self.density(inputs)
+                return CompactionTask(
+                    self.cfs, inputs, level=self.level_of(density),
+                    shard_boundaries=self.shard_boundaries(
+                        self.num_shards(density)))
         return None
 
 

@@ -22,6 +22,7 @@ bit-identical merge engines:
 """
 from __future__ import annotations
 
+import bisect
 import logging
 import threading
 import time
@@ -30,6 +31,8 @@ import numpy as np
 
 from ..ops.device_write import (DeviceWriteLane, collect_merge_resident,
                                 materialize_round, submit_merge_resident)
+from ..ops.merge import _bucket
+from ..service.metrics import GLOBAL as _METRICS
 from ..storage import cellbatch as cb
 from ..storage.lifecycle import LifecycleTransaction
 from ..storage.sstable import Descriptor, SSTableReader, SSTableWriter
@@ -51,6 +54,27 @@ def _full_key(batch: cb.CellBatch, i: int) -> bytes:
     shortened string — always pad before [:16]."""
     K = batch.n_lanes
     return bytes(_lane_keys(batch)[i]).ljust(4 * K, b"\x00")
+
+
+def _cut_range(lo: int, hi: int, cuts: list) -> list:
+    """The token range (lo, hi] split at each cut point inside it."""
+    out = []
+    for c in cuts:
+        if lo < c < hi:
+            out.append((lo, c))
+            lo = c
+    out.append((lo, hi))
+    return out
+
+
+def _own_pk_map(batch: cb.CellBatch) -> dict[bytes, bytes]:
+    """The entries of batch.pk_map for the partitions batch holds (a
+    sorted batch: one lookup per partition run)."""
+    lane4 = np.ascontiguousarray(batch.lanes[:, :4].astype(">u4"))
+    new = np.ones(len(lane4), dtype=bool)
+    new[1:] = (lane4[1:] != lane4[:-1]).any(axis=1)
+    keys = lane4[new].view("V16").ravel().tolist()
+    return dict(zip(keys, map(batch.pk_map.__getitem__, keys)))
 
 
 class _Cursor:
@@ -116,6 +140,9 @@ class _Cursor:
             if not self._fetch():
                 return
 
+    def first_key(self) -> bytes:
+        return self.bufs[0].lanes[0].astype(">u4").tobytes()
+
     def last_key(self) -> bytes:
         return _full_key(self.bufs[-1], -1)
 
@@ -146,6 +173,12 @@ class _Cursor:
             return buf
         head = buf.slice_range(0, idx)
         tail = buf.slice_range(idx, len(buf))
+        if len(tail.pk_map) > len(tail):
+            # more keys than cells: the map holds partitions already
+            # consumed (a joined buffer's holds every partition of the
+            # buffers it joined). Kept, it would grow with all the input
+            # read so far, and each round's concats walk it in Python
+            tail.pk_map = _own_pk_map(tail)
         self.bufs = [tail]
         return head
 
@@ -329,7 +362,8 @@ class CompactionTask:
                  mesh_devices: int | None = None,
                  device_compress: bool | None = None,
                  drop_only: bool = False,
-                 backend_probe=None):
+                 backend_probe=None,
+                 shard_boundaries: list | None = None):
         """engine: 'device' (TPU kernel), 'native' (C++ streaming merge),
         'numpy' (reference path). All three are tested bit-identical.
         An explicit engine= wins; else use_device=True means 'device'
@@ -346,6 +380,19 @@ class CompactionTask:
         why; a chosen engine is counted at execute()
         (compaction.engine_chosen.<engine>). backend_probe: the probe
         in tpu_backend()'s place (tests).
+
+        shard_boundaries: the first token of each output shard, sorted
+        (UCS's fixed token-space split, strategies.py): the task writes
+        one sstable per shard that holds data, rolling to the next
+        writer between rounds. A round is never cut across a boundary,
+        so every engine writes the same split; without boundaries the
+        rounds are cut exactly as they always were. On the device a
+        sharded task's partial rounds (capped at a shard's end, or the
+        input's last) run at the full rounds' padded shape (the whole
+        compaction's, where that is smaller), so which shapes a
+        compaction compiles does not depend on where its data falls.
+        The mesh fan-out cuts its token ranges at the boundaries too
+        and writes the same sstables.
 
         limiter: a utils.ratelimit.RateLimiter debited per round with the
         round's share of on-disk input bytes (compaction_throughput).
@@ -400,6 +447,8 @@ class CompactionTask:
         self.cfs = cfs
         self.inputs = inputs
         self.max_output_bytes = max_output_bytes
+        self.shard_boundaries = sorted(shard_boundaries) \
+            if shard_boundaries else None
         self.level = level
         self.use_device = bool(use_device)
         self.limiter = limiter
@@ -444,6 +493,12 @@ class CompactionTask:
         self.round_cells = round_cells or (
             self.ROUND_CELLS_DEVICE if self.engine == "device"
             else self.ROUND_CELLS_HOST)
+        # a sharded task pads every device round to the full round's
+        # shape, or to the whole compaction's where that is smaller
+        self.min_bucket = min(
+            _bucket(self.round_cells),
+            _bucket(sum(r.n_cells for r in inputs))) \
+            if self.shard_boundaries else 0
         # drop_only: the selecting strategy asserts every input is
         # fully expired and safe to delete without a rewrite (TWCS
         # expired drop, CompactionController.fully_expired). execute()
@@ -454,6 +509,22 @@ class CompactionTask:
         # per-phase wall seconds, accumulated across rounds (published by
         # bench.py -- the breakdown the perf work navigates by)
         self.profile: dict = {}
+
+    def _shard_cap(self, cursors: list, n_lanes: int):
+        """(shard, cap) of the round the cursors' smallest buffered key
+        starts: the index of the shard it falls in and the largest lane
+        key of that shard's last token, None for the last shard. (None,
+        None) for a task without boundaries."""
+        bounds = self.shard_boundaries
+        if not bounds:
+            return None, None
+        first_key = min(c.first_key() for c in cursors)
+        token = int.from_bytes(first_key[:8], "big") - (1 << 63)
+        shard = max(bisect.bisect_right(bounds, token) - 1, 0)
+        if shard + 1 >= len(bounds):
+            return shard, None
+        last = bounds[shard + 1] - 1 + (1 << 63)
+        return shard, last.to_bytes(8, "big") + b"\xff" * (4 * n_lanes - 8)
 
     def _effective_mesh_devices(self) -> int:
         """The mesh width this task runs at: the explicit mesh_devices=
@@ -567,6 +638,17 @@ class CompactionTask:
         if bounds is None:
             return False
         ranges = boundaries_to_ranges(bounds, n_shards)
+        # a sharded task's output shards: every range lies inside one
+        # (cut at each boundary's last token below it) and is tagged with
+        # it, so the writer rolls where the serial round loop rolls
+        tags = [None] * len(ranges)
+        if self.shard_boundaries:
+            cuts = [b - 1 for b in self.shard_boundaries[1:]]
+            ranges = [piece for lo, hi in ranges
+                      for piece in _cut_range(lo, hi, cuts)]
+            tags = [max(bisect.bisect_right(self.shard_boundaries, hi) - 1,
+                        0) for _lo, hi in ranges]
+            n_shards = len(ranges)
         # exact per-shard INPUT cells from the partition directories
         # (throttle + progress accounting in on-disk byte terms)
         shard_in_cells = np.zeros(n_shards, dtype=np.int64)
@@ -762,7 +844,7 @@ class CompactionTask:
                     progress.add_read(
                         int(shard_in_cells[s] * bytes_per_cell))
                 if merged is not None and len(merged):
-                    wq.put(merged)
+                    wq.put((tags[s], merged))
         finally:
             stop.set()
             for t in workers:
@@ -853,8 +935,7 @@ class CompactionTask:
         if self.drop_only and self._drop_safe():
             return self._execute_drop()
         if self.engine_chosen:
-            from ..service.metrics import GLOBAL as _metrics
-            _metrics.incr(f"compaction.engine_chosen.{self.engine}")
+            _METRICS.incr(f"compaction.engine_chosen.{self.engine}")
         if self.progress is not None:
             self.progress.engine = self.engine
         # the root span: every span of this task, on whichever thread,
@@ -933,8 +1014,9 @@ class CompactionTask:
         # appends, so finish()'s pool drain must credit the tail too.
         # resident: device-resident rounds flow as DeviceRound objects
         # through a DeviceWriteLane instead of writer.append ("lane").
+        # shard: the output shard of the rounds the writer is on
         wstate = {"writer": None, "cells": 0, "credited": 0,
-                  "resident": False, "lane": None}
+                  "resident": False, "lane": None, "shard": None}
 
         progress = self.progress
 
@@ -948,6 +1030,20 @@ class CompactionTask:
             if lane is not None:
                 lane.flush()
                 wstate["lane"] = None
+
+        def roll():
+            # finish the writer and open the next: the lane's pending
+            # partial flushes into the finishing writer first — exactly
+            # the cells finish() would cut from host pending
+            w = wstate["writer"]
+            flush_lane()
+            w.finish()
+            if progress is not None:
+                progress.add_written(w.data_offset() - wstate["credited"])
+            new_readers.append(SSTableReader(w.desc, table))
+            wstate["writer"] = new_writer()
+            wstate["credited"] = 0
+            return w
 
         def write_loop():
             # pack/compress stage of the pipeline: writer.append cuts
@@ -967,8 +1063,8 @@ class CompactionTask:
                 while True:
                     with led_wq.idle("compaction.writeq.get_wait",
                                      prof=prof, key="writeq_get_wait"):
-                        merged = wq.get()
-                    if merged is None:
+                        item = wq.get()
+                    if item is None:
                         # the sentinel is already consumed: a raise out
                         # of the lane flush must land in werr and
                         # RETURN (the generic except below drains the
@@ -979,6 +1075,17 @@ class CompactionTask:
                         except BaseException as e:
                             werr.append(e)
                         return
+                    shard, merged = item
+                    if shard != wstate["shard"]:
+                        if wstate["shard"] is not None:
+                            # the next shard's first round: its cells
+                            # go to an sstable of their own
+                            with pipeline_ledger.span(
+                                    "compaction.shard.roll",
+                                    items=wstate["shard"]) as sp:
+                                sp.nbytes = roll().data_offset()
+                            _METRICS.incr("compaction.shard_rolls")
+                        wstate["shard"] = shard
                     if hasattr(merged, "materialize"):
                         # deferred native-merge gather: runs HERE, on
                         # the writer thread, overlapping the producer's
@@ -1005,18 +1112,7 @@ class CompactionTask:
                         # in-flight segments, so the roll lands late by
                         # a bounded amount — finish() drains the pool
                         # (and the drained tail is credited below).
-                        # The lane's pending partial flushes into the
-                        # finishing writer first — exactly the cells
-                        # finish() would cut from host pending.
-                        w = wstate["writer"]
-                        flush_lane()
-                        w.finish()
-                        if progress is not None:
-                            progress.add_written(
-                                w.data_offset() - wstate["credited"])
-                        new_readers.append(SSTableReader(w.desc, table))
-                        wstate["writer"] = new_writer()
-                        wstate["credited"] = 0
+                        roll()
             except BaseException as e:   # surfaced after join
                 werr.append(e)
                 wstate["lane"] = None
@@ -1031,9 +1127,10 @@ class CompactionTask:
         pending: deque = deque()
 
         def collect_oldest():
-            merged = collect_merge_resident(pending.popleft())
+            shard, h = pending.popleft()
+            merged = collect_merge_resident(h)
             if len(merged):
-                wq_put(merged)
+                wq_put((shard, merged))
 
         # throttle + progress work in on-disk byte terms: each round
         # consumed cells are mapped back to their share of the input
@@ -1164,16 +1261,26 @@ class CompactionTask:
                 # the partition-aligned boundary: the minimal buffered-
                 # through key, extended so no cursor's buffer ends INSIDE
                 # that key's partition; merge everything up to the
-                # partition end (full key width padded with 0xFF)
+                # partition end (full key width padded with 0xFF). A
+                # sharded task caps the cut at the last token of the
+                # shard the round starts in (`items` = 1 on such a cut)
                 per_cursor = max(self.round_cells // len(active), 1)
                 with pipeline_ledger.span("compaction.round.cut") as sp:
                     for c in active:
                         c.fill_to(per_cursor)
                     prefix16 = min(c.last_key() for c in active)[:16]
-                    for c in cursors:
-                        c.extend_past_partition(prefix16)
                     K = self.inputs[0].K
                     boundary = prefix16 + b"\xff" * (4 * K - 16)
+                    shard, cap = self._shard_cap(active, K)
+                    if cap is not None and boundary > cap:
+                        # every buffer reaches past the shard's end,
+                        # so none ends inside a partition below it
+                        boundary = cap
+                        sp.items = 1
+                        _METRICS.incr("compaction.rounds_cut_at_shard")
+                    else:
+                        for c in cursors:
+                            c.extend_past_partition(prefix16)
                     slices = []
                     for c in cursors:
                         s = c.split_at(boundary)
@@ -1196,17 +1303,17 @@ class CompactionTask:
                 if self.limiter is not None:
                     self.limiter.acquire(round_bytes)
                 if self.engine == "device":
-                    pending.append(submit_merge_resident(
+                    pending.append((shard, submit_merge_resident(
                         slices, gc_before=gc_before, now=now,
                         purgeable_ts_fn=controller.purgeable_ts_fn,
-                        prof=prof))
+                        prof=prof, min_bucket=self.min_bucket)))
                     while len(pending) >= self.PIPELINE_DEPTH:
                         collect_oldest()
                 else:
                     merged = merge_fn(slices, gc_before=gc_before, now=now,
                                       purgeable_ts_fn=controller.purgeable_ts_fn)
                     if len(merged):
-                        wq_put(merged)
+                        wq_put((shard, merged))
             stop_prefetch()
             pf_thread = None
             while pending:

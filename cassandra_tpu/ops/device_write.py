@@ -92,14 +92,15 @@ _LED_RESIDENT = pipeline_ledger.ledger("merge").stage("resident")
 # ------------------------------------------------------------- operands --
 
 def build_resident_operands(cat: CellBatch, gc_before: int, now: int,
-                            purgeable_ts_fn):
+                            purgeable_ts_fn, min_bucket: int = 0):
     """The kernel operands (merge.build_operands) extended with the
     serialize-side columns: full flags byte, ttl, u32 frame lengths and
-    value offsets. Returns (operands, pts_host) or None when a frame
+    value offsets, padded to the round's bucket and to at least
+    min_bucket cells. Returns (operands, pts_host) or None when a frame
     exceeds the u32 lanes (the host path raises its loud error
     instead)."""
     n = len(cat)
-    N = dmerge._bucket(n)
+    N = max(dmerge._bucket(n), min_bucket)
     lens64 = cat.off[1:] - cat.off[:-1]
     vrel64 = cat.val_start - cat.off[:-1]
     if n and (int(lens64.max()) >= 1 << 32
@@ -381,10 +382,13 @@ def _note_passes(sp, h: ResidentHandle) -> None:
 def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
                           now: int = 0, purgeable_ts_fn=None,
                           prof: dict | None = None,
-                          device=None) -> ResidentHandle:
+                          device=None, min_bucket: int = 0) -> ResidentHandle:
     """Dispatch one device-resident round (async). Rounds the program
     cannot encode (counters, range bounds, oversized frames) are merged
     here by the numpy spec instead (_host_round — counted).
+
+    min_bucket: the least padded shape the program runs at (a partial
+    round then shares the full rounds' compiled shapes).
 
     device: an explicit jax.Device to commit the operands to (the mesh
     compaction path places shard s's round on mesh device s); None =
@@ -406,7 +410,7 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     with _LED_RESIDENT.busy("merge.resident.pack", prof=prof, key="pack",
                             cells=h.n) as sp:
         built = build_resident_operands(cat, gc_before, now,
-                                        purgeable_ts_fn)
+                                        purgeable_ts_fn, min_bucket)
         if built is not None:
             operands, h.pts = built
             if device is not None:
