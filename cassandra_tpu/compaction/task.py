@@ -36,7 +36,7 @@ from ..service.metrics import GLOBAL as _METRICS
 from ..storage import cellbatch as cb
 from ..storage.lifecycle import LifecycleTransaction
 from ..storage.sstable import Descriptor, SSTableReader, SSTableWriter
-from ..utils import gil_probe, pipeline_ledger, timeutil
+from ..utils import gil_probe, murmur3, pipeline_ledger, timeutil
 
 _log = logging.getLogger(__name__)
 
@@ -240,8 +240,15 @@ class CompactionController:
         return out
 
     def purgeable_ts_fn(self, batch: cb.CellBatch) -> np.ndarray:
+        """Per cell, the oldest timestamp a source outside the
+        compaction may hold for its partition (+inf where none may).
+        Each distinct partition key of the probed runs is hashed once
+        and every outside sstable's bloom filter is asked about all of
+        them in one call: the runs of one partition that several input
+        cursors bring share their key."""
         n = len(batch)
-        out = np.full(n, np.iinfo(np.int64).max, dtype=np.int64)
+        imax = np.iinfo(np.int64).max
+        out = np.full(n, imax, dtype=np.int64)
         overlapping = self._overlapping()
         mems = {id(m): m for m in (self.memtable_at_start,
                                    self.cfs.memtable)}.values()
@@ -255,25 +262,37 @@ class CompactionController:
             mask = (batch.flags & (cb.DEATH_FLAGS | cb.FLAG_EXPIRING)) != 0
             if not mask.any():
                 return out
-            lane4 = batch.lanes[:, :4]
-            part_new = np.ones(n, dtype=bool)
-            part_new[1:] = (lane4[1:] != lane4[:-1]).any(axis=1)
-            part_id = np.cumsum(part_new) - 1
-            starts = np.flatnonzero(part_new)
-            probed = np.unique(part_id[mask])
+            # run boundaries lane by lane: a strided (n, 4) compare
+            # costs several times as much
+            lanes = batch.lanes
+            new = lanes[1:, 0] != lanes[:-1, 0]
+            for k in range(1, 4):
+                new |= lanes[1:, k] != lanes[:-1, k]
+            starts = np.concatenate(([0], np.flatnonzero(new) + 1))
+            probed = np.flatnonzero(np.logical_or.reduceat(mask, starts))
             sp.cells, sp.items = len(starts), len(probed)
-            per_part = np.full(len(starts), np.iinfo(np.int64).max,
-                               dtype=np.int64)
-            for j in probed:
-                pk = batch.partition_key(int(starts[j]))
-                lo = np.iinfo(np.int64).max
-                for src in overlapping:
-                    if src.might_contain(pk) and src.min_ts is not None:
-                        lo = min(lo, src.min_ts)
-                if any(m.contains(pk) for m in mems):
-                    lo = min(lo, 0)  # memtable data is never purged against
-                per_part[j] = lo
-            return per_part[part_id]
+            ids = lanes[starts[probed], :4].astype(">u4")
+            uniq, key_of_run = np.unique(ids.view("V16").ravel(),
+                                         return_inverse=True)
+            keys = [batch.pk_map[k] for k in uniq.tolist()]
+            h1, h2 = murmur3.hash128_batch(keys)
+            _METRICS.incr("compaction.purge.keys_hashed", len(keys))
+            lo = np.full(len(keys), imax, dtype=np.int64)
+            for src in overlapping:
+                if src.min_ts is None:
+                    continue
+                hit = src.might_contain_hashes(h1, h2)
+                _METRICS.incr("compaction.purge.filter_probes")
+                lo = np.where(hit, np.minimum(lo, src.min_ts), lo)
+            if mems:
+                # memtable data is never purged against
+                held = np.fromiter((any(m.contains(pk) for m in mems)
+                                    for pk in keys), dtype=bool,
+                                   count=len(keys))
+                lo = np.where(held, np.minimum(lo, 0), lo)
+            per_run = np.full(len(starts), imax, dtype=np.int64)
+            per_run[probed] = lo[key_of_run]
+            return np.repeat(per_run, np.diff(starts, append=n))
 
 
 def tpu_backend() -> bool:

@@ -433,6 +433,10 @@ class SSTableReader:
     def might_contain(self, pk: bytes) -> bool:
         return self.bloom.might_contain(pk)
 
+    def might_contain_hashes(self, h1: np.ndarray,
+                             h2: np.ndarray) -> np.ndarray:
+        return self.bloom.might_contain_hashes(h1, h2)
+
     def _key_cache_key(self, pk: bytes) -> tuple:
         return (self.desc.directory, self.desc.generation, pk)
 

@@ -68,14 +68,17 @@ class BloomFilter:
     def add(self, key: bytes) -> None:
         self.add_batch([key])
 
-    def might_contain_batch(self, keys: list[bytes]) -> np.ndarray:
-        if not keys:
-            return np.zeros(0, dtype=bool)
-        h1, h2 = murmur3.hash128_batch(keys)
+    def might_contain_hashes(self, h1: np.ndarray,
+                             h2: np.ndarray) -> np.ndarray:
+        """The answer for keys the caller hashed (murmur3.hash128_batch):
+        one hash serves filters of any bits and k."""
         idx = self._indexes(h1, h2)
         w = self.words[(idx >> np.uint64(6)).astype(np.int64)]
         hit = (w >> (idx & np.uint64(63))) & np.uint64(1)
         return hit.all(axis=1)
+
+    def might_contain_batch(self, keys: list[bytes]) -> np.ndarray:
+        return self.might_contain_hashes(*murmur3.hash128_batch(keys))
 
     def might_contain(self, key: bytes) -> bool:
         return bool(self.might_contain_batch([key])[0])

@@ -19,7 +19,8 @@ from cassandra_tpu.storage.memtable import Memtable
 from cassandra_tpu.storage.mutation import Mutation
 from cassandra_tpu.storage.sstable import Descriptor, SSTableWriter
 from cassandra_tpu.storage.table import ColumnFamilyStore
-from cassandra_tpu.utils import pipeline_ledger, timeutil
+from cassandra_tpu.service.metrics import GLOBAL as METRICS
+from cassandra_tpu.utils import bloom, murmur3, pipeline_ledger, timeutil
 
 from test_device_resident import _hashes
 
@@ -129,12 +130,13 @@ def _mixed_batch(table, rng) -> "cb.CellBatch":
     return b.seal()
 
 
-def _store(tmp_path, tag: str, table, seed: int):
-    """Three inputs (gen 1-3), one sstable OUTSIDE the compaction (gen 4)
-    holding every fifth partition, and a memtable holding every seventh."""
+def _store(tmp_path, tag: str, table, seed: int, n_inputs: int = 3):
+    """n_inputs inputs (gen 1..n_inputs), one sstable OUTSIDE the
+    compaction (the next gen) holding every fifth partition, and a
+    memtable holding every seventh."""
     cfs = ColumnFamilyStore(table, str(tmp_path / tag), commitlog=None)
     rng = np.random.default_rng(seed)
-    batches = [_mixed_batch(table, rng) for _ in range(3)]
+    batches = [_mixed_batch(table, rng) for _ in range(n_inputs)]
     for gen, batch in enumerate(batches, 1):
         _write(cfs, table, gen, batch)
     v = table.columns["v"].column_id
@@ -143,15 +145,22 @@ def _store(tmp_path, tag: str, table, seed: int):
         b.add_cell(table.serialize_partition_key([p]),
                    table.serialize_clustering([0]), v, b"outside",
                    OVERLAP_MIN_TS + p)
-    _write(cfs, table, 4, b.seal())
+    _write(cfs, table, n_inputs + 1, b.seal())
     cfs.reload_sstables()
-    for p in range(0, N_PARTS, 7):
+    _memtable_rows(cfs, table, range(0, N_PARTS, 7))
+    inputs = [r for r in cfs.live_sstables()
+              if r.desc.generation <= n_inputs]
+    assert len(inputs) == n_inputs
+    assert len(cfs.live_sstables()) == n_inputs + 1
+    return cfs, inputs, batches
+
+
+def _memtable_rows(cfs, table, parts) -> None:
+    v = table.columns["v"].column_id
+    for p in parts:
         mu = Mutation(table.id, table.serialize_partition_key([p]))
         mu.add(table.serialize_clustering([0]), v, b"", b"mem", 1)
         cfs.apply(mu)
-    inputs = [r for r in cfs.live_sstables() if r.desc.generation < 4]
-    assert len(inputs) == 3 and len(cfs.live_sstables()) == 4
-    return cfs, inputs, batches
 
 
 def _close(cfs) -> None:
@@ -188,6 +197,104 @@ def test_timestamps_agree_where_a_purge_reads_them(tmp_path, seed, gc):
         holds = np.bincount(part_id[read], minlength=part_id[-1] + 1) > 0
         assert (new[~holds[part_id]] == INT64_MAX).all()
         assert (old[~holds[part_id]] != INT64_MAX).any()
+    _close(cfs)
+
+
+def _probed_walk(ctl, batch) -> np.ndarray:
+    """The oracle on the runs the guard probes (those holding a cell a
+    purge reads), +inf on the others: what the guard must return cell
+    for cell."""
+    want = full_walk(ctl, batch)
+    if not len(batch):
+        return want
+    read = (batch.flags & PURGE_FLAGS) != 0
+    starts = np.flatnonzero(_part_new(batch))
+    holds = np.logical_or.reduceat(read, starts)
+    return np.where(np.repeat(holds, np.diff(starts, append=len(batch))),
+                    want, INT64_MAX)
+
+
+def _part_new(batch) -> np.ndarray:
+    lane4 = batch.lanes[:, :4]
+    part_new = np.ones(len(batch), dtype=bool)
+    part_new[1:] = (lane4[1:] != lane4[:-1]).any(axis=1)
+    return part_new
+
+
+def _probed_keys(batch) -> set:
+    read = (batch.flags & PURGE_FLAGS) != 0
+    return {batch.partition_key(int(i)) for i in np.flatnonzero(read)}
+
+
+GUARD_CASES = ["cursors2", "cursors4", "bloom_false_positives",
+               "min_ts_none", "two_memtables", "probed_and_unprobed",
+               "empty"]
+
+
+@pytest.mark.parametrize("case", GUARD_CASES)
+def test_batched_guard_equals_the_walk(tmp_path, case):
+    """Hashing each probed key once and asking each outside filter one
+    vectorised question returns, cell for cell, what the full walk
+    returns on the probed runs: the same partition in runs of 2 and 4
+    concatenated cursors, an outside filter so small it answers yes to
+    keys it never saw, a source without a min_ts (whose yes counts for
+    nothing), a flush's new memtable beside the one the task started
+    with, runs probed and not side by side, no cell at all."""
+    table = _table("cases", 0)
+    n_inputs = {"cursors2": 2, "cursors4": 4}.get(case, 3)
+    cfs, inputs, batches = _store(tmp_path, case, table, 31, n_inputs)
+    pk = table.serialize_partition_key
+    if case == "min_ts_none":
+        b = CellBatchBuilder(table)
+        b.add_cell(pk([N_PARTS + 1]), table.serialize_clustering([0]),
+                   table.columns["v"].column_id, b"x", 1)
+        _write(cfs, table, n_inputs + 2, b.seal())
+        cfs.reload_sstables()
+        inputs = [r for r in cfs.live_sstables()
+                  if r.desc.generation <= n_inputs]
+    ctl = CompactionController(cfs, inputs)
+    outside = ctl._overlapping()
+    cat = cb.CellBatch.concat(batches)
+    if case == "empty":
+        cat = cat.apply_permutation(np.zeros(0, dtype=np.int64))
+    got = ctl.purgeable_ts_fn(cat)
+    if case == "bloom_false_positives":
+        src, = outside
+        held = set(src.partition_keys())
+        src.bloom = bloom.BloomFilter(64, 1)
+        src.bloom.add_batch(sorted(held))
+        assert any(src.might_contain(k) for k in _probed_keys(cat) - held)
+        got = ctl.purgeable_ts_fn(cat)
+    elif case == "min_ts_none":
+        extra, = [r for r in outside if r.desc.generation > n_inputs + 1]
+        extra.bloom.words[:] = np.iinfo(np.uint64).max   # yes to all
+        extra.stats["min_ts"] = None
+        got = ctl.purgeable_ts_fn(cat)
+    elif case == "two_memtables":
+        cfs.memtable = Memtable(table)    # a flush switched it
+        _memtable_rows(cfs, table, range(3, N_PARTS, 11))
+        got = ctl.purgeable_ts_fn(cat)
+    want = _probed_walk(ctl, cat)
+    assert got.dtype == np.int64 and got.shape == (len(cat),)
+    np.testing.assert_array_equal(got, want)
+    if case == "empty":
+        assert len(cat) == 0
+        _close(cfs)
+        return
+    read = (cat.flags & PURGE_FLAGS) != 0
+    assert (want[read] < INT64_MAX).any() and (want[~read] == INT64_MAX).any()
+    runs = np.flatnonzero(_part_new(cat))
+    probed = np.logical_or.reduceat(read, runs)
+    assert probed.any() and not probed.all()
+    if case.startswith("cursors"):
+        # probed runs of one partition from several cursors share a key
+        assert probed.sum() > len(_probed_keys(cat))
+    elif case == "two_memtables":
+        # partitions only the new memtable holds read 0
+        only_new = {pk([p]) for p in range(3, N_PARTS, 11) if p % 7}
+        cells = [i for i in np.flatnonzero(read)
+                 if cat.partition_key(int(i)) in only_new]
+        assert cells and (got[cells] == 0).all()
     _close(cfs)
 
 
@@ -379,3 +486,54 @@ def test_no_span_without_memtable_or_overlap(wide):
     since = pipeline_ledger.new_task_id()
     assert (ctl.purgeable_ts_fn(batch) == INT64_MAX).all()
     assert _probe_spans(since) == []
+
+
+def test_each_key_hashed_once_and_each_filter_asked_once(tmp_path,
+                                                        monkeypatch):
+    """One call over four cursors' runs hashes each distinct probed key
+    once, in ONE batch, and asks each outside sstable's filter ONE
+    vectorised question; the scalar bloom probe never runs, and the
+    counters `compaction.purge.keys_hashed` / `.filter_probes` say so."""
+    table = _table("cost", 0)
+    cfs, inputs, batches = _store(tmp_path, "c", table, 41, n_inputs=4)
+    b = CellBatchBuilder(table)
+    for p in range(1, N_PARTS, 4):
+        b.add_cell(table.serialize_partition_key([p]),
+                   table.serialize_clustering([1]),
+                   table.columns["v"].column_id, b"second", 7_000)
+    _write(cfs, table, 6, b.seal())
+    cfs.reload_sstables()
+    inputs = [r for r in cfs.live_sstables() if r.desc.generation <= 4]
+    ctl = CompactionController(cfs, inputs)
+    assert len(ctl._overlapping()) == 2
+    cat = cb.CellBatch.concat(batches)
+    keys = _probed_keys(cat)
+    hashed = []
+    real = murmur3.hash128_batch
+
+    def counting(batch_keys, *a, **kw):
+        hashed.append(sorted(batch_keys))
+        return real(batch_keys, *a, **kw)
+
+    def scalar(self, key):
+        raise AssertionError("the scalar bloom probe ran")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(murmur3, "hash128_batch", counting)
+        mp.setattr(bloom.BloomFilter, "might_contain", scalar)
+        n_hashed = METRICS.counter("compaction.purge.keys_hashed")
+        n_probes = METRICS.counter("compaction.purge.filter_probes")
+        since = pipeline_ledger.new_task_id()
+        got = ctl.purgeable_ts_fn(cat)
+        assert hashed == [sorted(keys)]
+        assert METRICS.counter("compaction.purge.keys_hashed") \
+            - n_hashed == len(keys)
+        assert METRICS.counter("compaction.purge.filter_probes") \
+            - n_probes == 2
+    sp, = _probe_spans(since)
+    # the runs probed outnumber the keys hashed: the dedupe engaged
+    assert sp["items"] > len(keys) > 0
+    want = _probed_walk(ctl, cat)
+    np.testing.assert_array_equal(got, want)
+    assert (want == 7_000).any()       # the second filter's own answer
+    _close(cfs)

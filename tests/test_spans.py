@@ -37,6 +37,9 @@ class FakeClock:
 def clock(monkeypatch):
     c = FakeClock()
     monkeypatch.setattr(pl, "CLOCK", c)
+    # the thread's CPU reading is reused for CPU_REUSE_S of CLOCK: one
+    # an earlier test took on another clock must not be reused here
+    vars(pl._TLS).pop("cpu_at", None)
     return c
 
 

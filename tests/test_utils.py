@@ -198,6 +198,25 @@ def test_bloom_filter():
     assert bf2.might_contain_batch(keys).all()
 
 
+@pytest.mark.parametrize("bits,k", [(64, 1), (640, 3), (1024, 7),
+                                    bloom.optimal_params(100, 0.01)])
+def test_bloom_might_contain_hashes_is_might_contain(bits, k):
+    """Keys hashed once by the caller get, from filters of any bits and
+    k, the answer each filter gives the key itself: members and
+    non-members, false positives included."""
+    rng = random.Random(bits * 31 + k)
+    keys = [bytes(rng.randrange(256) for _ in range(rng.choice(
+        (0, 1, 15, 16, 17, 33, 185)))) for _ in range(300)]
+    bf = bloom.BloomFilter(bits, k)
+    bf.add_batch(keys[:60])
+    h1, h2 = murmur3.hash128_batch(keys)
+    got = bf.might_contain_hashes(h1, h2)
+    assert got.dtype == bool
+    assert got.tolist() == [bf.might_contain(key) for key in keys]
+    assert got[:60].all() and not got.all()
+    assert bf.might_contain_hashes(*murmur3.hash128_batch([])).shape == (0,)
+
+
 @pytest.mark.parametrize("batch", [1 << 18, 7])
 def test_bloom_add_blob_is_add_by_add(batch, monkeypatch):
     """Keys stored back to back, hashed in bounded batches, set the bits
