@@ -18,16 +18,18 @@ host as columns. The write lane (`DeviceWriteLane`) runs two more
 jitted programs, and nothing eager: per round `_lane_append` lays the
 round behind the device-resident carry of cells earlier rounds left
 over and cuts the full segments out of the pair; per full segment
-`_meta_block_kernel` serializes its META block (including the "ce"
-ts-delta pre-transform, format.py) byte-identically to the host
-serializer (storage/sstable/writer.py build_meta_block). The offsets
-are traced, so the shapes depend on the round's bucket, the lanes and
-the segment size, never on how many cells a round kept. The host
-receives only the FINISHED blocks the compress pool consumes — the
-META bytes and the row-major LANES matrix `segment_pack` wants, in one
-pull a segment — plus the variable-length payload, which never went to
-the device (ragged bytes gather through the native C++ path,
-storage/cellbatch.py).
+`_meta_block_kernel` computes what its META block needs computed — the
+"ce" ts-delta pre-transform (format.py) as u32 word planes, and the
+segment's stats — and lays out no bytes. The offsets are traced, so the
+shapes depend on the round's bucket, the lanes and the segment size,
+never on how many cells a round kept. The host receives, in one pull a
+segment, the kernel's word planes and stats, the segment's flags bytes
+and the row-major LANES matrix `segment_pack` wants, and writes the
+META block through the one layout definition
+(storage/sstable/writer.py lay_meta_block, which the host serializer's
+build_meta_block writes through too) — plus the variable-length
+payload, which never went to the device (ragged bytes gather through
+the native C++ path, storage/cellbatch.py).
 
 This is the ONE jitted merge program compaction dispatches
 (`_resident_program`, kernel name `merge.resident`), and this module owns
@@ -71,6 +73,7 @@ import numpy as np
 from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
                                  FLAG_RANGE_BOUND, FLAG_TOMBSTONE, CellBatch,
                                  merge_sorted)
+from ..storage.sstable.writer import build_meta_block, lay_meta_block
 from ..service.metrics import GLOBAL as _METRICS
 from ..service.profiling import GLOBAL as _kprof
 from ..utils import pipeline_ledger
@@ -223,18 +226,28 @@ _META_COLS = ("ts_h", "ts_l", "ldt", "ttl", "flags8", "fl", "vr")
 
 @jax.jit
 def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
-    """Fused META-block serialize for one FULL segment: the "ce"
-    ts-delta pre-transform + the 25 B/cell section layout emitted as
-    one u8 buffer, plus the segment's stats reductions packed into one
-    u32 vector (`_segment_stats` reads it) — all in a single device
-    program, byte-identical to the host build_meta_block (pinned by
-    test).
+    """The device's part of one FULL segment's META block: the "ce"
+    ts-delta pre-transform and the segment's stats reductions, in one
+    program. It lays out no bytes. Returns (words, stats):
+
+      words  ONE u32 vector, six segment-long planes end to end — the
+             delta's low and high words, ldt, ttl, frame lengths, value
+             offsets (`_meta_from_words` reads it back);
+      stats  the seven stats words (`_segment_stats` reads them).
+
+    The host writes the block from these planes and the segment's flags
+    byte plane (which needs no arithmetic, so it is pulled from the
+    segment's columns) through the one layout definition
+    (storage/sstable/writer.py lay_meta_block): byte identity with the
+    host serializer holds by construction, and is pinned by test. The
+    bytes are not laid out here: interleaving them on the device takes
+    a per-byte gather, which the chip pays per index (≈ 15 ms a
+    65,536-cell segment on a v5e), for what the host does as one copy.
 
     ts planes arrive BIASED (uts = ts + 2^63 mod 2^64, the sort form);
     bias cancels in differences, so the wraparound deltas of the u32
     pairs ARE the i64 deltas, and cell 0's absolute stamp is its uts
     minus the bias — one XOR on the high word."""
-    n = ts_h.shape[0]
     with jax.named_scope("timestamps"):
         prev_h = jnp.concatenate(
             [jnp.full((1,), _BIAS_H, dtype=jnp.uint32), ts_h[:-1]])
@@ -243,34 +256,12 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
         d_l = ts_l - prev_l
         borrow = (ts_l < prev_l).astype(jnp.uint32)
         d_h = ts_h - prev_h - borrow
-
-    def le_bytes(words, per_cell):
-        """Little-endian bytes of `per_cell` u32 word planes per cell,
-        interleaved cell by cell. Written as a gather over a byte iota
-        — byte j is a shift of word j>>2 — because the direct form
-        (bitcast to (n, 4) u8, then flatten) merges a 4-wide minor
-        dimension, which the TPU compiler unrolls: 18 s of compile per
-        section at 65,536 cells against 1 s for this (CHANGES.md
-        PR 21). Same bytes."""
-        j = jnp.arange(4 * per_cell * n, dtype=jnp.int32)
-        w = j >> 2
-        cell = w // per_cell
-        word = words[0][cell]
-        for k in range(1, per_cell):
-            word = jnp.where(w % per_cell == k, words[k][cell], word)
-        shift = (j & 3).astype(jnp.uint32) << 3
-        return ((word >> shift) & jnp.uint32(0xFF)).astype(jnp.uint8)
-
-    def u32_bytes(a):
-        return le_bytes(
-            [jax.lax.bitcast_convert_type(a, jnp.uint32)], 1)
-
-    with jax.named_scope("timestamps"):
-        ts_bytes = le_bytes([d_l, d_h], 2)
     with jax.named_scope("lengths"):
-        meta = jnp.concatenate([
-            ts_bytes, u32_bytes(ldt), u32_bytes(ttl), flags8,
-            u32_bytes(fl), u32_bytes(vr)])
+        # a 1-D concatenate at multiples of the segment: no minor
+        # dimension to merge, and one vector is one transfer
+        words = jnp.concatenate([
+            d_l, d_h, jax.lax.bitcast_convert_type(ldt, jnp.uint32),
+            jax.lax.bitcast_convert_type(ttl, jnp.uint32), fl, vr])
 
     # stats reductions (biased-pair lexicographic min/max for ts)
     with jax.named_scope("stats"):
@@ -285,7 +276,15 @@ def _meta_block_kernel(ts_h, ts_l, ldt, ttl, flags8, fl, vr):
             jax.lax.bitcast_convert_type(jnp.min(ldt), jnp.uint32),
             jax.lax.bitcast_convert_type(jnp.max(ldt), jnp.uint32),
             tombs.astype(jnp.uint32)])
-    return meta, stats
+    return words, stats
+
+
+def _meta_from_words(words: np.ndarray, flags8: np.ndarray) -> np.ndarray:
+    """A full segment's META block, laid out on the host from the
+    kernel's pulled word planes and the segment's flags bytes."""
+    n = len(flags8)
+    lo, hi, ldt, ttl, fl, vr = (words[k * n:(k + 1) * n] for k in range(6))
+    return lay_meta_block(lo, hi, ldt, ttl, flags8, fl, vr)
 
 
 def _uts_pair_to_i64(h: int, l: int) -> int:
@@ -607,14 +606,16 @@ class DeviceWriteLane:
     earlier rounds left over, fewer than a segment, kept on the device —
     and returns every full segment's columns and the next carry. Each
     full segment is then ONE dispatch of `_meta_block_kernel`
-    (`write.serialize`, the fused META serialize + stats), all of a
+    (`write.serialize`, the ts deltas as word planes + stats), all of a
     round's dispatched before the first is pulled, and ONE pull of its
-    block, stats and row-major lanes; the writer gets only finished
+    words, stats, flags and row-major lanes; the host lays out the META
+    block from them (`_meta_from_words`) and the writer gets finished
     blocks (writer._emit_segment — the exact tail the host path runs
     after its own serialize). The final partial segment is the carry
-    itself, pulled whole and trimmed on the host, and assembles through
-    the host build_meta_block: one segment per output, and bit-equality
-    with the kernel is the pinned contract, not an optimization target.
+    itself, pulled whole and trimmed on the host, and its block is
+    built by the host build_meta_block. Both go through the one section
+    writer, lay_meta_block: one layout, so byte identity with the host
+    serializer holds by construction (and is pinned by test).
     Counters: `write.lane.dispatches` (one a round plus one a full
     segment) and `write.lane.segments` (every segment cut)."""
 
@@ -732,32 +733,31 @@ class DeviceWriteLane:
             # the handoff: the program was dispatched at append time
             with span("write.lane.cut.slice"):
                 if full:
-                    meta_d, stats_d = out
-                    lanes_d = seg["lanes"]
+                    words_d, stats_d = out
+                    pull = (words_d, stats_d, seg["flags8"], seg["lanes"])
                 else:
-                    carry = self.carry
-            # the cut's one pull: the kernel's block and stats with the
-            # segment's lanes (it blocks on the kernel), or the carry
+                    pull = self.carry
+            # the cut's one pull: the kernel's word planes and stats with
+            # the segment's flags and lanes (it blocks on the kernel), or
+            # the carry
             with span("write.lane.cut.kernel_pull") as sp:
+                host = jax.device_get(pull)
                 if full:
-                    meta, st, lanes_np = jax.device_get(
-                        (meta_d, stats_d, lanes_d))
+                    words, st, flags8, lanes_np = host
                     # the chip hands a matrix back in its own layout
                     # (column-major); the writer reads rows from memory
                     lanes_np = np.ascontiguousarray(lanes_np)
-                    stats = _segment_stats(st)
-                    sp.nbytes = meta.nbytes + st.nbytes + lanes_np.nbytes
-                else:
-                    host = jax.device_get(carry)
-                    sp.nbytes = sum(v.nbytes for v in host.values())
+                sp.nbytes = sum(v.nbytes
+                                for v in jax.tree_util.tree_leaves(host))
             if full:
                 _kprof.record_execute("write.serialize", sp.seconds)
-            else:
-                # final partial segment: host assembly through the one
-                # shared META builder (byte-identical layout by
-                # definition)
-                from ..storage.sstable.writer import build_meta_block
-                with span("write.lane.cut.host_meta"):
+            # the block laid out on the host, full segment or final
+            # partial alike, through the one META layout
+            with span("write.lane.cut.host_meta"):
+                if full:
+                    meta = _meta_from_words(words, flags8)
+                    stats = _segment_stats(st)
+                else:
                     c = {k: v[len(v) - n:] for k, v in host.items()}
                     lanes_np = np.ascontiguousarray(c["lanes"])
                     h = c["ts_h"].astype(np.uint64)
@@ -781,7 +781,7 @@ class DeviceWriteLane:
             # emission (O(sequences)) and the pwrite pump
             with w._span("compress", "busy", "write.lane.device_compress",
                          key="compress", cells=n):
-                dc_state = self._device_compress(n, meta_d, lanes_d)
+                dc_state = self._device_compress(n, meta, seg["lanes"])
         device_pack = None
         if dc_state is not None:
             planes_np, scans = dc_state
@@ -794,12 +794,15 @@ class DeviceWriteLane:
                         stats, device_pack=device_pack)
 
     @staticmethod
-    def _device_compress(n: int, meta_d, lanes_d):
+    def _device_compress(n: int, meta: np.ndarray, lanes_d):
         """(planes, scans) of one full segment compressed on the device,
         or None when the kernel failed and the host compress leg takes
-        the segment (counted; output bytes identical either way)."""
+        the segment (counted; output bytes identical either way). The
+        META block the host laid out goes back to the device for it:
+        the main lane never holds the block's bytes there."""
         span = pipeline_ledger.span
         try:
+            meta_d = jax.device_put(meta)
             with span("write.lane.device_compress.dispatch") as sp:
                 planes_d, mbl, mbd, lbl, lbd, order_ok = \
                     device_compress.segment_scan_kernel(meta_d, lanes_d)

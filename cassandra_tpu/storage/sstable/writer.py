@@ -91,24 +91,50 @@ def build_meta_block(ts: "np.ndarray", ldt: "np.ndarray",
                      ttl: "np.ndarray", flags: "np.ndarray",
                      frame_len: "np.ndarray", val_rel: "np.ndarray"
                      ) -> "np.ndarray":
-    """The "ce" META block: ts-delta 8 + ldt 4 + ttl 4 + flags 1 +
-    frame_len u32 + val_rel u32 = 25 B/cell. The ts lane is stored as
-    per-segment wraparound deltas (first cell absolute; format.py "ce")
-    — mod-2^64 arithmetic, so the reader's cumsum rebuild is exact for
-    any i64 timestamps. ONE definition of the layout: the host write
-    path serializes through here and the device fused-serialize kernel
-    (ops/device_write.py) is pinned byte-identical to it by test."""
+    """The "ce" META block of i64 timestamps: the delta step
+    (`ts_delta_words`), then the one section writer
+    (`lay_meta_block`)."""
+    lo, hi = ts_delta_words(ts)
+    return lay_meta_block(lo, hi, ldt.astype("<i4", copy=False),
+                          ttl.astype("<i4", copy=False),
+                          flags.astype("u1", copy=False),
+                          frame_len, val_rel)
+
+
+def ts_delta_words(ts: "np.ndarray") -> tuple:
+    """The ts lane's per-segment wraparound deltas (first cell absolute;
+    format.py "ce") as their (low, high) u32 words: mod-2^64
+    arithmetic, so the reader's cumsum rebuild is exact for any i64
+    timestamps. The device lane computes the same words in its
+    serialize kernel (ops/device_write.py `_meta_block_kernel`)."""
     n = len(ts)
-    tsd = np.empty(n, dtype=np.int64)
+    tsd = np.empty(n, dtype="<i8")
     if n:
         tsd[0] = ts[0]
         np.subtract(ts[1:], ts[:-1], out=tsd[1:])
+    words = tsd.view("<u4").reshape(n, 2)
+    return words[:, 0], words[:, 1]
+
+
+def lay_meta_block(ts_lo: "np.ndarray", ts_hi: "np.ndarray",
+                   ldt: "np.ndarray", ttl: "np.ndarray",
+                   flags: "np.ndarray", frame_len: "np.ndarray",
+                   val_rel: "np.ndarray") -> "np.ndarray":
+    """The "ce" META block: ts-delta 8 + ldt 4 + ttl 4 + flags 1 +
+    frame_len u32 + val_rel u32 = 25 B/cell, every section
+    little-endian, laid end to end. ONE definition of the layout: the
+    host write path and the device write lane both write the block
+    here. ts_lo / ts_hi are the delta's u32 words (`ts_delta_words`),
+    written interleaved as the first 8n bytes; the other planes are
+    4-byte words (ldt, ttl, frame_len, val_rel: signed or not, the
+    bytes are the same) and the flags byte."""
+    n = len(flags)
     meta = np.empty(n * 25, dtype=np.uint8)
-    pos = 0
-    for arr, width in ((tsd, 8),
-                       (ldt.astype("<i4", copy=False), 4),
-                       (ttl.astype("<i4", copy=False), 4),
-                       (flags.astype("u1", copy=False), 1),
+    ts = meta[:8 * n].view("<u4").reshape(n, 2)
+    ts[:, 0] = ts_lo
+    ts[:, 1] = ts_hi
+    pos = 8 * n
+    for arr, width in ((ldt, 4), (ttl, 4), (flags, 1),
                        (frame_len, 4), (val_rel, 4)):
         end = pos + n * width
         meta[pos:end] = np.ascontiguousarray(arr).view(np.uint8)
@@ -1224,7 +1250,7 @@ class SSTableWriter:
         _lo("min_ldt", mn_ldt)
         _hi("max_ldt", mx_ldt)
         self._stats["tombstones"] += tombs
-        # the flags plane of the "ce" META block (build_meta_block):
+        # the flags plane of the "ce" META block (lay_meta_block):
         # ts-delta 8 + ldt 4 + ttl 4 bytes per cell precede it
         st["cell_flags"] |= int(np.bitwise_or.reduce(meta[16 * n:17 * n]))
         return opened

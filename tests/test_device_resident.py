@@ -113,9 +113,10 @@ def _compact(tmp_path, tag: str, table, **task_kw) -> dict:
 # ------------------------------------------------------- serialize kernel --
 
 def test_meta_kernel_matches_host_builder():
-    """The fused device META kernel and the host build_meta_block must
-    emit identical bytes — including wraparound ts deltas at extreme
-    timestamps — and identical stats reductions."""
+    """The block the host lays out from the device META kernel's word
+    planes and the host build_meta_block must be the same bytes —
+    including wraparound ts deltas at extreme timestamps — and the
+    kernel's stats reductions the host's."""
     rng = np.random.default_rng(3)
     n = 4096
     ts = rng.integers(-(1 << 62), 1 << 62, n).astype(np.int64)
@@ -130,12 +131,22 @@ def test_meta_kernel_matches_host_builder():
     import jax.numpy as jnp
     with np.errstate(over="ignore"):
         uts = ts.astype(np.uint64) ^ np.uint64(1 << 63)
-    meta_d, st = dwrite._meta_block_kernel(
+    words_d, st = dwrite._meta_block_kernel(
         jnp.asarray((uts >> np.uint64(32)).astype(np.uint32)),
         jnp.asarray((uts & np.uint64(0xFFFFFFFF)).astype(np.uint32)),
         jnp.asarray(ldt), jnp.asarray(ttl), jnp.asarray(flags),
         jnp.asarray(fl), jnp.asarray(vr))
-    assert bytes(np.asarray(meta_d)) == bytes(host)
+    # six word planes end to end, one vector: one transfer
+    assert words_d.shape == (6 * n,) and words_d.dtype == np.uint32
+    words = np.asarray(words_d)
+    assert bytes(dwrite._meta_from_words(words, flags)) == bytes(host)
+    # the planes themselves: the i64 deltas' words, then the columns
+    with np.errstate(over="ignore"):
+        tsd = np.concatenate([ts[:1], ts[1:] - ts[:-1]]).view(np.uint64)
+    assert (words[:n] == (tsd & np.uint64(0xFFFFFFFF))).all()
+    assert (words[n:2 * n] == (tsd >> np.uint64(32))).all()
+    assert (words[2 * n:3 * n].view(np.int32) == ldt).all()
+    assert (words[5 * n:] == vr).all()
     # the stats come back as ONE u32 vector: one transfer, not seven
     assert st.shape == (7,) and st.dtype == np.uint32
     from cassandra_tpu.storage.cellbatch import DEATH_FLAGS
@@ -236,6 +247,29 @@ def test_write_lane_writes_the_host_writers_bytes(tmp_path, plan):
     assert host and dev == host
     if "roll" in steps:
         assert len(host) > 6        # two outputs
+
+
+def test_write_lane_wrapping_delta_after_a_carry(tmp_path):
+    """A segment whose cells start in the carry and whose ts delta at the
+    junction with the next round wraps past 2^63 (timestamps alternate
+    between large positive and large negative): the device lane still
+    writes the host writer's bytes."""
+    table = _table("lanewrap")
+    batch = _merged_cells(table, 8000, seed=17)
+    n = len(batch)
+    mag = np.random.default_rng(17).integers(1 << 62, np.iinfo(np.int64).max,
+                                             n, dtype=np.int64)
+    batch.ts[:] = np.where(np.arange(n) % 2 == 0, mag, -mag)
+    # round 2's first cell (700) follows the carry's last (699): the
+    # i64 delta overflows, so only its wraparound words are exact
+    assert int(batch.ts[700]) - int(batch.ts[699]) > np.iinfo(np.int64).max
+    steps = [(700, R), (1300, R), (999, P), (1501, R), "roll", (1200, P)]
+    assert n >= sum(s[0] for s in steps if s != "roll")
+    host = _write_rounds(str(tmp_path / "host"), table, batch, steps,
+                         device=False)
+    dev = _write_rounds(str(tmp_path / "dev"), table, batch, steps,
+                        device=True)
+    assert host and dev == host
 
 
 def test_write_lane_orders_pulled_lanes_for_the_writer(tmp_path, monkeypatch):

@@ -27,7 +27,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 ROUND = 1 << 19          # one ROUND_CELLS_DEVICE round, padded (task.py)
 SEGMENT = 1 << 16        # storage/sstable/format.py SEGMENT_CELLS
 LANES = 13               # (id int, c int, v blob): lanes_for_table
-META_BYTES = 25          # writer.build_meta_block bytes per cell
+META_BYTES = 25          # writer.lay_meta_block bytes per cell
+META_WORDS = 6           # u32 planes write.serialize returns per cell
 ANN_ROWS, ANN_DIM = 100_000, 128
 
 # (program, compile seconds, generated code bytes, temp bytes) — printed
@@ -38,8 +39,8 @@ REPORT: list = []
 # gathers outside the passes (eighteen of them one-lane)
 BEFORE = {"merge.resident": (36.1, 171.6, 36.1),
           "merge.sharded_step": (26.5, 76.8, 89.2),
-          # the serialize before it packed its stats into one vector
-          "write.serialize": (1.1, 1.2, 2.3)}
+          # the serialize while it laid out the block's bytes itself
+          "write.serialize": (1.4, 1.2, 2.5)}
 # merge.resident's temporaries at 2^19 x 13 as the compiler counts them:
 # 24.1 MB with the two stacked matrices 23 and 22 words wide (PR 36's
 # step 0, here and on the chip's host; the parent 36.1). At 26 words the
@@ -132,8 +133,16 @@ def test_meta_block_segment(one_chip):
     compiled = _compile("write.serialize", _meta_block_kernel, a(u32),
                         a(u32), a(i32), a(i32), a(jnp.uint8), a(u32),
                         a(u32))
-    meta, stats = compiled.out_info
-    assert meta.shape == (SEGMENT * META_BYTES,) and stats.shape == (7,)
+    words, stats = compiled.out_info
+    # the block's six word planes (its flags bytes come from the
+    # segment's columns), laid out by the host
+    assert words.shape == (SEGMENT * META_WORDS,) and stats.shape == (7,)
+    assert words.dtype == jnp.uint32
+    # the program computes words and lays out no bytes: a gather here
+    # is paid per index on the chip (≈ 15 ms a segment for a per-byte
+    # layout on a v5e)
+    hlo = compiled.as_text()
+    assert "gather(" not in hlo and "iota(" not in hlo
 
 
 @pytest.mark.parametrize("bucket", [ROUND, 2 * ROUND])
