@@ -338,7 +338,7 @@ def run_mesh_bench(base_dir: str, table, cfg) -> dict:
     MiB/s and batched-read rows/s at 1/2/4/8 mesh lanes vs the serial
     path. Lanes here are GIL-releasing host threads under the native
     engine (the device engine fans the same shards across jax devices;
-    the virtual-mesh curve lives in __graft_entry__.dryrun_multichip).
+    __graft_entry__.dryrun_multichip checks that path's bytes).
     Output bytes are identical to serial for every lane count
     (scripts/check_compaction_ab.py mesh legs pin it). The headline
     serial-vs-mesh number goes through paired_ab so box drift can't
@@ -380,10 +380,8 @@ def run_mesh_bench(base_dir: str, table, cfg) -> dict:
     # ratio is the signal. NOTE the ceiling on this box: the mesh
     # parallelizes decode+merge, which is ~40% of this pipeline's wall
     # (compress+io on the writer thread bound the rest), so the curve
-    # here proves overlap + byte identity at realistic cost, while the
-    # chips-vs-throughput scaling proof is the virtual-mesh curve in
-    # __graft_entry__.dryrun_multichip (pure merge, per-device walls
-    # asserted strictly decreasing)
+    # here proves overlap + byte identity at realistic cost; a device
+    # mesh's scaling is read on chips (chip_smoke.py --chips 4)
     curve = {}
     for n in MESH_LANE_COUNTS:
         pair = paired_ab(lambda: compact_leg(0),

@@ -17,7 +17,7 @@ Layer map (mirrors SURVEY.md section 1):
   storage/    local storage engine       (ref: db/)
   compaction/ compaction + lifecycle     (ref: db/compaction/, db/lifecycle/)
   ops/        device kernels + codecs    (ref: utils/MergeIterator, io/compress/)
-  parallel/   mesh sharding of kernels   (ref: db/compaction/ShardManager)
+  parallel/   mesh lanes + shard planning (ref: db/compaction/ShardManager)
   types/      CQL type system            (ref: db/marshal/)
   utils/      substrate                  (ref: utils/)
 """

@@ -151,7 +151,7 @@ def _resident_program(operands):
     # named_scope: metadata only (op names in a profiler trace), the
     # program and its bytes are unchanged
     with jax.named_scope("sort"):
-        perm, n_passes = dmerge.device_sort_perm(operands)
+        perm, n_passes = dmerge._traced_sort_perm(operands)
     with jax.named_scope("reconcile"):   # its purge stage names itself
         # ONE row gather through perm carries what reconcile reads AND
         # the serialize-side columns the next stage takes from it
@@ -368,14 +368,22 @@ _TEST_COLLECT_DELAY = None
 _collect_seq = 0
 
 
-def _note_passes(sp, h: ResidentHandle) -> None:
-    """Read how many sort passes a FINISHED round ran (one more scalar of
-    the pull that waited for it) into the wait span — items = the passes
-    the sort has, cells = the passes this round ran — and the counters."""
-    sp.items = dmerge.n_sort_keys(h.cat.n_lanes)
-    sp.cells = int(h.out[3])
-    _METRICS.incr("merge.resident.passes_run", sp.cells)
-    _METRICS.incr("merge.resident.passes_skipped", sp.items - sp.cells)
+def _wait(h: ResidentHandle) -> tuple[int, int, int]:
+    """Block on a dispatched round's program — span `merge.resident.wait`,
+    items = the passes the sort has, cells = the passes this round ran,
+    the two pass counters, and the registry's execute time. Returns the
+    round's (n_keep, n_amb, n_exp_kept); the one pull of its four count
+    scalars is the wait."""
+    with _LED_RESIDENT.stall("merge.resident.wait", prof=h.prof,
+                             key="device") as sp:
+        n_keep, n_amb, n_exp_kept, n_run = (
+            int(x) for x in jax.device_get(h.out[:4]))
+        sp.items = dmerge.n_sort_keys(h.cat.n_lanes)
+        sp.cells = n_run
+        _METRICS.incr("merge.resident.passes_run", sp.cells)
+        _METRICS.incr("merge.resident.passes_skipped", sp.items - sp.cells)
+    _kprof.record_execute("merge.resident", sp.seconds)
+    return n_keep, n_amb, n_exp_kept
 
 
 def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
@@ -449,14 +457,8 @@ def collect_merge_resident(h: ResidentHandle):
     if h.mode == "done":
         return promote_round(h.result)
     cat, prof = h.cat, h.prof
-    n_keep_d, n_amb_d, n_exp_d, _, perm_out_d, cols = h.out[:6]
-    with _LED_RESIDENT.stall("merge.resident.wait", prof=prof,
-                             key="device") as sp:
-        n_keep = int(n_keep_d)      # blocks until the program finishes
-        n_amb = int(n_amb_d)
-        n_exp_kept = int(n_exp_d)
-        _note_passes(sp, h)
-    _kprof.record_execute("merge.resident", sp.seconds)
+    perm_out_d, cols = h.out[4:6]
+    n_keep, n_amb, n_exp_kept = _wait(h)
 
     # cells = the cells the round kept, items = the cells it read
     with _LED_RESIDENT.busy("merge.resident.gather", prof=prof,
@@ -506,11 +508,7 @@ def materialize_round(h: ResidentHandle) -> CellBatch:
     program also did go unused here; that is the price of one program."""
     if h.mode == "done":
         return h.result
-    with _LED_RESIDENT.stall("merge.resident.wait", prof=h.prof,
-                             key="device") as sp:
-        jax.block_until_ready(h.out[-2:])
-        _note_passes(sp, h)
-    _kprof.record_execute("merge.resident", sp.seconds)
+    _wait(h)
     with _LED_RESIDENT.busy("merge.resident.gather", prof=h.prof,
                             key="gather", cells=h.n):
         return _materialize(h)

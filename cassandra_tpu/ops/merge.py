@@ -46,9 +46,8 @@ Shapes are padded to buckets so programs are traced once per bucket size.
 
 This module holds the kernel's PARTS. The one jitted program compaction
 dispatches, and the submit/collect pair around it, live in
-ops/device_write.py (`_resident_program`, kernel name `merge.resident`);
-`merge_reconcile_kernel` is the bare sort + reconcile form the driver
-entry and the parallel/mesh.py shard_map bodies trace.
+ops/device_write.py (`_resident_program`, kernel name `merge.resident`),
+on one chip and on every mesh lane of a sharded compaction.
 """
 from __future__ import annotations
 
@@ -101,15 +100,6 @@ def _lsd_pass(key: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
     return new_perm
 
 
-# registry-instrumented (service/profiling.py): eager host calls are
-# timed under "merge.lsd_pass"; calls from inside an enclosing trace
-# (_resident_program, shard_map bodies) pass through untimed — the
-# outer program's dispatch owns those
-from ..service.profiling import GLOBAL as _kprof_registry  # noqa: E402
-
-_lsd_pass = _kprof_registry.wrap("merge.lsd_pass", _lsd_pass)
-
-
 def _sort_keys(operands) -> list:
     """Most-significant first: validity, identity lanes, ~ts."""
     lanes = operands["lanes"]
@@ -133,11 +123,8 @@ def _traced_sort_perm(operands):
     pass only where the answer is yes. Padding rows are masked out of
     the question — they carry 0xFFFFFFFF lanes and ts 0, so unmasked
     every key of a padded round would vary — and need not sit at the
-    end (a mesh shard's valid rows start anywhere). `valid` itself is
-    always sorted: it is what moves the padding behind the cells.
-
-    Works eagerly and under an enclosing jit/shard_map (nested jit
-    inlines).
+    end. `valid` itself is always sorted: it is what moves the padding
+    behind the cells.
 
     Returns (perm, passes_run); perm is the array the unconditional
     passes give, element for element."""
@@ -152,9 +139,6 @@ def _traced_sort_perm(operands):
         perm = jax.lax.cond(varies, _lsd_pass, lambda _k, p: p, key, perm)
         ran += varies
     return _lsd_pass(jnp.asarray(keys[0]), perm), ran
-
-
-device_sort_perm = _traced_sort_perm
 
 
 # -------------------------------------------------------------- reconcile --
@@ -307,28 +291,6 @@ def reconcile_columns(operands, perm, also=()) -> dict:
     for i, name in enumerate(_PREDICATES):
         s[name] = ((bits >> i) & 1) != 0
     return s
-
-
-@jax.jit
-def reconcile_kernel(operands, perm):
-    """Dict-operand form (driver entry / shard_map body); operands
-    UNSORTED, moved through perm here."""
-    return reconcile_sorted(reconcile_columns(operands, perm),
-                            operands["now"], operands["gc_before"])
-
-
-# dual-use like _lsd_pass: host entry ("merge.reconcile") or traced body
-reconcile_kernel = _kprof_registry.wrap("merge.reconcile",
-                                        reconcile_kernel)
-
-
-def merge_reconcile_kernel(operands):
-    """Jittable single-call form (driver entry / shard_map body): traced
-    sort composition + reconcile. Returns (perm, packed_masks) where
-    packed bit0=keep, bit1=ambiguous, bit2=expired, bit3=shadowed."""
-    perm, _ = _traced_sort_perm(operands)
-    packed = reconcile_kernel(operands, perm)
-    return perm, packed
 
 
 def prev_eq(a):

@@ -1,27 +1,7 @@
-"""Mesh data plane package. `fanout` (the host-thread lane pool +
+"""Mesh data plane: `fanout` (the host-thread lane pool and the
 `compaction_mesh_devices` demand registry) and `boundaries` (token
-boundary planning + mesh.* shard metrics) are jax-free; fanout is
-imported eagerly — every StorageEngine pulls it in at startup. The
-mesh module imports jax at module level, so its re-exports resolve
-LAZILY (PEP 562) and the numpy-only planner symbols resolve from
-`boundaries`: a node with the knob at its default 0 must not pay the
-jax import (~1s + its RSS) for a subsystem it never touches, and the
-host-engine mesh paths (batched reads, range scans, native-engine
-compaction) stay jax-free even with the knob on.
+boundary planning and the mesh.* shard metrics). Both are jax-free; a
+device-engine compaction's lanes dispatch `merge.resident`
+(ops/device_write.py), one jax device per lane (compaction/task.py
+`_mesh_produce`).
 """
-from . import fanout  # noqa: F401
-
-_BOUNDARY_EXPORTS = ("plan_token_boundaries", "boundaries_from_indexes",
-                     "shard_imbalance")
-_MESH_EXPORTS = ("make_mesh", "sharded_merge_step", "shard_batch")
-
-
-def __getattr__(name):
-    if name in _BOUNDARY_EXPORTS:
-        from . import boundaries
-        return getattr(boundaries, name)
-    if name in _MESH_EXPORTS:
-        from . import mesh
-        return getattr(mesh, name)
-    raise AttributeError(
-        f"module {__name__!r} has no attribute {name!r}")

@@ -1,21 +1,16 @@
-"""Token-range boundary planning + mesh.* shard metrics — the jax-FREE
-half of the mesh data plane (the ShardManager.computeBoundaries role).
-
-These helpers are pure numpy and serve the host-engine mesh paths
-(batched reads, range scans, native/numpy mesh compaction) that must
-not pay mesh.py's module-level jax import. mesh.py re-exports
-everything here so `parallel.mesh` imports keep working.
+"""Token-range boundary planning + mesh.* shard metrics (the
+ShardManager.computeBoundaries role). Pure numpy: the host-engine mesh
+paths (batched reads, range scans, native/numpy mesh compaction) pay
+no jax import for it.
 
 Boundary planning is count-weighted over DISTINCT cells: weighting by
 raw input cells puts a hot, heavily-duplicated partition's shard at the
-target input size but a fraction of the target OUTPUT size (the skewed
-multichip sweep measured 21x kept-cell spread, 6.2k vs 130k).
-Duplicates collapse in the merge, so the planner weights each token by
-its distinct-identity count — from the batch itself
-(`distinct_token_weights`) or, on the real compaction/read paths, from
-the input sstables' partition directories (`boundaries_from_indexes`:
-per-sstable per-partition cell counts, max-combined across inputs as
-the distinct estimate).
+target input size but a fraction of the target OUTPUT size (a skewed
+sweep measured 21x kept-cell spread, 6.2k vs 130k). Duplicates
+collapse in the merge, so the planner weights each token by an
+estimate of its distinct-identity count from the input sstables'
+partition directories (`boundaries_from_indexes`: per-sstable
+per-partition cell counts, max-combined across inputs).
 """
 from __future__ import annotations
 
@@ -69,28 +64,6 @@ def plan_token_boundaries(uniq_tokens: np.ndarray, weights: np.ndarray,
         assigned = int(cum[take - 1]) if take > 0 else 0
         taken = take
     return bounds
-
-
-def batch_tokens_u64(cat) -> np.ndarray:
-    """Biased uint64 tokens of every cell (lane0 << 32 | lane1)."""
-    with np.errstate(over="ignore"):
-        return (cat.lanes[:, 0].astype(np.uint64) << np.uint64(32)) \
-            | cat.lanes[:, 1].astype(np.uint64)
-
-
-def distinct_token_weights(cat) -> tuple[np.ndarray, np.ndarray]:
-    """(distinct tokens asc, distinct-IDENTITY cell count per token).
-    The planner's weight source for in-memory batches: duplicates of the
-    same identity collapse in the merge, so balancing on raw cell counts
-    leaves duplicate-heavy shards with a fraction of the target OUTPUT
-    size. One np.unique over the full identity lanes counts survivors
-    exactly (tombstone purging aside)."""
-    K = cat.n_lanes
-    tok = batch_tokens_u64(cat)
-    keys = np.ascontiguousarray(cat.lanes.astype(">u4")).view(
-        f"S{4 * K}").ravel()
-    _, first = np.unique(keys, return_index=True)
-    return np.unique(tok[first], return_counts=True)
 
 
 def boundaries_from_indexes(readers, n_shards: int) -> np.ndarray | None:

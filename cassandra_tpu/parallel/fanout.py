@@ -1,19 +1,18 @@
 """Process-global shard fan-out: the host-thread execution substrate of
 the mesh data plane.
 
-The mesh probe (parallel/mesh.py) established that per-shard work —
-device programs AND host merge engines (the native FFI and numpy both
-release the GIL in their hot paths) — overlaps only when each shard is
-DRIVEN FROM ITS OWN HOST THREAD. This module owns the knob and the
-read-side threads: `configure()` applies the hot-reloadable
-`compaction_mesh_devices` setting exactly like the compressor pool's
-(0 = off: every caller falls back to its serial path); batched mesh
-reads and sharded range scans (storage/table.py) run on the shared
-ShardFanout pool here, while mesh compaction (compaction/task.py)
-reads only the WIDTH via mesh_devices() and drives its own
-per-task lanes (a compaction shard can block on the throughput
-limiter — parking a shared read lane behind the compaction throttle
-would let one background task starve point-read batches).
+Per-shard work — device programs AND host merge engines (the native FFI
+and numpy both release the GIL in their hot paths) — overlaps only when
+each shard is DRIVEN FROM ITS OWN HOST THREAD. This module owns the knob
+and the read-side threads: `configure()` applies the hot-reloadable
+`compaction_mesh_devices` setting exactly like the compressor pool's (0
+= off: every caller falls back to its serial path); batched mesh reads
+and sharded range scans (storage/table.py) run on the shared ShardFanout
+pool here, while mesh compaction (compaction/task.py) reads only the
+WIDTH via mesh_devices() and drives its own per-task lanes (a compaction
+shard can block on the throughput limiter — parking a shared read lane
+behind the compaction throttle would let one background task starve
+point-read batches).
 
 map_shards(fn, n) preserves SHARD ORDER in its results — token-range
 shard order is identity-lane order (the PR 4 memtable invariant), so

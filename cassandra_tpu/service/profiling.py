@@ -1,7 +1,7 @@
 """Device program registry: compile/dispatch/execute accounting for
 every jitted entry point (observability layer 6, device half).
 
-The JAX programs (ops/merge.py, ops/device_write.py, parallel/mesh.py)
+The JAX programs (ops/device_write.py, ops/device_scan.py, index/manager.py)
 were a black box: a first call on a new operand shape pays XLA
 compilation (seconds to minutes for big sorts), warm calls pay dispatch
 + device execution, and nothing recorded which was which. This module

@@ -1015,7 +1015,7 @@ class ColumnFamilyStore:
 
     def _mesh_boundaries(self, n_shards: int):
         """Count-weighted token boundaries over the live sstable set
-        (parallel/mesh.boundaries_from_indexes), cached per (live
+        (parallel/boundaries.boundaries_from_indexes), cached per (live
         generations, n_shards): the plan walks every live partition
         directory, but only changes when the sstable set does —
         flush/compaction/quarantine all change the generation tuple,

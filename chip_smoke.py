@@ -23,10 +23,10 @@ one chip, the entry points a user would call, data made from --seed:
            against a numpy brute force.
 
 With --chips 4 it runs ONLY what exists across chips: the same backlog
-compacted by the device engine serially and with mesh_devices=4
-(byte-identical), the sharded merge (per-device dispatch and the
-shard_map/psum program) against cellbatch.merge_sorted, and a check that
-four distinct devices did the work.
+compacted by the device engine serially and across the store's four mesh
+lanes, CompactionTask(mesh_devices=4), each lane's rounds run by
+merge.resident on its own chip: output components byte-identical, and a
+check that four distinct devices did the work.
 
 Every earlier line of stdout is one JSON object per phase. The LAST line is
 the contract line, {"ok": true, "device": {...}}, printed only when every
@@ -86,7 +86,6 @@ class Sizes:
     ann_rows: int
     ann_dim: int
     ann_queries: int
-    shard_cells: int        # --chips 4: cells per input of the sharded merge
 
 
 # what a user would call real: ~4x bench.py CONFIGS["stcs"], a 2^20-row scan
@@ -95,14 +94,12 @@ FULL = Sizes(wire_rows=3000, runs=4, run_cells=1 << 20, partitions=4096,
              ck_space=50_000, sample_partitions=8, min_device_rounds=8,
              min_full_segments=60, scan_rows=1 << 20,
              scan_partitions=1 << 14, scan_sstables=4, ann_rows=100_000,
-             ann_dim=128, ann_queries=5,
-             shard_cells=(1 << 19) - (1 << 15))
+             ann_dim=128, ann_queries=5)
 # the same control flow in seconds on the CPU (tests/test_chip_smoke.py)
 TINY = Sizes(wire_rows=40, runs=4, run_cells=1 << 15, partitions=64,
              ck_space=20_000, sample_partitions=3, min_device_rounds=1,
              min_full_segments=1, scan_rows=1 << 12, scan_partitions=1 << 8,
-             scan_sstables=2, ann_rows=300, ann_dim=16, ann_queries=2,
-             shard_cells=1 << 11)
+             scan_sstables=2, ann_rows=300, ann_dim=16, ann_queries=2)
 
 
 class SmokeFailure(Exception):
@@ -704,77 +701,6 @@ def phase_mesh_compact(rng, sz: Sizes, scratch: str, led: Ledger,
             "peak_bytes_in_use": peaks}
 
 
-def phase_sharded_merge(rng, sz: Sizes, led: Ledger, chips: int) -> dict:
-    """parallel/mesh.py against cellbatch.merge_sorted: the per-device
-    dispatch path (materialize_sharded_merge / run_sharded_merge) and the
-    one-program shard_map step whose stats are psum'd across the mesh."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from cassandra_tpu.parallel.mesh import (make_mesh,
-                                             materialize_sharded_merge,
-                                             shard_batch,
-                                             sharded_merge_step)
-    from cassandra_tpu.schema import make_table
-    from cassandra_tpu.storage import cellbatch as cb
-    from cassandra_tpu.tools import bulk
-    table = make_table("smoke", "shard", pk=["id"], ck=["c"],
-                       cols={"id": "int", "c": "int", "v": "blob"})
-    runs = []
-    for _ in range(2):
-        n = sz.shard_cells
-        runs.append(cb.merge_sorted([bulk.build_int_batch(
-            table, rng.integers(0, sz.partitions, n),
-            rng.integers(1, sz.ck_space, n),
-            rng.integers(0, 256, (n, 16), dtype=np.uint8),
-            rng.integers(1, 1 << 40, n).astype(np.int64))]))
-    cat = cb.CellBatch.concat(runs)
-    ref = cb.merge_sorted(runs)
-    mesh = make_mesh(chips)
-    led.mark()
-
-    t0 = time.perf_counter()
-    walls: list = []
-    shards = materialize_sharded_merge(cat, mesh, walls_out=walls)
-    t_dispatch = time.perf_counter() - t0
-    merged = cb.CellBatch.concat([x for x in shards if len(x)])
-    for col in ("lanes", "ts", "flags", "off", "payload"):
-        require(np.array_equal(getattr(merged, col), getattr(ref, col)),
-                f"sharded merge: {col} differs from merge_sorted")
-    require(sum(1 for w in walls if w > 0) == chips,
-            f"sharded merge: device walls {walls}: not every device ran")
-
-    # the shard_map program: keep masks per shard + psum'd (kept, dropped)
-    operands, _shard_of, _pos, members = shard_batch(cat, chips)
-    arr = NamedSharding(mesh, P("shard"))
-    rep = NamedSharding(mesh, P())
-    placed = {k: jax.device_put(v, rep if k in ("gc_before", "now")
-                                else arr) for k, v in operands.items()}
-    t1 = time.perf_counter()
-    perm, packed, stats = sharded_merge_step(mesh)(placed)
-    stats = np.asarray(stats)
-    t_step = time.perf_counter() - t1
-    require(len(packed.sharding.device_set) == chips,
-            "sharded step: output not spread over every device")
-    require(int(stats[0]) == len(ref)
-            and int(stats[1]) == len(cat) - len(ref),
-            f"sharded step: psum'd stats {stats.tolist()} != "
-            f"({len(ref)}, {len(cat) - len(ref)})")
-    keep = (np.asarray(packed) & 1).astype(bool)
-    perm = np.asarray(perm)
-    picked = np.concatenate([members[s][perm[s][keep[s]]]
-                             for s in range(chips)])
-    got = cat.apply_permutation(picked)
-    for col in ("lanes", "ts", "payload"):
-        require(np.array_equal(getattr(got, col), getattr(ref, col)),
-                f"sharded step: {col} differs from merge_sorted")
-    return {"cells_in": len(cat), "cells_kept": len(ref),
-            "per_device_dispatch_s": round(t_dispatch, 3),
-            "device_walls_s": [round(w, 3) for w in walls],
-            "shard_map_step_s": round(t_step, 3),
-            "psum_stats": stats.tolist(), "programs": led.programs()}
-
-
 # ------------------------------------------------------------------ run --
 
 def run(seed: int = 0, chips: int = 1, sizes: Sizes = FULL,
@@ -829,9 +755,7 @@ def run(seed: int = 0, chips: int = 1, sizes: Sizes = FULL,
         else:
             phases = (
                 ("mesh_compact", lambda: phase_mesh_compact(
-                    rng, sizes, scratch, led, chips)),
-                ("sharded_merge", lambda: phase_sharded_merge(
-                    rng, sizes, led, chips)))
+                    rng, sizes, scratch, led, chips)),)
         for phase, fn in phases:
             t0 = time.perf_counter()
             out = fn()

@@ -177,8 +177,9 @@ def _reference(operands):
     o = {k: np.asarray(v) for k, v in operands.items()}
     keys = [np.asarray(k) for k in dmerge._sort_keys(operands)]
     perm = np.lexsort(keys[::-1]).astype(np.int32)    # last key = primary
-    packed = np.asarray(dmerge.reconcile_kernel(operands,
-                                                jnp.asarray(perm)))
+    packed = np.asarray(dmerge.reconcile_sorted(
+        dmerge.reconcile_columns(operands, jnp.asarray(perm)),
+        operands["now"], operands["gc_before"]))
     keep, amb, expired, _ = dmerge.unpack_masks(packed)
     order = np.argsort(~keep, kind="stable")
     perm_out = perm[order]
@@ -270,22 +271,20 @@ def test_the_ttl_round_keeps_and_converts_what_the_host_spec_does():
 
 
 def test_valid_rows_need_not_start_at_row_zero():
-    """A mesh shard's valid rows start anywhere: the question a pass
-    asks is masked by `valid`, never read off row 0."""
+    """Valid rows may start anywhere: the question a pass asks is masked
+    by `valid`, never read off row 0."""
     rng = np.random.default_rng(5)
     lanes, ts = _standard1(600, rng)
     operands, _ = dw.build_resident_operands(_batch(lanes, ts), 0, 0, None)
     roll = lambda a: jnp.roll(a, 300, axis=0)
     rolled = {k: (roll(v) if getattr(v, "ndim", 0) else v)
               for k, v in operands.items()}
-    perm, passes = dmerge.device_sort_perm(rolled)
+    got = dw._resident_program(rolled)
     keys = [np.asarray(k) for k in dmerge._sort_keys(rolled)]
-    np.testing.assert_array_equal(np.asarray(perm),
+    np.testing.assert_array_equal(np.asarray(got[6]),
                                   np.lexsort(keys[::-1]))
-    assert int(passes) == 7
-    perm2, packed = dmerge.merge_reconcile_kernel(rolled)
-    np.testing.assert_array_equal(np.asarray(perm2), np.asarray(perm))
-    assert int((np.asarray(packed) & 1).sum()) == 600
+    assert int(got[3]) == 7
+    assert int(got[0]) == int((np.asarray(got[7]) & 1).sum()) == 600
 
 
 @pytest.mark.parametrize("collect", ["write_lane", "host_batch"])

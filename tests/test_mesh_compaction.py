@@ -1,21 +1,25 @@
 """Mesh execution mode of the data plane (docs/multichip.md): mesh
-compaction byte-identity vs the serial path, adversarial shard
-completion orders, corrupt-input quarantine under mesh mode,
-boundary-planning balance on skewed inputs, mesh batched reads /
-range scans, knob hot-reload, and sim determinism."""
+compaction byte-identity vs the serial path (host engines, and the
+device engine's lanes up to eight jax devices), the host tie-break on a
+lane, adversarial shard completion orders, an all-or-nothing round
+when a shard's writer fails, corrupt-input quarantine under mesh mode,
+boundary-planning balance on landed and skewed inputs, mesh batched
+reads / range scans, knob hot-reload, and sim determinism."""
 import importlib.util
 import os
+import time
 
+import jax
 import numpy as np
 import pytest
 
 from cassandra_tpu.compaction.task import CompactionTask
 from cassandra_tpu.parallel import fanout
-from cassandra_tpu.parallel.mesh import (boundaries_from_indexes,
-                                         boundaries_to_ranges,
-                                         distinct_token_weights,
-                                         plan_token_boundaries,
-                                         shard_imbalance)
+from cassandra_tpu.parallel.boundaries import (boundaries_from_indexes,
+                                               boundaries_to_ranges,
+                                               plan_token_boundaries,
+                                               shard_imbalance)
+from cassandra_tpu.schema import COL_REGULAR_BASE, make_table
 from cassandra_tpu.storage import cellbatch as cb
 from cassandra_tpu.storage.cellbatch import content_digest
 from cassandra_tpu.storage.sstable import Descriptor, SSTableWriter
@@ -49,15 +53,6 @@ def _mesh_off_after():
     faultfs.disarm()
 
 
-def _seed_sstables(cfs, table, n=40_000, gens=(1, 2, 3)):
-    for gen in gens:
-        w = SSTableWriter(Descriptor(cfs.directory, gen), table,
-                          estimated_partitions=256)
-        w.append(_ab()._mixed_batch(table, seed=gen, n=n))
-        w.finish()
-    cfs.reload_sstables()
-
-
 # ------------------------------------------------- boundary planning --
 
 def test_plan_boundaries_balances_skewed_weights():
@@ -81,18 +76,90 @@ def test_plan_boundaries_balances_skewed_weights():
     assert sizes.min() > 0
 
 
-def test_distinct_weights_collapse_duplicates():
-    """Weighting by raw cells overweights duplicate-heavy partitions;
-    the planner weight source must count distinct identities (what
-    survives the merge)."""
-    table = _ab()._mk_table("w")
-    b1 = _ab()._mixed_batch(table, seed=1, n=20_000)
-    # duplicate the whole batch: raw cells double, distinct must not
-    cat = cb.CellBatch.concat([b1, b1])
-    uniq, w = distinct_token_weights(cat)
-    assert int(w.sum()) == len(np.unique(
-        np.ascontiguousarray(b1.lanes.astype(">u4"))
-        .view(f"S{4 * b1.n_lanes}").ravel()))
+def test_mesh_really_has_8_devices():
+    assert len(jax.devices()) >= 8, jax.devices()
+    assert jax.default_backend() == "cpu"
+
+
+T = make_table("ks", "t", pk=["id"], ck=["c"],
+               cols={"id": "int", "c": "int", "v": "text"})
+IDT = T.columns["id"].cql_type
+
+
+def _workload(n_parts=40, n_cks=5, gens=3):
+    """One batch a generation: every (partition, row) written again in
+    each, with a newer timestamp."""
+    batches = []
+    for g in range(gens):
+        b = cb.CellBatchBuilder(T)
+        for p in range(n_parts):
+            for c in range(n_cks):
+                b.add_cell(IDT.serialize(p), T.serialize_clustering([c]),
+                           COL_REGULAR_BASE, f"g{g}".encode(), 100 + g)
+        batches.append(b.seal())
+    return batches
+
+
+def _skewed_workload(n=60_000, seed=9):
+    """~40% of the cells in 2 hot partitions, the rest over 2,046."""
+    from cassandra_tpu.tools import bulk
+    rng = np.random.default_rng(seed)
+    hot = rng.random(n) < 0.4
+    pk = np.where(hot, rng.integers(0, 2, n), rng.integers(2, 2048, n))
+    return [bulk.build_int_batch(
+        T, pk, rng.integers(0, 10_000, n),
+        np.full((n, 1), ord("v"), dtype=np.uint8),
+        np.full(n, 100, dtype=np.int64))]
+
+
+def _land(cfs, batches, table=T):
+    """Each batch as one sstable of the store, generations 1, 2, ..."""
+    for gen, batch in enumerate(batches, start=1):
+        w = SSTableWriter(Descriptor(cfs.directory, gen), table,
+                          estimated_partitions=256)
+        w.append(cb.merge_sorted([batch]))
+        w.finish()
+    cfs.reload_sstables()
+    return cfs.tracker.view()
+
+
+@pytest.mark.parametrize("workload,max_imbalance,min_share", [
+    pytest.param(lambda: _workload(n_parts=100, n_cks=3, gens=2),
+                 None, None, id="no_token_split"),
+    pytest.param(lambda: _workload(n_parts=200, n_cks=4, gens=1),
+                 3.0, None, id="uniform_balance"),
+    # the hot partitions are unsplittable (~20% of the cells each, 1.6x
+    # the 1/8 mean), so max/mean ~1.6 is the floor: the shards around
+    # them re-balance instead of starving
+    pytest.param(_skewed_workload, 2.0, 1 / 3, id="skewed_balance"),
+])
+def test_index_boundaries_on_landed_workloads(tmp_path, workload,
+                                              max_imbalance, min_share):
+    """The mesh lanes' planner over landed sstables: every token's
+    cells fall in exactly one shard's range, the ranges cover every
+    cell, and the shards' input cells stay within the case's spread."""
+    cfs = ColumnFamilyStore(T, str(tmp_path), commitlog=None)
+    readers = _land(cfs, workload())
+    bounds = boundaries_from_indexes(readers, 8)
+    assert bounds is not None and len(bounds) == 7
+    counts, owner = [], {}
+    for s, (lo, hi) in enumerate(boundaries_to_ranges(bounds, 8)):
+        n = 0
+        for r in readers:
+            w = r.scan_tokens(lo, hi)
+            if w is None or not len(w):
+                continue
+            n += len(w)
+            tok = (w.lanes[:, 0].astype(np.uint64) << np.uint64(32)) \
+                | w.lanes[:, 1].astype(np.uint64)
+            for t in np.unique(tok).tolist():
+                assert owner.setdefault(t, s) == s, (t, owner[t], s)
+        counts.append(n)
+    assert sum(counts) == sum(r.n_cells for r in readers)
+    if max_imbalance is not None:
+        assert shard_imbalance(counts) <= max_imbalance, counts
+    if min_share is not None:
+        assert min(counts) >= min_share * np.mean(counts), counts
 
 
 def test_boundaries_from_indexes_skewed_fixture(tmp_path):
@@ -133,8 +200,9 @@ def test_boundaries_from_indexes_skewed_fixture(tmp_path):
     # max-combine per-sstable distinct counts), so the kept-cell spread
     # floor on this adversarial fixture is ~1.35 — still 15x better
     # than the 21x the single-batch sample produced (MULTICHIP_r05).
-    # The exact-weight planner path is pinned at <= 1.2 by the
-    # multichip entry sweep (__graft_entry__._dryrun_inner).
+    # The input-cell spread of the lanes' own shards is pinned on the
+    # skewed 1M-cell round by the multichip entry
+    # (__graft_entry__._dryrun_inner).
     assert shard_imbalance(sizes) <= 1.5, sizes
 
 
@@ -148,7 +216,10 @@ def test_boundaries_from_indexes_skewed_fixture(tmp_path):
     # (merge.resident), one jax device per lane, against the numpy spec
     {"serial": dict(engine="numpy", mesh_devices=0),
      "device_mesh2": dict(engine="device", mesh_devices=2)},
-], ids=["host", "device"])
+    # a lane on each of eight jax devices
+    {"serial": dict(engine="numpy", mesh_devices=0),
+     "device_mesh8": dict(engine="device", mesh_devices=8)},
+], ids=["host", "device", "device8"])
 def test_mesh_compaction_byte_identity(tmp_path, legs):
     """serial vs mesh-1 vs mesh-4: sha256-identical components and
     equal merged-view digests — the mesh drains shard results in token
@@ -172,6 +243,96 @@ def test_mesh_compaction_byte_identity(tmp_path, legs):
         assert hashes == ref_hashes, (tag, sorted(
             k for k in hashes if hashes[k] != ref_hashes.get(k)))
         assert digest == ref_digest, tag
+
+
+def test_equal_ts_tombstone_wins_on_a_device_lane(tmp_path,
+                                                  monkeypatch):
+    """The device sort does not order a live cell and a tombstone of
+    the same identity and timestamp: the lane's round resolves the tie
+    on the host (materialize_round), and the tombstone wins, as the
+    serial numpy engine has it."""
+    ab = _ab()
+    table = ab._mk_table("meshtie")
+    vcol = table.columns["v"].column_id
+    pk = table.serialize_partition_key([7])
+    ck = table.serialize_clustering([1])
+    b = cb.CellBatchBuilder(table)
+    b.add_cell(pk, ck, vcol, b"live", 100)
+    t = cb.CellBatchBuilder(table)
+    # deleted now: inside gc_grace, so the winner is kept, not purged
+    t.add_tombstone(pk, ck, vcol, 100, int(time.time()))
+    tomb = t.seal()
+    pristine = os.path.join(str(tmp_path), "pristine")
+    cfs = ColumnFamilyStore(table, pristine, commitlog=None)
+    _land(cfs, [cb.CellBatch.concat(
+        [ab._mixed_batch(table, seed=1, n=20_000), b.seal()]),
+        ab._mixed_batch(table, seed=2, n=20_000), tomb], table)
+    ref_hashes, ref_digest = ab._compaction_leg(
+        str(tmp_path), pristine, table, "serial", engine="numpy",
+        mesh_devices=0)
+
+    leg = os.path.join(str(tmp_path), "lanes")
+    import shutil
+    shutil.copytree(pristine, leg)
+    cfs2 = ColumnFamilyStore(table, leg, commitlog=None)
+    cfs2.reload_sstables()
+    from cassandra_tpu.ops import merge as dmerge
+    ties = []
+    real_tiebreak = dmerge.host_tiebreak
+
+    def tiebreak(cat, perm, keep, amb, *args):
+        ties.append(int(amb.sum()))
+        return real_tiebreak(cat, perm, keep, amb, *args)
+
+    monkeypatch.setattr(dmerge, "host_tiebreak", tiebreak)
+    task = CompactionTask(cfs2, cfs2.tracker.view(), engine="device",
+                          mesh_devices=4)
+    task.execute()
+    assert len({d.id for d in task.mesh_lane_devices}) == 4
+    assert ties == [1]      # one lane's round held the one tie
+    assert ab._component_hashes(cfs2.directory) == ref_hashes
+    assert ab._scan_digest(cfs2) == ref_digest
+    [out] = cfs2.live_sstables()
+    got = cb.CellBatch.concat(list(out.scanner()))
+    key = np.flatnonzero((got.lanes == tomb.lanes[0]).all(axis=1))
+    assert len(key) == 1
+    assert got.flags[key[0]] & cb.FLAG_TOMBSTONE, "live cell beat tombstone"
+    out.close()
+
+
+def test_failed_shard_write_leaves_no_partial_round(tmp_path, monkeypatch):
+    """A sharded task's third output shard fails to finish mid-compaction
+    under four mesh lanes: the round is all-or-nothing
+    (LifecycleTransaction) — no output sstable survives, not even the
+    shards finished before it, and every input stays live."""
+    from cassandra_tpu.storage.sstable import writer as writer_mod
+
+    ab = _ab()
+    table = ab._mk_table("meshfail")
+    cfs = ColumnFamilyStore(table, str(tmp_path), commitlog=None)
+    inputs = _land(cfs, [ab._mixed_batch(table, seed=g, n=20_000)
+                         for g in (1, 2)], table)
+    before = sorted(os.listdir(cfs.directory))
+    digest = ab._scan_digest(cfs)
+    calls = {"n": 0}
+    real_finish = writer_mod.SSTableWriter.finish
+
+    def failing_finish(self):
+        calls["n"] += 1
+        if calls["n"] == 3:          # the third shard's commit blows up
+            raise OSError("injected shard write failure")
+        return real_finish(self)
+
+    monkeypatch.setattr(writer_mod.SSTableWriter, "finish", failing_finish)
+    ring = [-(1 << 63) + i * (1 << 62) for i in range(4)]
+    task = CompactionTask(cfs, inputs, mesh_devices=4,
+                          shard_boundaries=ring)
+    with pytest.raises(OSError, match="injected"):
+        task.execute()
+    assert calls["n"] == 3 and task._mesh_completion_order
+    assert sorted(os.listdir(cfs.directory)) == before
+    assert sorted(r.desc.generation for r in cfs.live_sstables()) == [1, 2]
+    assert ab._scan_digest(cfs) == digest
 
 
 def test_mesh_adversarial_completion_order(tmp_path):
@@ -312,7 +473,8 @@ def test_mesh_deterministic_under_sim(tmp_path):
 def _read_fixture(tmp_path, n=30_000):
     table = _ab()._mk_table("meshread")
     cfs = ColumnFamilyStore(table, str(tmp_path), commitlog=None)
-    _seed_sstables(cfs, table, n=n)
+    _land(cfs, [_ab()._mixed_batch(table, seed=g, n=n) for g in (1, 2, 3)],
+          table)
     return cfs, table
 
 

@@ -16,13 +16,11 @@ chip cannot be read back without one. x64 stays off, as in production.
 """
 import time
 
-import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import SingleDeviceSharding
 
 ROUND = 1 << 19          # one ROUND_CELLS_DEVICE round, padded (task.py)
 SEGMENT = 1 << 16        # storage/sstable/format.py SEGMENT_CELLS
@@ -38,7 +36,6 @@ REPORT: list = []
 # figures: (compile seconds, code MB, temp MB) with the twenty separate
 # gathers outside the passes (eighteen of them one-lane)
 BEFORE = {"merge.resident": (36.1, 171.6, 36.1),
-          "merge.sharded_step": (26.5, 76.8, 89.2),
           # the serialize while it laid out the block's bytes itself
           "write.serialize": (1.4, 1.2, 2.5)}
 # merge.resident's temporaries at 2^19 x 13 as the compiler counts them:
@@ -89,32 +86,34 @@ def _compile(name, jitted, *args, **kwargs):
     return compiled
 
 
-def _resident_operands(sh, n=ROUND, k=LANES, lead=()):
+def _resident_operands(sh, n=ROUND, k=LANES):
     """ops/device_write.build_resident_operands' shapes."""
     def a(dt, *tail):
-        return jax.ShapeDtypeStruct(lead + (n,) + tail, dt, sharding=sh)
+        return jax.ShapeDtypeStruct((n,) + tail, dt, sharding=sh)
     u32, i32 = jnp.uint32, jnp.int32
-    ops = {"lanes": a(u32, k), "valid": a(u32), "ts_h": a(u32),
-           "ts_l": a(u32), "death": a(u32), "cdel": a(u32),
-           "ldt": a(i32), "expiring": a(u32), "purge_h": a(u32),
-           "purge_l": a(u32)}
-    return ops, a
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=sh)
+    return {"lanes": a(u32, k), "valid": a(u32), "ts_h": a(u32),
+            "ts_l": a(u32), "death": a(u32), "cdel": a(u32),
+            "ldt": a(i32), "expiring": a(u32), "purge_h": a(u32),
+            "purge_l": a(u32), "gc_before": scalar, "now": scalar,
+            "flags8": a(jnp.uint8), "ttl": a(i32), "fl": a(u32),
+            "vr": a(u32)}
+
+
+def _compile_resident(name, sh):
+    from cassandra_tpu.ops.device_write import _resident_program
+    return _compile(name, _resident_program, _resident_operands(sh))
 
 
 def test_lsd_pass_round(one_chip):
     from cassandra_tpu.ops.merge import _lsd_pass
     key = jax.ShapeDtypeStruct((ROUND,), jnp.uint32, sharding=one_chip)
     perm = jax.ShapeDtypeStruct((ROUND,), jnp.int32, sharding=one_chip)
-    _compile("merge.lsd_pass", _lsd_pass, key, perm)
+    _compile("_lsd_pass", _lsd_pass, key, perm)
 
 
 def test_resident_round(one_chip):
-    from cassandra_tpu.ops.device_write import _resident_program
-    ops, a = _resident_operands(one_chip)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    ops.update(gc_before=scalar, now=scalar, flags8=a(jnp.uint8),
-               ttl=a(jnp.int32), fl=a(jnp.uint32), vr=a(jnp.uint32))
-    compiled = _compile("merge.resident", _resident_program, ops)
+    compiled = _compile_resident("merge.resident", one_chip)
     # every pass but `valid`'s sits under its own skip
     assert compiled.as_text().count(" conditional(") == LANES + 2
     n_passes = compiled.out_info[3]
@@ -223,16 +222,13 @@ def test_ann_live_programs_at_the_streaming_cells_size(one_chip):
              a(UNSET_SLOTS, dt=jnp.int32))
 
 
-def test_sharded_merge_step_four_chips(topo):
-    """The one-program multi-chip step (shard_map + psum): one round
-    split over a 4-device mesh built from the described devices."""
-    from cassandra_tpu.parallel.mesh import sharded_merge_step
-    mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
-    arr = NamedSharding(mesh, P("shard"))
-    rep = NamedSharding(mesh, P())
-    ops, _ = _resident_operands(arr, n=ROUND // 4, lead=(4,))
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
-    ops.update(gc_before=scalar, now=scalar)
-    compiled = _compile("merge.sharded_step", sharded_merge_step(mesh),
-                        ops)
-    assert "all-reduce" in compiled.as_text()   # the psum is a collective
+def test_resident_round_on_a_lane_device(topo):
+    """A mesh lane commits its shard's round to its own device
+    (compaction/task.py `_mesh_produce`): the last of four lanes compiles
+    the one-chip program with its operands on device 3."""
+    d = topo.devices[3]
+    compiled = _compile_resident("merge.resident@3",
+                                 SingleDeviceSharding(d))
+    (ops,), _ = compiled.input_shardings
+    assert all(sh.device_set == {d} for sh in ops.values())
+    assert compiled.as_text().count(" conditional(") == LANES + 2
