@@ -23,6 +23,7 @@ bit-identical merge engines:
 from __future__ import annotations
 
 import bisect
+import collections
 import logging
 import threading
 import time
@@ -450,7 +451,8 @@ class CompactionTask:
         identical to the serial path for every N (token-range shard
         order IS identity-lane order — no reshuffle). None = inherit
         the `compaction_mesh_devices` knob (parallel/fanout.py);
-        0 = force serial.
+        0 = force serial. Mesh lanes place shard s on jax device s mod
+        N whether or not the owning engine is pinned to a device.
         device_compress: device-side block compression for the
         device-resident lane's full segments (ops/device_compress.py)
         — the fused policy-scan kernel compresses META + lanes on the
@@ -465,6 +467,10 @@ class CompactionTask:
         """
         self.cfs = cfs
         self.inputs = inputs
+        # the owning engine's jax device (config `device_ordinal`, bound
+        # at engine start): the serial round loop's rounds and its write
+        # lane are committed to it. None = jax's default device
+        self.device = getattr(cfs, "jax_device", None)
         self.max_output_bytes = max_output_bytes
         self.shard_boundaries = sorted(shard_boundaries) \
             if shard_boundaries else None
@@ -780,10 +786,14 @@ class CompactionTask:
                     if devices is not None:
                         # the serial loop's program, one device per
                         # lane; shards drain as host CellBatches
-                        merged = materialize_round(submit_merge_resident(
+                        h = submit_merge_resident(
                             slices, gc_before=gc_before, now=now,
                             purgeable_ts_fn=controller.purgeable_ts_fn,
-                            device=devices[s % n_devices]))
+                            device=devices[s % n_devices])
+                        if h.mode == "resident":
+                            with prof_lock:
+                                self.rounds_by_device[h.dev] += 1
+                        merged = materialize_round(h)
                     else:
                         merged = merge_shard(slices, shard_prof)
                 walls[s] = time.perf_counter() - t1
@@ -979,6 +989,11 @@ class CompactionTask:
         now = timeutil.now_seconds()
         controller = CompactionController(cfs, self.inputs)
         prof = self.profile
+        # merge.resident rounds and write-lane programs by the id of the
+        # device they ran on (the stats' `rounds_by_device`,
+        # `lane_dispatches_by_device`)
+        self.rounds_by_device = collections.Counter()
+        self.lane_dispatches_by_device = collections.Counter()
         # pipeline `compaction` (docs/observability.md): `decode` —
         # cursor fetches (inline AND decode-ahead) bill busy, the merge
         # thread's prefetch waits stall, the prefetch thread's parked
@@ -1049,6 +1064,7 @@ class CompactionTask:
             if lane is not None:
                 lane.flush()
                 wstate["lane"] = None
+                self.lane_dispatches_by_device[lane.dev] += lane.dispatches
 
         def roll():
             # finish the writer and open the next: the lane's pending
@@ -1114,7 +1130,8 @@ class CompactionTask:
                     if wstate["resident"]:
                         lane = wstate["lane"]
                         if lane is None:
-                            lane = wstate["lane"] = DeviceWriteLane(w)
+                            lane = wstate["lane"] = DeviceWriteLane(
+                                w, self.device)
                         lane.append(merged)
                     else:
                         w.append(merged)
@@ -1322,10 +1339,14 @@ class CompactionTask:
                 if self.limiter is not None:
                     self.limiter.acquire(round_bytes)
                 if self.engine == "device":
-                    pending.append((shard, submit_merge_resident(
+                    h = submit_merge_resident(
                         slices, gc_before=gc_before, now=now,
                         purgeable_ts_fn=controller.purgeable_ts_fn,
-                        prof=prof, min_bucket=self.min_bucket)))
+                        prof=prof, device=self.device,
+                        min_bucket=self.min_bucket)
+                    if h.mode == "resident":
+                        self.rounds_by_device[h.dev] += 1
+                    pending.append((shard, h))
                     while len(pending) >= self.PIPELINE_DEPTH:
                         collect_oldest()
                 else:
@@ -1431,6 +1452,9 @@ class CompactionTask:
             # what merged, and whether the task chose it itself
             "engine": self.engine,
             "engine_chosen": self.engine_chosen,
+            # where its device programs ran: {device id: calls}
+            "rounds_by_device": dict(self.rounds_by_device),
+            "lane_dispatches_by_device": dict(self.lane_dispatches_by_device),
         }
         # history ring + amplification counters in one locked fold
         # (storage/table.py record_compaction: the append shares a

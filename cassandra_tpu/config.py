@@ -136,6 +136,14 @@ class Config:
     # threads for the native/numpy engines). Output bytes are
     # identical to the serial paths for any N. 0 = off; hot-reloadable.
     compaction_mesh_devices: int = mut(0)
+    # the jax device this node's engine runs every device program on
+    # (docs/multichip.md, engine pinning): its ordinal in jax.devices().
+    # -1 = jax's default device. Read once at engine start, so not
+    # hot-reloadable; an ordinal jax does not see fails the start. A
+    # pinned engine's compaction rounds, write-lane programs, vector
+    # index and scan kernels are committed to that device; mesh lanes
+    # (compaction_mesh_devices) still place their shards by lane.
+    device_ordinal: int = -1
     # decode-ahead prefetch: a compaction helper thread decodes round
     # k+1's input segments while round k merges and its output
     # compresses (the LUDA decode/merge overlap; compaction/task.py).

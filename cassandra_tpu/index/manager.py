@@ -316,6 +316,10 @@ class VectorIndex(_AttachedIndex):
         self._epoch = 0          # bumped by every reset
         from ..storage.cellbatch import DEATH_FLAGS
         self._death_flags = DEATH_FLAGS
+        # the engine's jax device (config `device_ordinal`): the
+        # resident rows live there and its programs run there. None =
+        # jax's default device
+        self.device = getattr(backend, "device", None)
 
     def _build(self, reader):
         ssi.build_vector(reader, self.table, self.col_id, self.dim)
@@ -469,14 +473,17 @@ class VectorIndex(_AttachedIndex):
         if e.dcap and cap > max(e.nb, 1 << 16):
             return False
         grow = cap - e.dcap
+        dev = self.device
         if e.delta is None:
-            e.delta = jnp.zeros((cap, self.dim), jnp.float32)
-            e.vd = jnp.zeros(cap, bool)
-            e.vb = jnp.ones(e.nb, bool)
+            e.delta = jnp.zeros((cap, self.dim), jnp.float32, device=dev)
+            e.vd = jnp.zeros(cap, bool, device=dev)
+            e.vb = jnp.ones(e.nb, bool, device=dev)
         else:
             e.delta = jnp.concatenate(
-                [e.delta, jnp.zeros((grow, self.dim), jnp.float32)])
-            e.vd = jnp.concatenate([e.vd, jnp.zeros(grow, bool)])
+                [e.delta, jnp.zeros((grow, self.dim), jnp.float32,
+                                    device=dev)])
+            e.vd = jnp.concatenate([e.vd, jnp.zeros(grow, bool,
+                                                    device=dev)])
         e.ts = np.concatenate([e.ts, np.zeros(grow, np.int64)])
         e.owner = np.concatenate([e.owner, np.full(grow, MEM, np.int64)])
         e.alive = np.concatenate([e.alive, np.zeros(grow, bool)])
@@ -728,7 +735,7 @@ class VectorIndex(_AttachedIndex):
                 import jax
                 with _LED_ANN.busy("index.ann.upload", cells=len(m),
                                    nbytes=m.nbytes):
-                    dev = jax.device_put(m)
+                    dev = jax.device_put(m, self.device)
                     dev.block_until_ready()
                 entry = _Resident(similarity, dev, keys, ts, owner,
                                   dead, pdead,

@@ -434,16 +434,27 @@ def _split(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
             (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32))
 
 
-def mask_device(keys: np.ndarray, pred: CompiledPredicate) -> np.ndarray:
-    """Predicate mask evaluated by the jitted kernel. Bit-identical to
-    mask_host for any input (the AB check pins it)."""
+def _on(device, *arrays):
+    """The operands committed to `device` (None: left to jax's default
+    device, as numpy)."""
+    if device is None:
+        return arrays
+    import jax
+    return jax.device_put(arrays, device)
+
+
+def mask_device(keys: np.ndarray, pred: CompiledPredicate,
+                device=None) -> np.ndarray:
+    """Predicate mask evaluated by the jitted kernel, on `device` (None
+    = jax's default). Bit-identical to mask_host for any input (the AB
+    check pins it)."""
     kernels, _ = _kernels()
     hi, lo = _split(keys)
     qhi, qlo = _split(pred.qkeys if len(pred.qkeys)
                       else np.zeros(1, dtype=np.uint64))
     if pred.kop == "in" and len(pred.qkeys) == 0:
         return np.zeros(len(keys), dtype=bool)
-    out = kernels[pred.kop](hi, lo, qhi, qlo)
+    out = kernels[pred.kop](*_on(device, hi, lo, qhi, qlo))
     return np.asarray(out, dtype=bool)
 
 
@@ -481,12 +492,14 @@ def nan_fix(mask: np.ndarray, keys: np.ndarray,
 
 
 def segment_mask(keys: np.ndarray, pred: CompiledPredicate,
-                 use_device: bool) -> tuple[np.ndarray, bool]:
-    """(mask, ran_on_device). The device leg falls back PER SEGMENT on
-    any kernel failure — counted by the caller, results identical."""
+                 use_device: bool, device=None) -> tuple[np.ndarray, bool]:
+    """(mask, ran_on_device). The device leg (on `device`, None = jax's
+    default) falls back PER SEGMENT on any kernel failure — counted by
+    the caller, results identical."""
     if use_device:
         try:
-            return nan_fix(mask_device(keys, pred), keys, pred), True
+            return nan_fix(mask_device(keys, pred, device), keys,
+                           pred), True
         except Exception as e:
             warn_once(_log, "scan.mask.fallback",
                       "device scan mask kernel failed, host numpy "
@@ -532,10 +545,12 @@ def batch_predicate_cells(batch, pred: CompiledPredicate,
     return sel, keys
 
 
-def fold_batch(batch, pred: CompiledPredicate, use_device: bool
+def fold_batch(batch, pred: CompiledPredicate, use_device: bool,
+               device=None
                ) -> tuple[int, int | None, int | None, int, bool]:
     """Exact aggregate partials over a RECONCILED batch: (count,
-    min_key, max_key, int_sum, ran_on_device). Only called for exact
+    min_key, max_key, int_sum, ran_on_device); the kernels run on
+    `device` (None = jax's default). Only called for exact
     predicate kinds, so the mask equals `_match` row for row; the i64
     sum is exact because the executor only pushes SUM/AVG for integer
     widths <= 4 bytes (no 64-bit overflow for any realistic row count).
@@ -548,8 +563,8 @@ def fold_batch(batch, pred: CompiledPredicate, use_device: bool
         try:
             _, fold = _kernels()
             hi, lo = _split(keys)
-            mask = nan_fix(mask_device(keys, pred), keys, pred)
-            cnt, mnh, mnl, mxh, mxl = fold(hi, lo, mask)
+            mask = nan_fix(mask_device(keys, pred, device), keys, pred)
+            cnt, mnh, mnl, mxh, mxl = fold(*_on(device, hi, lo, mask))
             cnt = int(cnt)
             if cnt == 0:
                 return 0, None, None, 0, True

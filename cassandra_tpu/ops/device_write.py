@@ -76,6 +76,7 @@ from ..storage.cellbatch import (DEATH_FLAGS, FLAG_COUNTER,
 from ..storage.sstable.writer import build_meta_block, lay_meta_block
 from ..service.metrics import GLOBAL as _METRICS
 from ..service.profiling import GLOBAL as _kprof
+from ..service.profiling import device_id
 from ..utils import pipeline_ledger
 from ..utils.logonce import warn_once
 from . import device_compress
@@ -92,14 +93,21 @@ _BIAS_H = 0x80000000  # high u32 word of the 2^63 timestamp bias
 _LED_RESIDENT = pipeline_ledger.ledger("merge").stage("resident")
 
 
+def _device_tag(dev: int) -> str:
+    """The last component of a per-device counter: `dev<id>`."""
+    return f"dev{dev}"
+
+
 # ------------------------------------------------------------- operands --
 
 def build_resident_operands(cat: CellBatch, gc_before: int, now: int,
-                            purgeable_ts_fn, min_bucket: int = 0):
+                            purgeable_ts_fn, min_bucket: int = 0,
+                            device=None):
     """The kernel operands (merge.build_operands) extended with the
     serialize-side columns: full flags byte, ttl, u32 frame lengths and
     value offsets, padded to the round's bucket and to at least
-    min_bucket cells. Returns (operands, pts_host) or None when a frame
+    min_bucket cells, pushed to `device` in one transfer (None = jax's
+    default device). Returns (operands, pts_host) or None when a frame
     exceeds the u32 lanes (the host path raises its loud error
     instead)."""
     n = len(cat)
@@ -125,11 +133,8 @@ def build_resident_operands(cat: CellBatch, gc_before: int, now: int,
     fl[:n] = lens64.astype(np.uint32)
     vr = np.zeros(N, dtype=np.uint32)
     vr[:n] = vrel64.astype(np.uint32)
-    operands["flags8"] = jnp.asarray(flags8)
-    operands["ttl"] = jnp.asarray(ttl)
-    operands["fl"] = jnp.asarray(fl)
-    operands["vr"] = jnp.asarray(vr)
-    return operands, pts_host
+    operands.update(flags8=flags8, ttl=ttl, fl=fl, vr=vr)
+    return jax.device_put(operands, device), pts_host
 
 
 RESIDENT_COLS = ("lanes", "ts_h", "ts_l", "ldt", "ttl", "flags8",
@@ -327,12 +332,13 @@ class DeviceRound:
 
 
 class ResidentHandle:
-    """An in-flight round: mode "resident" (the program was dispatched,
-    `out` holds its outputs) or "done" (`result` already is the merged
-    host CellBatch: an empty round, or one the numpy spec merged)."""
+    """An in-flight round: mode "resident" (the program was dispatched
+    on device id `dev`, `out` holds its outputs) or "done" (`result`
+    already is the merged host CellBatch: an empty round, or one the
+    numpy spec merged)."""
 
     __slots__ = ("mode", "result", "cat", "n", "out", "pts",
-                 "gc_before", "now", "prof")
+                 "gc_before", "now", "prof", "device", "dev")
 
 
 def _resident_fallback(n: int, why: str) -> None:
@@ -397,11 +403,12 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     min_bucket: the least padded shape the program runs at (a partial
     round then shares the full rounds' compiled shapes).
 
-    device: an explicit jax.Device to commit the operands to (the mesh
-    compaction path places shard s's round on mesh device s); None =
-    the default device."""
+    device: an explicit jax.Device to commit the operands to (a pinned
+    engine's device, or mesh device s for shard s's round); None = the
+    default device. Each dispatched round counts under
+    `merge.resident.rounds.dev<id>`, the id of the device it ran on."""
     h = ResidentHandle()
-    h.gc_before, h.now, h.prof = gc_before, now, prof
+    h.gc_before, h.now, h.prof, h.device = gc_before, now, prof, device
     with _LED_RESIDENT.busy("merge.resident.concat") as sp:
         cat = CellBatch.concat(batches)
         sp.cells = len(cat)
@@ -417,12 +424,9 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     with _LED_RESIDENT.busy("merge.resident.pack", prof=prof, key="pack",
                             cells=h.n) as sp:
         built = build_resident_operands(cat, gc_before, now,
-                                        purgeable_ts_fn, min_bucket)
+                                        purgeable_ts_fn, min_bucket, device)
         if built is not None:
             operands, h.pts = built
-            if device is not None:
-                operands = {k: jax.device_put(v, device)
-                            for k, v in operands.items()}
             # items = the padded cell count the program runs at
             # (_bucket), nbytes = what the round pushes to the device
             sp.items = int(operands["lanes"].shape[0])
@@ -433,13 +437,14 @@ def submit_merge_resident(batches: list[CellBatch], gc_before: int = 0,
     with _LED_RESIDENT.busy("merge.resident.dispatch") as sp:
         h.out = _resident_program(operands)
     # jit compiles synchronously inside the dispatch call, per shape AND
-    # per device: the lane's device is part of the key, or lanes 2..n's
+    # per device: the device is part of the key, or a second device's
     # compiles read as warm dispatches
+    dev = h.dev = device_id(device)
+    _METRICS.incr(f"merge.resident.rounds.{_device_tag(dev)}")
     if _kprof.record_dispatch(
             "merge.resident",
             (int(operands["lanes"].shape[0]),
-             int(operands["lanes"].shape[1]),
-             getattr(device, "id", None)), sp.seconds):
+             int(operands["lanes"].shape[1]), dev), sp.seconds, dev):
         _kprof.maybe_record_cost("merge.resident", _resident_program,
                                  (operands,))
     h.mode = "resident"
@@ -455,7 +460,7 @@ def collect_merge_resident(h: ResidentHandle):
         time.sleep(_TEST_COLLECT_DELAY.get(_collect_seq, 0.0))
     _collect_seq += 1
     if h.mode == "done":
-        return promote_round(h.result)
+        return promote_round(h.result, h.device)
     cat, prof = h.cat, h.prof
     perm_out_d, cols = h.out[4:6]
     n_keep, n_amb, n_exp_kept = _wait(h)
@@ -467,7 +472,7 @@ def collect_merge_resident(h: ResidentHandle):
             # exact-resolution round: equal-(identity, ts) runs need the
             # host's full-value tie-break
             _resident_fallback(h.n, "equal-(identity, ts) ties")
-            return promote_round(_materialize(h))
+            return promote_round(_materialize(h), h.device)
 
         # resident round: pull ONLY the kept permutation (the payload
         # gather's index vector) — the columns stay on the device
@@ -524,9 +529,10 @@ def merge_sorted_device(batches: list[CellBatch], gc_before: int = 0,
         batches, gc_before, now, purgeable_ts_fn, prof))
 
 
-def promote_round(batch: CellBatch) -> DeviceRound:
+def promote_round(batch: CellBatch, device=None) -> DeviceRound:
     """Lift a host-materialized round (fallback rounds: ties, counters,
-    range bounds) onto the device so the write lane consumes ONE
+    range bounds) onto the device (`device`, None = the default one:
+    where the round's program ran) so the write lane consumes ONE
     ordered stream — interleaving host appends with device-pending
     cells would cut segments out of order. Values are
     copied verbatim, so the serialized bytes are identical to feeding
@@ -549,7 +555,7 @@ def promote_round(batch: CellBatch) -> DeviceRound:
     def padded(a, dtype):
         out = np.zeros((N,) + a.shape[1:], dtype=dtype)
         out[:n] = a
-        return jnp.asarray(out)
+        return jax.device_put(out, device)
     cols = {
         "lanes": padded(batch.lanes, np.uint32),
         "ts_h": padded(uts >> np.uint64(32), np.uint32),
@@ -615,11 +621,16 @@ class DeviceWriteLane:
     writer, lay_meta_block: one layout, so byte identity with the host
     serializer holds by construction (and is pinned by test).
     Counters: `write.lane.dispatches` (one a round plus one a full
-    segment) and `write.lane.segments` (every segment cut)."""
+    segment; and per device, `.dev<id>`) and `write.lane.segments`
+    (every segment cut). `device`: the jax device the lane's carry and
+    programs are committed to, its rounds' own (None = the default)."""
 
-    def __init__(self, writer):
+    def __init__(self, writer, device=None):
         from ..storage.sstable.format import SEGMENT_CELLS
         self.writer = writer
+        self.device = device
+        self.dev = device_id(device)
+        self.dispatches = 0               # programs this lane dispatched
         self.seg_cells = writer.segment_cells or SEGMENT_CELLS
         self.carry: dict | None = None    # device columns, seg_cells rows
         self.pending = 0                  # cells at the carry's end
@@ -639,14 +650,16 @@ class DeviceWriteLane:
                 # an empty carry shaped like the round's columns: a plain
                 # transfer, no program
                 self.carry = {k: jax.device_put(
-                    np.zeros((seg,) + v.shape[1:], dtype=v.dtype))
+                    np.zeros((seg,) + v.shape[1:], dtype=v.dtype),
+                    self.device)
                     for k, v in r.cols.items()}
             args = (self.carry, r.cols, np.int32(self.pending),
                     np.int32(r.n))
             t0 = time.perf_counter()
             segments, self.carry = _lane_append(*args)
-            if _kprof.record_dispatch("write.append", (N, K, seg),
-                                      time.perf_counter() - t0):
+            if _kprof.record_dispatch("write.append",
+                                      (N, K, seg, self.dev),
+                                      time.perf_counter() - t0, self.dev):
                 _kprof.maybe_record_cost("write.append", _lane_append,
                                          args)
             full, self.pending = divmod(self.pending + r.n, seg)
@@ -654,7 +667,10 @@ class DeviceWriteLane:
             # items = programs dispatched: this round's, one per full
             # segment it completed
             sp.items = 1 + full
+            self.dispatches += sp.items
             _METRICS.incr("write.lane.dispatches", sp.items)
+            _METRICS.incr(f"write.lane.dispatches.{_device_tag(self.dev)}",
+                          sp.items)
         # the host's side of the round: its payload frames queued, its
         # partition keys merged (one C-level update: a round of 2^19
         # cells brings ≈ 100,000 keys)
@@ -675,15 +691,14 @@ class DeviceWriteLane:
             self._cut(self.pending)
             self.pending = 0
 
-    @staticmethod
-    def _dispatch_cut(s: dict):
+    def _dispatch_cut(self, s: dict):
         """Dispatch one full segment's serialize (async)."""
         kargs = tuple(s[k] for k in _META_COLS)
         t0 = time.perf_counter()
         out = _meta_block_kernel(*kargs)
         if _kprof.record_dispatch("write.serialize",
-                                  (int(kargs[0].shape[0]),),
-                                  time.perf_counter() - t0):
+                                  (int(kargs[0].shape[0]), self.dev),
+                                  time.perf_counter() - t0, self.dev):
             _kprof.maybe_record_cost("write.serialize", _meta_block_kernel,
                                      kargs)
         return out
@@ -779,7 +794,8 @@ class DeviceWriteLane:
             # emission (O(sequences)) and the pwrite pump
             with w._span("compress", "busy", "write.lane.device_compress",
                          key="compress", cells=n):
-                dc_state = self._device_compress(n, meta, seg["lanes"])
+                dc_state = self._device_compress(n, meta, seg["lanes"],
+                                                 self.device)
         device_pack = None
         if dc_state is not None:
             planes_np, scans = dc_state
@@ -792,7 +808,7 @@ class DeviceWriteLane:
                         stats, device_pack=device_pack)
 
     @staticmethod
-    def _device_compress(n: int, meta: np.ndarray, lanes_d):
+    def _device_compress(n: int, meta: np.ndarray, lanes_d, device=None):
         """(planes, scans) of one full segment compressed on the device,
         or None when the kernel failed and the host compress leg takes
         the segment (counted; output bytes identical either way). The
@@ -800,11 +816,13 @@ class DeviceWriteLane:
         the main lane never holds the block's bytes there."""
         span = pipeline_ledger.span
         try:
-            meta_d = jax.device_put(meta)
+            meta_d = jax.device_put(meta, device)
             with span("write.lane.device_compress.dispatch") as sp:
                 planes_d, mbl, mbd, lbl, lbd, order_ok = \
                     device_compress.segment_scan_kernel(meta_d, lanes_d)
-            if _kprof.record_dispatch("write.compress", (n,), sp.seconds):
+            dev = device_id(device)
+            if _kprof.record_dispatch("write.compress", (n, dev),
+                                      sp.seconds, dev):
                 _kprof.maybe_record_cost(
                     "write.compress",
                     device_compress.segment_scan_kernel,

@@ -312,7 +312,8 @@ def _bucket(n: int) -> int:
 
 def build_operands(cat: CellBatch, gc_before: int = 0, now: int = 0,
                    purgeable_ts_fn=None, bucket: int | None = None) -> dict:
-    """Pack a CellBatch into the kernel's padded uint32 operand arrays."""
+    """Pack a CellBatch into the kernel's padded uint32 operand arrays,
+    host (numpy) arrays: the caller puts them on its device."""
     n = len(cat)
     N = bucket or _bucket(n)
     K = cat.n_lanes
@@ -349,13 +350,10 @@ def build_operands(cat: CellBatch, gc_before: int = 0, now: int = 0,
         purge_l = np.full(N, 0xFFFFFFFF, dtype=np.uint32)
 
     return {
-        "lanes": jnp.asarray(lanes), "valid": jnp.asarray(valid),
-        "ts_h": jnp.asarray(ts_h), "ts_l": jnp.asarray(ts_l),
-        "death": jnp.asarray(death),
-        "cdel": jnp.asarray(cdel),
-        "ldt": jnp.asarray(ldt), "expiring": jnp.asarray(expiring),
-        "purge_h": jnp.asarray(purge_h), "purge_l": jnp.asarray(purge_l),
-        "gc_before": jnp.int32(gc_before), "now": jnp.int32(now),
+        "lanes": lanes, "valid": valid, "ts_h": ts_h, "ts_l": ts_l,
+        "death": death, "cdel": cdel, "ldt": ldt, "expiring": expiring,
+        "purge_h": purge_h, "purge_l": purge_l,
+        "gc_before": np.int32(gc_before), "now": np.int32(now),
     }
 
 

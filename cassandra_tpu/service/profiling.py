@@ -7,7 +7,7 @@ compilation (seconds to minutes for big sorts), warm calls pay dispatch
 + device execution, and nothing recorded which was which. This module
 is the accounting layer:
 
-  record_dispatch(kernel, shape_key, s) -> bool
+  record_dispatch(kernel, shape_key, s, device=None) -> bool
       timed around the jitted call itself. jit compiles synchronously
       inside the call, so the FIRST dispatch for a (kernel, shape_key)
       pair is the compile: it is recorded under compile_s/compiles and
@@ -25,8 +25,8 @@ is the accounting layer:
       timed around blocking on the result (device wait).
   wrap(name, fn)
       the auto-instrumentation seam: returns `fn` with dispatch timing,
-      an argument-derived shape key and best-effort XLA cost analysis
-      folded in. Trace-safe — a call whose operands are tracers is
+      an argument-derived shape key, the device of its first committed
+      array operand and best-effort XLA cost analysis folded in. Trace-safe — a call whose operands are tracers is
       inside an ENCLOSING program's trace, where wall timing is
       meaningless and the outer program's dispatch already owns the
       cost, so the wrapper passes straight through.
@@ -49,9 +49,10 @@ system_views.device_programs virtual tables, the `kernel_profile`
 section of bench.py output and the `profile` section of flight-recorder
 bundles.
 
-Process-global (like the device itself); engine-scoped consumers read
-through the vtable which serves this singleton — acceptable because the
-accelerator is shared by every in-process node anyway.
+Process-global (like the devices themselves); engine-scoped consumers
+read through the vtable which serves this singleton. In-process nodes
+pinned to devices of their own (config `device_ordinal`) are told apart
+by each program's per-device calls and compiles.
 """
 from __future__ import annotations
 
@@ -88,6 +89,34 @@ def _shape_of(x):
     if isinstance(x, (int, float, str, bool, type(None))):
         return ("lit", x)
     return ("obj", type(x).__name__)
+
+
+def device_id(device=None) -> int:
+    """The id of `device`; for None, of the device jax places an
+    uncommitted operand on (its default device) — where a program runs
+    that no engine pinned."""
+    if device is None:
+        import jax
+        device = jax.config.jax_default_device
+        if not hasattr(device, "id"):
+            device = jax.devices(device)[0] if device else jax.devices()[0]
+    return int(device.id)
+
+
+def _device_of(x):
+    """The id of the device of the first COMMITTED jax array in an
+    operand tree (a jitted call runs there), else None."""
+    if getattr(x, "committed", False):
+        return next(iter(x.devices())).id
+    if isinstance(x, dict):
+        x = x.values()
+    elif not isinstance(x, (tuple, list)):
+        return None
+    for v in x:
+        d = _device_of(v)
+        if d is not None:
+            return d
+    return None
 
 
 def _has_tracer(x) -> bool:
@@ -130,20 +159,31 @@ class DeviceProgramRegistry:
                 "calls": 0, "compiles": 0, "compile_s": 0.0,
                 "dispatch_s": 0.0, "execute_s": 0.0,
                 "shapes": OrderedDict(), "shape_evictions": 0,
-                "retraces": 0, "sentinel_fired": False, "cost": None}
+                "retraces": 0, "sentinel_fired": False, "cost": None,
+                "devices": {}}
         return k
 
     def record_dispatch(self, kernel: str, shape_key,
-                        seconds: float) -> bool:
+                        seconds: float, device=None) -> bool:
+        """`device`: the id of the device the call ran on; None = the
+        default device. A program compiles per device as well as per
+        shape, so give the device in `shape_key` too where a program
+        runs on more than one."""
+        dev = device_id() if device is None else int(device)
         fire = compiles = retraces = None
         with self._lock:
             k = self._kernel_locked(kernel)
             k["calls"] += 1
+            per = k["devices"].get(dev)
+            if per is None:
+                per = k["devices"][dev] = {"calls": 0, "compiles": 0}
+            per["calls"] += 1
             shapes = k["shapes"]
             if shape_key in shapes:
                 shapes.move_to_end(shape_key)
                 k["dispatch_s"] += seconds
                 return False
+            per["compiles"] += 1
             shapes[shape_key] = True
             if len(shapes) > self.shape_cap:
                 shapes.popitem(last=False)
@@ -189,9 +229,11 @@ class DeviceProgramRegistry:
             key = _shape_of(args) if not kwargs \
                 else (_shape_of(args),
                       _shape_of(tuple(sorted(kwargs.items()))))
+            dev = _device_of(args)
+            key = (key, dev)
             t0 = CLOCK()
             out = fn(*args, **kwargs)
-            if registry.record_dispatch(name, key, CLOCK() - t0) \
+            if registry.record_dispatch(name, key, CLOCK() - t0, dev) \
                     and cost:
                 registry.maybe_record_cost(name, fn, args, kwargs)
             return out
@@ -232,8 +274,10 @@ class DeviceProgramRegistry:
     def snapshot(self) -> dict:
         """{"kernels": {name: {calls, compiles, shape_count,
         shape_evictions, retraces, compile_s, dispatch_s, execute_s,
-        cost_flops, cost_bytes}}, "phases": {name: seconds}}.
-        `shape_count` is the LIVE tracked-shape count."""
+        cost_flops, cost_bytes, devices}}, "phases": {name: seconds}}.
+        `shape_count` is the LIVE tracked-shape count; `devices` is
+        {device id: {"calls", "compiles"}}, the calls and compiles by
+        the device each ran on."""
         with self._lock:
             kernels = {}
             for name, k in self._kernels.items():
@@ -248,7 +292,9 @@ class DeviceProgramRegistry:
                     "execute_s": round(k["execute_s"], 6),
                     "cost_flops": float(cost.get("flops", 0.0)),
                     "cost_bytes": float(cost.get("bytes_accessed",
-                                                 0.0))}
+                                                 0.0)),
+                    "devices": {d: dict(v)
+                                for d, v in k["devices"].items()}}
             phases = {p: round(s, 6) for p, s in self._phases.items()}
         return {"kernels": kernels, "phases": phases}
 

@@ -38,6 +38,12 @@ class StorageEngine:
         omitted."""
         from ..config import Settings
         self.settings = settings or Settings()
+        # the jax device every device program of this engine runs on
+        # (config `device_ordinal`, read once here): compaction rounds
+        # and the write lane (through each store's `jax_device`), the
+        # vector index and the scan kernels. None = jax's default device
+        self.device = self._bind_device(
+            int(self.settings.get("device_ordinal")))
         self.data_dir = data_dir
         self.schema = schema or Schema()
         self.durable = durable_writes
@@ -388,6 +394,24 @@ class StorageEngine:
         self.settings.on_change("compaction_history_entries",
                                 self._ch_capacity_listener)
 
+    @staticmethod
+    def _bind_device(ordinal: int):
+        """The jax device `device_ordinal` names: span
+        `engine.device.bind`, `items` = the ordinal. -1 binds nothing
+        (None: jax's default device, and no jax import); an ordinal jax
+        does not see fails the start."""
+        if ordinal < 0:
+            return None
+        from ..config import ConfigError
+        with pipeline_ledger.span("engine.device.bind", items=ordinal):
+            import jax
+            devices = jax.devices()
+            if ordinal >= len(devices):
+                raise ConfigError(
+                    f"device_ordinal {ordinal}: jax sees "
+                    f"{len(devices)} {devices[0].platform} device(s)")
+            return devices[ordinal]
+
     def _mesh_devices(self) -> int:
         """This engine's mesh width (its knob, not the shared pool's —
         the pool sizes to the max across co-hosted engines; routing is
@@ -488,6 +512,7 @@ class StorageEngine:
                                     "memtable_shards") or None,
                                 failures=self.failures)
         cfs.backup_enabled = lambda: self.incremental_backup
+        cfs.jax_device = self.device
         cfs.mesh_devices_fn = self._mesh_devices
         cfs.decode_ahead_fn = self._decode_ahead
         cfs.device_compress_fn = self._device_compress

@@ -282,6 +282,10 @@ class ColumnFamilyStore:
         # SEGMENT (results identical either way)
         self.scan_device_filter_fn = \
             lambda: bool(_Config().scan_device_filter)
+        # the jax device this store's compactions and scan kernels run
+        # on: a StorageEngine sets its own (config `device_ordinal`);
+        # None = jax's default device
+        self.jax_device = None
         # eager attached-index builds: a StorageEngine points this at
         # IndexManager.build_eager so new sstables (flush/compaction)
         # get their index components in the writer tail; a standalone
@@ -1303,7 +1307,8 @@ class ColumnFamilyStore:
                             if not len(sel):
                                 continue
                             mask, on_dev = ds.segment_mask(
-                                keys, pred, bool(gate()))
+                                keys, pred, bool(gate()),
+                                self.jax_device)
                         loc["device_segments" if on_dev
                             else "host_segments"] += 1
                         st_filter.add_items(len(sel))
@@ -1329,7 +1334,8 @@ class ColumnFamilyStore:
                 sel, keys = ds.batch_predicate_cells(mem, pred,
                                                      reconciled=False)
                 if len(sel):
-                    mask, _ = ds.segment_mask(keys, pred, bool(gate()))
+                    mask, _ = ds.segment_mask(keys, pred, bool(gate()),
+                                              self.jax_device)
                     for i in sel[mask]:
                         pks.add(mem.partition_key(int(i)))
         view = self.tracker.view()
@@ -1394,7 +1400,8 @@ class ColumnFamilyStore:
             return 0, None, None, 0, info
         big = CellBatch.concat(parts) if len(parts) > 1 else parts[0]
         cnt, kmn, kmx, sm, on_dev = ds.fold_batch(big, pred,
-                                                  bool(gate()))
+                                                  bool(gate()),
+                                                  self.jax_device)
         info["fold_on_device"] = on_dev
         if cnt == 0:
             return 0, None, None, 0, info

@@ -43,7 +43,10 @@ class Ring3:
     i's front door (0-based)."""
 
     def __init__(self, base_dir, tables: list, rf: int = 3, n: int = 3,
-                 gossip_interval: float = 0.1, node_config=None):
+                 gossip_interval: float = 0.1, node_config=None,
+                 devices: list | None = None):
+        """devices: node i's `device_ordinal`, the jax device its engine
+        runs every device program on (None: jax's default for all)."""
         ports, tokens = free_ports(n), even_tokens(n, vnodes=4)
         names = [f"node{i + 1}" for i in range(n)]
         ddl = [f"CREATE KEYSPACE {KEYSPACE} WITH replication = "
@@ -55,10 +58,13 @@ class Ring3:
                     "port": ports[i], "tokens": tokens[i]}
         self.nodes, self.servers, self.down = [], [], set()
         for i in range(n):
+            config = dict(node_config or NODE_CONFIG)
+            if devices is not None:
+                config["device_ordinal"] = devices[i]
             cfg = dict(peer(i), data_dir=str(base_dir / names[i]),
                        peers=[peer(j) for j in range(n) if j != i],
                        seeds=[names[0]], gossip_interval=gossip_interval,
-                       config=dict(node_config or NODE_CONFIG), ddl=ddl)
+                       config=config, ddl=ddl)
             node, _transport = build_node(cfg)
             self.nodes.append(node)
             self.servers.append(CQLServer(node, "127.0.0.1", 0))
